@@ -105,6 +105,33 @@ def _roll_call(num_processes: int, process_id: int,
             f"after {timeout_s:.0f}s roll call ({e})") from e
 
 
+# the persistent XLA compile cache when JAX_COMPILATION_CACHE_DIR is not
+# set: ONE fixed directory inside the checkout (the path is part of the
+# cache key, so a directory that moves — /tmp, a pid, a tempfile name —
+# never hits). Tests, worker processes, bench and chip_smoke.py all
+# share it through init().
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def setup_compile_cache() -> str:
+    """Turn the persistent compile cache on and return its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set jax already reads it and no
+    directory is set in code; otherwise ``COMPILE_CACHE_DIR``. A cache
+    directory that cannot be created raises — repeated sessions (tests,
+    bench, the chip smoke) recompiling everything is a fault to see,
+    not a warning to scroll past."""
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 def init(backend: Optional[str] = None,
          data_axis: Optional[int] = None,
          model_axis: Optional[int] = None,
@@ -148,18 +175,7 @@ def init(backend: Optional[str] = None,
     _log.configure(level=cfg.log_level,
                    log_dir=cfg.log_dir or None)
 
-    # persistent XLA compilation cache: repeated sessions (tests, bench,
-    # conformance servers) skip recompiling identical programs — this
-    # both cuts cold-start time and shrinks the exposure to the CPU
-    # backend's flaky-compile crashes observed in long processes
-    try:
-        cache_dir = os.environ.get("H2O3TPU_XLA_CACHE",
-                                   "/tmp/h2o3tpu_xla_cache")
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception as e:           # noqa: BLE001 — cache is optional
-        log.warning("persistent XLA cache unavailable: %s", e)
+    setup_compile_cache()
 
     if coordinator_address is not None and not _STARTED:
         timeout_s = _cloud_timeout_s(cfg)

@@ -108,7 +108,7 @@ class Config:
     batch_models: str = "auto"
     # -- HBM memory governor (core/memgov.py) --------------------------
     # deterministic HBM budget in MB when the backend reports no
-    # bytes_limit (CPU tests, plugins exporting no memory stats);
+    # bytes_limit (the CPU backend, i.e. tests);
     # 0 = no explicit budget (the governor only observes)
     hbm_budget_mb: int = 0
     # bounded wait for concurrent fits' reservations to release before

@@ -272,7 +272,8 @@ class HeartbeatMonitor:
         if self._psum_fn is None or self._psum_mesh is not mesh:
             import functools
             from jax.sharding import PartitionSpec as P
-            from h2o3_tpu.parallel.mesh import DATA_AXIS, shard_map
+            from jax import shard_map
+            from h2o3_tpu.parallel.mesh import DATA_AXIS
 
             @functools.partial(shard_map, mesh=mesh,
                                in_specs=P(DATA_AXIS), out_specs=P(),

@@ -52,11 +52,6 @@ from h2o3_tpu.utils.log import get_logger
 
 log = get_logger("h2o3_tpu.memgov")
 
-# assumed HBM when an accelerator plugin exports no memory stats and no
-# budget knob is set (the old private fallback of ops/merge.py, now the
-# one shared constant)
-DEFAULT_DEVICE_HBM_BYTES = 16 << 30
-
 
 class MemoryBudgetExceeded(ValueError):
     """Pre-dispatch admission rejection — deliberately a ValueError so

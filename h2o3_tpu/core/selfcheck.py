@@ -5,7 +5,7 @@ boot every node measures GFLOPS, memory bandwidth, and network
 throughput so cluster health pages can flag slow nodes. TPU-native
 probes: MXU matmul GFLOPS (Linpack role), HBM read bandwidth
 (MemoryBandwidth role), host↔device transfer (NetworkBench role — the
-PCIe/tunnel link is the analogous bottleneck path), and a mesh psum
+PCIe link is the analogous bottleneck path), and a mesh psum
 round-trip when more than one device is attached.
 """
 
@@ -73,7 +73,7 @@ def run_self_bench(sizes: Dict[str, int] | None = None) -> Dict[str, float]:
         mesh = get_mesh()
         if mesh.shape[DATA_AXIS] > 1:
             import functools
-            from h2o3_tpu.parallel.mesh import shard_map
+            from jax import shard_map
 
             @jax.jit
             @functools.partial(shard_map, mesh=mesh, in_specs=P(DATA_AXIS),

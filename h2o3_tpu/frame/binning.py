@@ -109,12 +109,12 @@ class BinnedMatrix:
     def tile_view(self, rows: Optional[int] = None) -> BinTileView:
         """Bin-major tile view (cached per ``rows``): the matrix padded
         to whole [rows, F] tiles for VMEM streaming. ``rows=None`` picks
-        the VMEM-sized suggestion for this matrix's (F, B) at a 32-node
-        level (ops/pallas.vmem_tile_rows)."""
+        the VMEM-sized tile for this matrix's (F, B) at a 32-node level
+        (ops/pallas.tile_rows), the whole matrix where none fits."""
         if rows is None:
-            from h2o3_tpu.ops.pallas import vmem_tile_rows
-            rows = vmem_tile_rows(max(self.nfeatures, 1),
-                                  self.nbins_total, 32)
+            from h2o3_tpu.ops.pallas import tile_rows
+            rows = tile_rows(max(self.nfeatures, 1), self.nbins_total,
+                             32) or self.bins.shape[0]
         rows = max(1, min(int(rows), self.bins.shape[0]))
         tv = self._tile_cache.get(rows)
         if tv is None:

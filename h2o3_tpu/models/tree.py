@@ -122,7 +122,7 @@ class TreeParams:
                                      # orders — uniform-weight
                                      # normalization covers the exact-
                                      # equality contracts instead
-    pallas: str = "off"              # fused level-loop backend:
+    pallas: str = "off"              # level-pass backend:
                                      # "off" = XLA, "native"/"interpret"
                                      # = ops/pallas/treekernel. STATIC
                                      # on purpose: the knob decision
@@ -271,15 +271,19 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
     # small matmul whose exactness the weight≡duplication metric
     # contracts actually observe
     prec = jax.lax.Precision.HIGHEST if params.exact_f32 else None
-    # fused Pallas level loop (ops/pallas/treekernel.py): histogram +
-    # split scan + row partition in one pass over the bin-major tiles,
-    # selected per fit via the STATIC params.pallas knob. The stats
-    # block {w, w·g, w·h} is level-invariant, so it is built once here
-    # (the XLA path rebuilds the same values inside ops/histogram.py).
-    use_fused = params.pallas in ("native", "interpret")
-    if use_fused:
+    # Pallas level kernels (ops/pallas/treekernel.py): the histogram
+    # and row-partition passes over the bin-major tiles, selected per
+    # fit via the STATIC params.pallas knob and per LEVEL by whether
+    # the level's shapes fit a VMEM tile (ops/pallas.tile_rows — deep
+    # levels' accumulators do not; they take the XLA sequence below).
+    # The stats block {w, w·g, w·h} is level-invariant, so it is built
+    # once here (the XLA path rebuilds the same values inside
+    # ops/histogram.py).
+    use_kernels = params.pallas in ("native", "interpret")
+    if use_kernels:
+        from h2o3_tpu.ops import pallas as pallas_policy
         from h2o3_tpu.ops.pallas.treekernel import fused_level
-        stats3 = jnp.stack([w, w * g, w * h], axis=1).astype(jnp.float32)
+        stats3 = jnp.stack([w, w * g, w * h]).astype(jnp.float32)  # [3, N]
     prev_hist = None
     for d in range(D):
         L = 2 ** d
@@ -289,6 +293,9 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
             cm = _mtries_mask(sub, L, F, mtries) & col_mask[None, :]
         if interaction_sets is not None:
             cm = (cm if cm.ndim == 2 else cm[None, :]) & allowed
+        use_fused = use_kernels and pallas_policy.tile_rows(F, B, L) > 0
+        if use_kernels and not use_fused:
+            pallas_policy.record_fallback("level_fits_no_tile")
         if use_fused:
             (hist, bg, bf, bt, bnal, blv, brv, leftmask, split,
              nid_next) = fused_level(
@@ -374,7 +381,7 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
             lo = jnp.stack([lo_l, lo_r], axis=1).reshape(-1)
             hi = jnp.stack([hi_l, hi_r], axis=1).reshape(-1)
         # route rows (the reference's DecidedNode assignment pass);
-        # the fused kernel already partitioned inside its second phase
+        # the partition kernel has already done it on the kernel path
         if nid_next is not None:
             nid = nid_next
         else:

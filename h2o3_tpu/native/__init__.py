@@ -5,14 +5,18 @@ The reference's native layer is the XGBoost JNI bridge
 the host-side hot paths that JAX/XLA doesn't cover — currently the
 chunk-parallel CSV tokenizer (csv_parser.cpp, the water/parser role).
 
-The shared object is compiled on first use with g++ (cached next to the
-source, keyed by source mtime); every consumer must degrade gracefully
-when no toolchain is available (`load_csv_parser()` returns None).
+The shared object is compiled on first use with g++ and cached next to
+the source under a name keyed by a hash of ``csv_parser.cpp``
+(``_csv_parser.<sha>.so``, ignored by git), so a binary left on disk
+from other source is never loaded; every consumer must degrade
+gracefully when no toolchain is available (`load_csv_parser()` returns
+None).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,25 +30,40 @@ log = get_logger("h2o3_tpu.native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "csv_parser.cpp")
-_SO = os.path.join(_DIR, "_csv_parser.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
 
 
-def _build() -> bool:
+def library_path() -> str:
+    """Where the tokenizer built from the CURRENT source lives."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_csv_parser.{digest}.so")
+
+
+def _build(so: str) -> bool:
+    # build under a per-process name, then rename: several processes
+    # (xdist workers, pod workers) may build at once and none may load
+    # a half-written file (the name still ends in .so: git ignores it)
+    tmp = f"{so[:-3]}.{os.getpid()}.tmp.so"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
         if r.returncode != 0:
             log.warning("native csv build failed: %s",
                         r.stderr.decode()[:500])
             return False
+        os.replace(tmp, so)
+        log.info("built native csv tokenizer %s", so)
         return True
     except (OSError, subprocess.TimeoutExpired) as e:
         log.warning("native csv build unavailable: %s", e)
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load_csv_parser() -> Optional[ctypes.CDLL]:
@@ -56,12 +75,11 @@ def load_csv_parser() -> Optional[ctypes.CDLL]:
         if _lib is not None or _lib_failed:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                if not _build():
-                    _lib_failed = True
-                    return None
-            lib = ctypes.CDLL(_SO)
+            so = library_path()
+            if not os.path.exists(so) and not _build(so):
+                _lib_failed = True
+                return None
+            lib = ctypes.CDLL(so)
             lib.csv_parse.restype = ctypes.c_void_p
             lib.csv_parse.argtypes = [ctypes.c_char_p, ctypes.c_long,
                                       ctypes.c_char, ctypes.c_int,

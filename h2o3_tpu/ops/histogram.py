@@ -23,7 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from h2o3_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS

@@ -30,6 +30,11 @@ import os
 from typing import Optional, Tuple
 
 _AVAILABLE: Optional[bool] = None
+# bin ids the kernels' bf16 one-hot expansion holds exactly (int8 binned
+# matrices, the default 64-bin histograms, stay far below it)
+MAX_KERNEL_BINS = 256
+# what a kernel may take of Mosaic's 16 MB scoped-VMEM limit
+VMEM_BUDGET_BYTES = 12 << 20
 _LOGGED_REASONS = set()        # single logged fallback per reason/process
 
 
@@ -117,18 +122,39 @@ def record_launch(kernel: str) -> None:
     telemetry.counter("pallas_kernel_launches_total", kernel=kernel).inc()
 
 
-def vmem_tile_rows(n_features: int, n_bins: int, n_nodes: int,
-                   budget_bytes: int = 8 << 20) -> int:
-    """Row extent of a bin-major tile that fits the phase-A working set
-    in a VMEM budget: the int8 bins tile, the f32 one-hot (feature, bin)
-    indicator, the f32 node⊗stat routing block, and double-counted
-    histogram accumulator + output. Pure math (the bench stub's planner
-    runs it with no backend); floors to a sublane multiple of 8.
+def _up(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
+
+
+def tile_rows(n_features: int, n_bins: int, n_nodes: int,
+              budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
+    """Rows per tile at which BOTH level kernels (histogram, partition)
+    fit ``budget_bytes`` of scoped VMEM, as a multiple of 128 up to
+    2048 — or 0 when the level cannot run in the kernels at all (bin
+    ids the bf16 expansion cannot hold exactly, or a histogram
+    accumulator that outgrows VMEM before a single tile is added: deep
+    levels). A level that gets 0 takes the XLA composition. Pure math,
+    no backend.
+
+    The per-row costs are what Mosaic allocated in compile sweeps for a
+    v5e (libtpu 0.0.34) over F in 4..60, B in 17..256, levels 0..8,
+    with a margin of 1.5x or more. The histogram kernel's row costs one
+    f32 lane-padded [F·B] one-hot row plus its [C, 3Lh] temporaries
+    (each at least one 128-lane tile wide); the partition kernel keeps
+    rows on the lanes and costs the sublanes of its [L, C], [B-1, C]
+    and [F, C] blocks.
     """
-    per_row = (n_features                    # int8 bins lane
-               + 4 * n_features * n_bins     # f32 one-hot right
-               + 4 * 3 * n_nodes             # f32 left block
-               + 64)                         # slack
-    fixed = 2 * 4 * 3 * n_nodes * n_features * n_bins
-    rows = max((int(budget_bytes) - fixed) // per_row, 8)
-    return max(8, (rows // 8) * 8)
+    if n_bins > MAX_KERNEL_BINS:
+        return 0
+    fb = 4 * _up(n_features * n_bins, 128)           # one f32 [., F·B] row
+    lh3 = 3 * max(n_nodes // 2, 1)
+    hist_row = (5 * (fb + 2048 + 8 * _up(lh3, 128))) // 4
+    # accumulator scratch + double-buffered output block
+    hist_fixed = 3 * _up(lh3, 8) * fb
+    part_row = 4 * (2 * _up(n_nodes, 8) + 2 * _up(n_bins - 1, 8)
+                    + 2 * _up(n_features, 32) + 32)
+    # double-buffered [B-1, L] f32 left-set block
+    part_fixed = 2 * _up(n_bins - 1, 8) * 4 * _up(n_nodes, 128)
+    rows = min((int(budget_bytes) - hist_fixed) // hist_row,
+               (int(budget_bytes) - part_fixed) // part_row, 2048)
+    return max(0, (rows // 128) * 128)

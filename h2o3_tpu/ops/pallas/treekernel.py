@@ -1,34 +1,33 @@
-"""Fused Pallas tree kernels: histogram + best-split + partition per level.
+"""Pallas tree kernels: the two row passes of a tree level, on the chip.
 
 The XLA level loop (models/tree.py grow_tree) touches the binned matrix
-three times per depth level — one-hot matmul histograms
-(ops/histogram.py), the split scan, then ``_level_goleft`` re-reads the
-matrix to route rows — with every intermediate round-tripping HBM. This
-module fuses the whole per-level inner loop the way the GPU tree-boosting
-systems do (Booster arxiv 2011.02022; XGBoost-GPU arxiv 1806.11248):
+twice per depth level — one-hot matmul histograms (ops/histogram.py),
+then ``_level_goleft`` re-reads the matrix to route rows — with the
+split scan between them. This module runs the two row passes as Pallas
+kernels over bin-major tiles (frame/binning.py tile layout: int8,
+feature-major lanes, NA folded in as bin B-1), the way the GPU
+tree-boosting systems do (Booster arxiv 2011.02022; XGBoost-GPU arxiv
+1806.11248), on every mesh alike:
 
-- single data shard: ONE ``pallas_call`` over a (phase, tile) grid.
-  Phase 0 streams bin-major tiles (frame/binning.py tile layout: int8,
-  feature-major lanes, NA folded in as bin B-1) through VMEM and
+- a per-shard histogram kernel streams the tiles through VMEM and
   accumulates the [3L, F·B] histogram in a VMEM scratch on the MXU;
-  the phase boundary derives the level histogram (sibling subtraction
-  against the parent level), runs the shared split scan
-  (ops/split_scan.py — the SAME function the XLA path calls, so the
-  two paths are bit-exact by construction), and parks the decisions in
-  the kernel's output refs; phase 1 re-streams the tiles and routes
-  every row to its child, all without leaving the chip.
-- sharded mesh: the same phase bodies split into a per-shard histogram
-  kernel, the cross-shard ``psum`` (the MRTask reduce tree,
-  water/MRTask.java:891 — a hard barrier no fusion can remove), the
-  boundary math, and a per-shard partition kernel.
+- the cross-shard ``psum`` (the MRTask reduce tree,
+  water/MRTask.java:891 — a no-op on one shard) and the level boundary
+  in plain XLA: sibling subtraction against the parent level and the
+  shared split scan (ops/split_scan.py — the SAME function the XLA path
+  calls, so the two paths are bit-exact by construction). The scan is
+  ``jax.numpy`` (cumsum, sort, scatter) and cannot lower inside a
+  kernel, which is why there is no single fused ``pallas_call``;
+- a per-shard partition kernel re-streams the tiles and routes every
+  row to its child.
 
 Numerics contract: with ``interpret=True`` (CPU tier-1) every output is
 bit-exact vs the XLA path on the same mesh — f32 accumulation with the
 XLA path's exact row-block structure, identical split tie-breaking
-(shared code), integer routing. Native TPU runs may pick VMEM-sized
-tiles instead (ops/pallas.vmem_tile_rows) and trade the bitwise match
-for throughput; the XLA path remains the always-available fallback
-behind ``H2O3TPU_PALLAS`` (core/config.py).
+(shared code), integer routing. Native TPU runs use VMEM-sized tiles
+(ops/pallas.tile_rows) and trade the bitwise match for fitting the
+chip. A level whose shapes fit no tile (``tile_rows == 0``: deep
+levels, bin ids past 256) is grown by grow_tree's XLA sequence instead.
 """
 
 from __future__ import annotations
@@ -37,13 +36,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu.ops import pallas as pallas_policy
 from h2o3_tpu.ops.split_scan import best_splits
-from h2o3_tpu.parallel.mesh import DATA_AXIS, shard_map
+from h2o3_tpu.parallel.mesh import DATA_AXIS
 
 
 # --------------------------------------------------------------- tile math
@@ -59,35 +59,60 @@ def _tile_geometry(n_rows: int, block_rows: int):
     return C, nblk, nblk * C
 
 
-def _pad_rows(arr, n_pad: int):
-    n = arr.shape[0]
+def _pad_lanes(arr, n_pad: int):
+    n = arr.shape[1]
     if n == n_pad:
         return arr
-    return jnp.pad(arr, ((0, n_pad - n),) + ((0, 0),) * (arr.ndim - 1))
+    return jnp.pad(arr, ((0, 0), (0, n_pad - n)))
 
 
-# ----------------------------------------------------- shared phase bodies
+# ----------------------------------------------------- kernel block bodies
+#
+# Layout: PER-ROW VALUES RIDE THE LANES ON THEIR WAY IN. Node ids reach
+# a kernel as a [1, C] row, stats as a [3, C] block, and the partition
+# kernel reads the bins transposed, [F, C] — the last (lane) axis is the
+# row axis, so they are lane-dense in HBM. The row-major alternative,
+# [N, 1] and [N, 3] operands, pads each to 128 lanes: 2.7 GB apiece in
+# HBM at 5M rows (the boost scan's temporaries compiled to 14 GB). The
+# partition kernel computes in that orientation throughout. The
+# histogram kernel transposes its two small blocks back to columns
+# inside VMEM (and takes the bins tile row-major, [C, F]): its product
+# has to be the XLA path's own ``left.T @ right`` for interpret-mode bit
+# parity, which a lane-major ``left`` breaks in the last bit.
 
 
 def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int):
     """One tile's [3Lh, F·B] partial histogram — VMEM one-hots feeding
-    the MXU. Values (not just sums) match ops/histogram._block_hist: the
-    one-hot indicators are exact 0/1 and the stats ride untouched, so
-    the f32 contraction sees identical operands. At levels d >= 1 only
-    LEFT-child rows accumulate, into their PARENT's slot (the sibling-
-    subtraction trick of grow_tree, kept inside the kernel)."""
+    the MXU. ``bins`` [C, F], ``nid`` [C, 1], ``stats`` [C, 3]: the
+    row-major forms of ops/histogram._block_hist, whose values (not
+    just sums) this matches — the one-hot indicators are exact 0/1, the
+    stats ride untouched and the contraction is the same
+    ``left.T @ right``, so the f32 accumulation sees identical operands
+    in an identical order. At levels d >= 1 only LEFT-child rows
+    accumulate, into their PARENT's slot (the sibling-subtraction trick
+    of grow_tree, kept inside the kernel).
+
+    The (feature, bin) indicator is built by EXPANDING the row's bins
+    across the F·B lanes with a [C, F] x [F, F·B] 0/1 selection matmul
+    and one compare — a per-feature compare-and-add loop costs Mosaic F
+    times the VPU work and an amount of scoped VMEM that no formula
+    predicted. The expansion runs in bf16 (one MXU pass, f32 result):
+    exact for bin ids up to 256, which ``tile_rows`` guarantees."""
     C, F = bins.shape
-    bins = bins.astype(jnp.int32)
+    FB = F * n_bins
+    assert n_bins <= pallas_policy.MAX_KERNEL_BINS, n_bins
     if d > 0:
         even = ((nid % 2) == 0).astype(jnp.float32)      # [C, 1]
         stats = stats * even
         nid = nid >> 1
-    feat_off = jax.lax.broadcasted_iota(jnp.int32, (C, F), 1) * n_bins
-    fb = bins + feat_off                                 # [C, F] in [0, FB)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (C, F * n_bins), 1)
-    right = (lane == fb[:, 0:1]).astype(jnp.float32)
-    for f in range(1, F):
-        right += (lane == fb[:, f:f + 1]).astype(jnp.float32)
+    lane_f = jax.lax.broadcasted_iota(jnp.int32, (F, FB), 1) // n_bins
+    sel = lane_f == jax.lax.broadcasted_iota(jnp.int32, (F, FB), 0)
+    row_bin = jax.lax.dot_general(                   # bins[c, lane's feature]
+        bins.astype(jnp.bfloat16), sel.astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # [C, FB]
+    lane_b = jax.lax.broadcasted_iota(jnp.int32, (1, FB), 1) % n_bins
+    right = (row_bin == lane_b.astype(jnp.float32)).astype(jnp.float32)
     lane3 = jax.lax.broadcasted_iota(jnp.int32, (C, n_nodes_h * 3), 1)
     node_of_k = lane3 // 3
     stat_of_k = lane3 - 3 * node_of_k
@@ -139,157 +164,47 @@ def _partition_block(bins, nid, bf, bt, bnal, isp, cs, leftmask, *,
     """Route one tile's rows to their children — gather-free
     ``_level_goleft`` semantics (one-hot selects + a 0/1 matmul for the
     categorical left-set membership). Pure integer/boolean work ⇒
-    bit-exact against the XLA routing by construction."""
-    C, F = bins.shape
+    bit-exact against the XLA routing by construction.
+
+    ``bins`` [F, C], ``nid`` [1, C]; ``bf``/``bt``/``bnal``/``isp``/
+    ``cs`` are [L, 1] int32 columns (flags 0/1) and ``leftmask`` a
+    [B-1, L] f32 0/1 block. Written for what Mosaic lowers: every
+    per-row value stays a [1, C] row (no 1-D vectors), flags stay int32
+    until one final compare, and booleans combine through and/or/not —
+    a ``select`` between two boolean operands is refused ("Unsupported
+    target bitwidth for truncation")."""
+    F, C = bins.shape
     L = bf.shape[0]
     bins = bins.astype(jnp.int32)
-    noh = nid == jax.lax.broadcasted_iota(jnp.int32, (C, L), 1)  # [C, L]
-    f_r = jnp.sum(jnp.where(noh, bf[None, :], 0), axis=1,
-                  keepdims=True)                                 # [C, 1]
-    t_r = jnp.sum(jnp.where(noh, bt[None, :], 0), axis=1)        # [C]
-    nal_r = jnp.sum(jnp.where(noh, bnal.astype(jnp.int32)[None, :], 0),
-                    axis=1) > 0
-    isp_r = jnp.sum(jnp.where(noh, isp.astype(jnp.int32)[None, :], 0),
-                    axis=1) > 0
-    cs_r = jnp.sum(jnp.where(noh, cs.astype(jnp.int32)[None, :], 0),
-                   axis=1) > 0
-    fio = jax.lax.broadcasted_iota(jnp.int32, (C, F), 1)
-    b_r = jnp.sum(jnp.where(f_r == fio, bins, 0), axis=1)        # [C]
+    noh = nid == jax.lax.broadcasted_iota(jnp.int32, (L, C), 0)  # [L, C]
+
+    def of_node(col):                                            # [1, C]
+        return jnp.sum(jnp.where(noh, col, 0), axis=0, keepdims=True)
+
+    f_r = of_node(bf)
+    t_r = of_node(bt)
+    nal_r = of_node(bnal) > 0
+    isp_r = of_node(isp) > 0
+    cs_r = of_node(cs) > 0
+    fio = jax.lax.broadcasted_iota(jnp.int32, (F, C), 0)
+    b_r = jnp.sum(jnp.where(f_r == fio, bins, 0), axis=0, keepdims=True)
     isna = b_r == (n_bins - 1)
     go_num = b_r <= t_r
     # leftmask[nid, b_r] without a 2D gather: 0/1 matmul over nodes,
-    # then a lane select over bins (exact — operands are indicators)
+    # then a sublane select over bins (exact — operands are indicators)
     row_mask = jax.lax.dot_general(
-        noh.astype(jnp.float32), leftmask.astype(jnp.float32),
+        leftmask, jnp.where(noh, 1.0, 0.0).astype(jnp.float32),
         (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                      # [C, B-1]
-    bio = jax.lax.broadcasted_iota(jnp.int32, (C, n_bins - 1), 1)
-    inset = jnp.sum(jnp.where(bio == b_r[:, None], row_mask, 0.0),
-                    axis=1) > 0.5
-    go_split = jnp.where(cs_r, inset, go_num)
-    goleft = jnp.where(isp_r, jnp.where(isna, nal_r, go_split), True)
-    return 2 * nid + jnp.where(goleft, 0, 1)[:, None]
+        preferred_element_type=jnp.float32)                      # [B-1, C]
+    bio = jax.lax.broadcasted_iota(jnp.int32, (n_bins - 1, C), 0)
+    inset = jnp.sum(jnp.where(bio == b_r, row_mask, 0.0), axis=0,
+                    keepdims=True) > 0.5
+    go_split = (cs_r & inset) | (~cs_r & go_num)
+    goleft = ~isp_r | (isna & nal_r) | (~isna & go_split)
+    return 2 * nid + jnp.where(goleft, 0, 1)
 
 
-# --------------------------------------------- single-shard fused kernel
-
-
-def _fused_kernel(bins_ref, nid_ref, stats_ref, prev_ref, cm_ref, nb_ref,
-                  iscat_ref, cons_ref, lo_ref, hi_ref, knobs_ref, dl_ref,
-                  hist_ref, bg_ref, bf_ref, bt_ref, bnal_ref, blv_ref,
-                  brv_ref, lmask_ref, isp_ref, newnid_ref,
-                  acc_ref, cs_ref, *, d: int, n_nodes: int, n_bins: int,
-                  n_features: int, nblk: int, has_cats: bool,
-                  has_cons: bool):
-    phase = pl.program_id(0)
-    blk = pl.program_id(1)
-
-    @pl.when((phase == 0) & (blk == 0))
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(phase == 0)
-    def _():
-        acc_ref[:] += _hist_block(
-            bins_ref[:], nid_ref[:], stats_ref[:],
-            n_nodes_h=max(n_nodes // 2, 1), n_bins=n_bins, d=d)
-        newnid_ref[:] = nid_ref[:]       # placeholder until phase 1
-
-    @pl.when((phase == 1) & (blk == 0))
-    def _():
-        hist, bg, bf, bt, bnal, blv, brv, lmask, split, cs = \
-            _level_boundary(
-                acc_ref[:], prev_ref[:] if d > 0 else None, cm_ref[:],
-                nb_ref[0], iscat_ref[0] != 0 if has_cats else None,
-                cons_ref[0] if has_cons else None, lo_ref[0], hi_ref[0],
-                knobs_ref[:], dl_ref[:], d=d, n_nodes=n_nodes,
-                n_bins=n_bins, n_features=n_features)
-        hist_ref[:] = hist
-        bg_ref[0, :] = bg
-        bf_ref[0, :] = bf
-        bt_ref[0, :] = bt
-        bnal_ref[0, :] = bnal.astype(jnp.int32)
-        blv_ref[0, :] = blv
-        brv_ref[0, :] = brv
-        lmask_ref[:] = lmask.astype(jnp.int32)
-        isp_ref[0, :] = split.astype(jnp.int32)
-        cs_ref[0, :] = cs.astype(jnp.int32)
-
-    @pl.when(phase == 1)
-    def _():
-        newnid_ref[:] = _partition_block(
-            bins_ref[:], nid_ref[:], bf_ref[0, :], bt_ref[0, :],
-            bnal_ref[0, :] != 0, isp_ref[0, :] != 0, cs_ref[0, :] != 0,
-            lmask_ref[:] != 0, n_bins=n_bins)
-
-
-def _fused_call(bins, nid, stats, prev, cm2, nb2, iscat, cons, lo2, hi2,
-                knobs, dl, *, d, n_nodes, n_bins, block_rows, interpret):
-    """The tentpole: hist + split + partition in ONE pallas_call over the
-    bin-major tiles — phase 0 accumulates, the boundary decides, phase 1
-    re-streams the same tiles and routes."""
-    N, F = bins.shape
-    C, nblk, n_pad = _tile_geometry(N, block_rows)
-    bins_p = _pad_rows(bins, n_pad)
-    nid_p = _pad_rows(nid, n_pad).reshape(-1, 1)
-    stats_p = _pad_rows(stats, n_pad)
-    Lh = max(n_nodes // 2, 1)
-    L, B = n_nodes, n_bins
-    Lcm = cm2.shape[0]
-    Llo = lo2.shape[1]
-
-    pallas_policy.record_launch("tree_fused_level")
-    grid = (2, nblk)
-    full = lambda *shape: pl.BlockSpec(       # noqa: E731 - spec shorthand
-        shape, lambda p, b: (0,) * len(shape))
-    tile = lambda *shape: pl.BlockSpec(       # noqa: E731
-        shape, lambda p, b: (b,) + (0,) * (len(shape) - 1))
-    kern = functools.partial(
-        _fused_kernel, d=d, n_nodes=L, n_bins=B, n_features=F, nblk=nblk,
-        has_cats=iscat is not None, has_cons=cons is not None)
-    outs = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            tile(C, F), tile(C, 1), tile(C, 3),
-            full(Lh, F, B, 3), full(Lcm, F), full(1, F),
-            full(1, F), full(1, F), full(1, Llo), full(1, Llo),
-            full(1, 3), full(1, 1),
-        ],
-        out_specs=[
-            full(L, F, B, 3),
-            full(1, L), full(1, L), full(1, L), full(1, L),
-            full(1, L), full(1, L), full(L, B - 1), full(1, L),
-            tile(C, 1),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((L, F, B, 3), jnp.float32),
-            jax.ShapeDtypeStruct((1, L), jnp.float32),
-            jax.ShapeDtypeStruct((1, L), jnp.int32),
-            jax.ShapeDtypeStruct((1, L), jnp.int32),
-            jax.ShapeDtypeStruct((1, L), jnp.int32),
-            jax.ShapeDtypeStruct((1, L), jnp.float32),
-            jax.ShapeDtypeStruct((1, L), jnp.float32),
-            jax.ShapeDtypeStruct((L, B - 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, L), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((3 * Lh, F * B), jnp.float32),
-            pltpu.VMEM((1, L), jnp.int32),
-        ],
-        interpret=interpret,
-    )(bins_p, nid_p, stats_p,
-      prev if prev is not None else jnp.zeros((Lh, F, B, 3), jnp.float32),
-      cm2, nb2, iscat if iscat is not None else jnp.zeros((1, F), jnp.int8),
-      cons if cons is not None else jnp.zeros((1, F), jnp.int8),
-      lo2, hi2, knobs, dl)
-    (hist, bg, bf, bt, bnal, blv, brv, lmask, isp, newnid) = outs
-    return (hist, bg[0], bf[0], bt[0], bnal[0] != 0, blv[0], brv[0],
-            lmask != 0, isp[0] != 0, newnid[:N, 0])
-
-
-# --------------------------------------------- sharded two-kernel variant
+# ------------------------------------------------------ the two kernels
 
 
 def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
@@ -300,7 +215,7 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += _hist_block(bins_ref[:], nid_ref[:], stats_ref[:],
+    acc_ref[:] += _hist_block(bins_ref[:], nid_ref[:].T, stats_ref[:].T,
                               n_nodes_h=n_nodes_h, n_bins=n_bins, d=d)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -308,9 +223,14 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
         out_ref[:] = acc_ref[:]
 
 
+def _lane_tile(k: int, C: int):
+    return pl.BlockSpec((k, C), lambda i: (0, i))
+
+
 def _hist_call(bins, nid, stats, *, d, n_nodes, n_bins, block_rows,
                interpret):
-    """Per-shard histogram kernel → [3Lh, F·B] (caller psums)."""
+    """Per-shard histogram kernel over ``bins`` [N, F], ``nid`` [1, N],
+    ``stats`` [3, N] → [3Lh, F·B] (caller psums)."""
     N, F = bins.shape
     C, nblk, n_pad = _tile_geometry(N, block_rows)
     Lh = max(n_nodes // 2, 1)
@@ -318,53 +238,48 @@ def _hist_call(bins, nid, stats, *, d, n_nodes, n_bins, block_rows,
     return pl.pallas_call(
         functools.partial(_hist_kernel, d=d, n_nodes_h=Lh, n_bins=n_bins),
         grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((C, F), lambda i: (i, 0)),
-            pl.BlockSpec((C, 1), lambda i: (i, 0)),
-            pl.BlockSpec((C, 3), lambda i: (i, 0)),
-        ],
+        in_specs=[pl.BlockSpec((C, F), lambda i: (i, 0)),
+                  _lane_tile(1, C), _lane_tile(3, C)],
         out_specs=pl.BlockSpec((3 * Lh, F * n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((3 * Lh, F * n_bins), jnp.float32),
         scratch_shapes=[pltpu.VMEM((3 * Lh, F * n_bins), jnp.float32)],
         interpret=interpret,
-    )(_pad_rows(bins, n_pad), _pad_rows(nid, n_pad).reshape(-1, 1),
-      _pad_rows(stats, n_pad))
+    )(jnp.pad(bins, ((0, n_pad - N), (0, 0))), _pad_lanes(nid, n_pad),
+      _pad_lanes(stats, n_pad))
 
 
 def _partition_kernel(bins_ref, nid_ref, bf_ref, bt_ref, bnal_ref,
                       isp_ref, cs_ref, lmask_ref, newnid_ref, *,
                       n_bins: int):
     newnid_ref[:] = _partition_block(
-        bins_ref[:], nid_ref[:], bf_ref[0], bt_ref[0], bnal_ref[0] != 0,
-        isp_ref[0] != 0, cs_ref[0] != 0, lmask_ref[:] != 0, n_bins=n_bins)
+        bins_ref[:], nid_ref[:], bf_ref[:], bt_ref[:], bnal_ref[:],
+        isp_ref[:], cs_ref[:], lmask_ref[:], n_bins=n_bins)
 
 
 def _partition_call(bins, nid, bf, bt, bnal, isp, cs, lmask, *, n_bins,
                     block_rows, interpret):
-    """Per-shard split+partition kernel → routed node ids [N]."""
-    N, F = bins.shape
+    """Per-shard partition kernel over ``bins`` [F, N], ``nid`` [1, N]
+    and the level's [L] decisions → routed node ids [1, N]."""
+    F, N = bins.shape
     C, nblk, n_pad = _tile_geometry(N, block_rows)
     L = bf.shape[0]
     pallas_policy.record_launch("tree_partition")
     full = lambda *shape: pl.BlockSpec(       # noqa: E731
         shape, lambda i: (0,) * len(shape))
+    col = lambda v: v.astype(jnp.int32)[:, None]   # noqa: E731
     newnid = pl.pallas_call(
         functools.partial(_partition_kernel, n_bins=n_bins),
         grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((C, F), lambda i: (i, 0)),
-            pl.BlockSpec((C, 1), lambda i: (i, 0)),
-            full(1, L), full(1, L), full(1, L), full(1, L), full(1, L),
-            full(L, n_bins - 1),
-        ],
-        out_specs=pl.BlockSpec((C, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+        in_specs=[_lane_tile(F, C), _lane_tile(1, C),
+                  full(L, 1), full(L, 1), full(L, 1), full(L, 1),
+                  full(L, 1), full(n_bins - 1, L)],
+        out_specs=_lane_tile(1, C),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
         interpret=interpret,
-    )(_pad_rows(bins, n_pad), _pad_rows(nid, n_pad).reshape(-1, 1),
-      bf[None, :], bt[None, :], bnal.astype(jnp.int32)[None, :],
-      isp.astype(jnp.int32)[None, :], cs.astype(jnp.int32)[None, :],
-      lmask.astype(jnp.int32))
-    return newnid[:N, 0]
+    )(_pad_lanes(bins, n_pad), _pad_lanes(nid, n_pad),
+      col(bf), col(bt), col(bnal), col(isp), col(cs),
+      lmask.astype(jnp.float32).T)
+    return newnid[:, :N]
 
 
 # ----------------------------------------------------------- entry points
@@ -373,20 +288,22 @@ def _partition_call(bins, nid, bf, bt, bnal, isp, cs, lmask, *, n_bins,
 def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
                 constraints, lo, hi, scalars, *, d: int, n_nodes: int,
                 n_bins: int, block_rows: int, mesh, interpret: bool):
-    """One tree level, fused: returns (hist [L,F,B,3], gain, feat,
-    thresh, na_left, left_val, right_val, leftmask, split, new_nid).
+    """One tree level through the kernels: returns (hist [L,F,B,3],
+    gain, feat, thresh, na_left, left_val, right_val, leftmask, split,
+    new_nid).
 
     Drop-in for grow_tree's per-level XLA sequence (histogram →
     _best_splits → _level_goleft), with identical semantics: ``stats``
-    is the level-invariant [N, 3] {w, w·g, w·h} block, ``prev_hist`` the
+    is the level-invariant [3, N] {w, w·g, w·h} block, ``prev_hist`` the
     previous level's histogram (None at the root — sibling subtraction
     starts at level 1), and the returned ``split`` already folds in the
     min-split-improvement and traced depth-limit masks. Rows must be
     pre-padded to the mesh (N %% data-shards == 0), as grow_tree's are.
 
-    Native mode caps the tile rows at the VMEM-sized suggestion;
-    interpret mode keeps the XLA path's exact block structure so tier-1
-    can assert bitwise parity.
+    Native mode sizes the tile rows for VMEM (ops/pallas.tile_rows —
+    the caller has checked that the level fits); interpret mode keeps
+    the XLA path's exact block structure so tier-1 can assert bitwise
+    parity.
     """
     knobs = jnp.stack([scalars.min_rows, scalars.reg_lambda,
                        scalars.msi]).astype(jnp.float32).reshape(1, 3)
@@ -401,20 +318,14 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
             else jnp.asarray(constraints, jnp.int8)[None, :])
     lo2 = jnp.asarray(lo, jnp.float32)[None, :]
     hi2 = jnp.asarray(hi, jnp.float32)[None, :]
-    if not interpret:
-        block_rows = min(block_rows, pallas_policy.vmem_tile_rows(
-            bins.shape[1], n_bins, n_nodes))
     F = bins.shape[1]
+    if not interpret:
+        tile = pallas_policy.tile_rows(F, n_bins, n_nodes)
+        assert tile > 0, (F, n_bins, n_nodes)
+        block_rows = min(block_rows, tile)
 
-    ndata = mesh.shape[DATA_AXIS]
-    if ndata == 1:
-        return _fused_call(bins, nid, stats, prev_hist, cm2, nb2, iscat,
-                           cons, lo2, hi2, knobs, dl, d=d,
-                           n_nodes=n_nodes, n_bins=n_bins,
-                           block_rows=block_rows, interpret=interpret)
-
-    # sharded: per-shard hist kernel, psum barrier, shared boundary
-    # math, per-shard partition kernel — same bodies, same numbers
+    # per-shard hist kernel, psum barrier, boundary math in XLA,
+    # per-shard partition kernel
     has_cats = iscat is not None
     has_cons = cons is not None
     Lh = max(n_nodes // 2, 1)
@@ -425,10 +336,12 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
 
     @functools.partial(
         shard_map, mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)) + (P(),) * 9,
+        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(None, DATA_AXIS))
+        + (P(),) * 9,
         out_specs=(P(),) * 9 + (P(DATA_AXIS),), check_vma=False)
     def _task(bins_l, nid_l, stats_l, prev, cm2, nb2, iscat_a, cons_a,
               lo2, hi2, knobs, dl):
+        nid_l = nid_l[None, :]
         lh = _hist_call(bins_l, nid_l, stats_l, d=d, n_nodes=n_nodes,
                         n_bins=n_bins, block_rows=block_rows,
                         interpret=interpret)
@@ -439,10 +352,10 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
                 iscat_a[0] != 0 if has_cats else None,
                 cons_a[0] if has_cons else None, lo2[0], hi2[0], knobs,
                 dl, d=d, n_nodes=n_nodes, n_bins=n_bins, n_features=F)
-        newnid_l = _partition_call(bins_l, nid_l, bf, bt, bnal, split,
+        newnid_l = _partition_call(bins_l.T, nid_l, bf, bt, bnal, split,
                                    cs, lmask, n_bins=n_bins,
                                    block_rows=block_rows,
-                                   interpret=interpret)
+                                   interpret=interpret)[0]
         return (hist, bg, bf, bt, bnal, blv, brv, lmask, split, newnid_l)
 
     return _task(bins, nid, stats, prev, cm2, nb2, iscat_in, cons_in,

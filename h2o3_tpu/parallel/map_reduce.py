@@ -21,7 +21,7 @@ import functools
 from typing import Any, Callable
 
 import jax
-from h2o3_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu import telemetry

@@ -29,19 +29,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        """Old-jax adapter: jax.experimental.shard_map spells the VMA
-        check flag ``check_rep``; everything else is call-compatible."""
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 

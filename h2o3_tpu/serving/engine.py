@@ -85,6 +85,15 @@ def _const_nbytes(model) -> int:
     return total
 
 
+def donating_jit(model):
+    """The accelerator's scoring program: a separate jit of the SAME
+    traced fn as ``Model._serve_jit`` (identical HLO → identical
+    numerics) with the input buffer donated — serving inputs are
+    transient, and donation frees a bucket of HBM per dispatch."""
+    import jax
+    return jax.jit(model._serve_dev, donate_argnums=(0,))
+
+
 class CompiledScorer:
     """One model's seat in the scorer cache: its serving schema, the
     jitted device program (shared across row buckets — XLA keys the
@@ -117,11 +126,7 @@ class CompiledScorer:
                 # serving cache and vice versa
                 base = model._serve_jit()
             else:
-                # accelerator: a separate jit of the SAME traced fn
-                # (identical HLO → identical numerics) with the input
-                # buffer donated — serving inputs are transient, and
-                # donation frees a bucket of HBM per dispatch
-                base = jax.jit(model._serve_dev, donate_argnums=(0,))
+                base = donating_jit(model)
             self.serve = observed_jit(f"serving.{self.algo}")(base)
         self.const_nbytes = _const_nbytes(model)
 

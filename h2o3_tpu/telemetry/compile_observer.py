@@ -85,12 +85,11 @@ def aot_source_names():
     with _aot_lock:
         return sorted(_aot_sources)
 
-_COMPILE_EVENTS = ("backend_compile_duration",      # jax >= 0.4.31
-                   "backend_compile_time_sec")      # older spelling
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _on_duration(name: str, secs: float, **kw) -> None:
-    if not name.endswith(_COMPILE_EVENTS):
+    if name != _COMPILE_EVENT:
         return
     counter("xla_compile_total").inc()
     histogram("xla_compile_seconds").observe(secs)

@@ -1,7 +1,7 @@
 """Perf-regression baselines — persisted step-time/MFU floors per fit
 shape, and the gauge the ``fit_step_regression`` SLO rule watches.
 
-BENCH_r01–r05 exist but nothing ever compared them; this module is the
+BENCH_r03–r05 exist but nothing ever compared them; this module is the
 in-process half of that guard (scripts/benchdiff.py is the offline
 half). Every profiled fit (telemetry/stepprof.py finish) records its
 mean step time under a baseline key

@@ -7,10 +7,12 @@ fit against the accelerator roofline the way DrJAX (arxiv 2403.07128)
 sizes its MapReduce primitives against peak and the Julia-to-TPU
 pipeline (arxiv 1810.09868) reports utilization per compiled program:
 
-- :func:`device_peaks` detects peak FLOP/s and HBM bandwidth per
-  backend (device_kind table for TPU generations, conservative
-  estimates for cpu/gpu, ``H2O3TPU_PEAK_FLOPS`` /
-  ``H2O3TPU_PEAK_HBM_GBPS`` overrides);
+- :func:`device_peaks` looks the device's peak FLOP/s and HBM
+  bandwidth up in a device_kind table of published TPU numbers
+  (``H2O3TPU_PEAK_FLOPS`` / ``H2O3TPU_PEAK_HBM_GBPS`` override). A
+  device that is not in the table has NO peaks (``source: "unknown"``)
+  and its fits' MFU / HBM utilization are not measured — never another
+  chip's number, never an invented CPU or GPU "peak";
 - per-fit work has two legs: **analytic** — closed-form per-algo
   estimates (GBM histogram matmuls, GLM IRLS Gram builds, DL dense
   fwd+bwd) — always drive the fit-level totals, and **cost_analysis**
@@ -60,33 +62,22 @@ _TPU_PEAKS: List[Tuple[str, float, float]] = [
     ("v3", 123e12, 900e9),
     ("v2", 45e12, 700e9),
 ]
-# conservative single-socket estimates where the backend publishes no
-# spec: utilization numbers stay comparable run-to-run, not absolute
-_CPU_PEAK = (1.0e11, 2.0e10)       # ~100 GFLOP/s, ~20 GB/s
-_GPU_PEAK = (1.0e13, 1.0e12)       # generic accelerator fallback
-
 _peaks_lock = threading.Lock()
 _peaks_cache: Optional[Dict] = None
 
 
 def peaks_for(device_kind: str, platform: str = "") -> Dict:
-    """Pure table lookup (no jax import) — also the bench stub path."""
+    """Pure table lookup (no jax import) — also the bench stub path.
+    A device_kind the table does not list yields ``flops`` and
+    ``hbm_bytes_per_s`` of None with ``source: "unknown"``."""
     kind = (device_kind or "").lower()
-    plat = (platform or "").lower()
     for sub, flops, bw in _TPU_PEAKS:
         if sub in kind:
             return {"flops": flops, "hbm_bytes_per_s": bw,
                     "device_kind": device_kind,
                     "source": f"tpu-spec:{sub}"}
-    if "tpu" in kind or plat == "tpu":
-        flops, bw = _TPU_PEAKS[0][1], _TPU_PEAKS[0][2]
-        return {"flops": flops, "hbm_bytes_per_s": bw,
-                "device_kind": device_kind, "source": "tpu-unknown"}
-    if plat in ("gpu", "cuda", "rocm") or "gpu" in kind:
-        return {"flops": _GPU_PEAK[0], "hbm_bytes_per_s": _GPU_PEAK[1],
-                "device_kind": device_kind, "source": "gpu-estimate"}
-    return {"flops": _CPU_PEAK[0], "hbm_bytes_per_s": _CPU_PEAK[1],
-            "device_kind": device_kind or "cpu", "source": "cpu-estimate"}
+    return {"flops": None, "hbm_bytes_per_s": None,
+            "device_kind": device_kind or platform, "source": "unknown"}
 
 
 def device_peaks(refresh: bool = False) -> Dict:
@@ -357,10 +348,13 @@ def record_model_fit(builder, model, frame, x, seconds: float,
                 if kc is not None:
                     break
         peaks = device_peaks()
-        agg_flops = peaks["flops"] * peaks.get("devices", 1)
-        agg_bw = peaks["hbm_bytes_per_s"] * peaks.get("devices", 1)
-        mfu = flops / (seconds * agg_flops) if agg_flops else 0.0
-        hbm = bytes_ / (seconds * agg_bw) if agg_bw else 0.0
+        # a device with no published peaks: work totals are recorded,
+        # utilization is not measured (None; the gauges stay unset)
+        ndev = peaks.get("devices", 1)
+        mfu = (flops / (seconds * peaks["flops"] * ndev)
+               if peaks["flops"] else None)
+        hbm = (bytes_ / (seconds * peaks["hbm_bytes_per_s"] * ndev)
+               if peaks["hbm_bytes_per_s"] else None)
         rec = {"algo": algo, "seconds": round(seconds, 4),
                "flops": flops, "bytes": bytes_,
                "mfu": mfu, "hbm_util": hbm, "source": source,
@@ -369,8 +363,10 @@ def record_model_fit(builder, model, frame, x, seconds: float,
                "peak_hbm_bytes_per_s": peaks["hbm_bytes_per_s"],
                "devices": peaks.get("devices", 1),
                "device_kind": peaks["device_kind"]}
-        gauge("model_fit_mfu", algo=algo).set(mfu)
-        gauge("model_fit_hbm_util", algo=algo).set(hbm)
+        if mfu is not None:
+            gauge("model_fit_mfu", algo=algo).set(mfu)
+        if hbm is not None:
+            gauge("model_fit_hbm_util", algo=algo).set(hbm)
         counter("roofline_fits_total", algo=algo, source=source).inc()
         roofline_meta = {"flops": flops, "bytes": bytes_,
                          "source": source, "seconds": round(seconds, 4)}
@@ -378,8 +374,9 @@ def record_model_fit(builder, model, frame, x, seconds: float,
             roofline_meta["kernel_cost"] = kc
         # unrounded: a toy fit's MFU on a big mesh is legitimately tiny
         # and must survive into the capsule as nonzero
-        spans_mod.annotate(mfu=mfu, hbm_util=hbm,
-                           roofline=roofline_meta)
+        if mfu is not None and hbm is not None:
+            spans_mod.annotate(mfu=mfu, hbm_util=hbm)
+        spans_mod.annotate(roofline=roofline_meta)
         # per-fit record on the MODEL: model_fit_mfu{algo} is a
         # latest-wins gauge, so concurrent fits of the same algo
         # (scheduler-spread grids) overwrite each other there — the

@@ -346,7 +346,7 @@ def _barrier_probe(mesh) -> None:
     if ent is None:
         n = mesh.shape[mesh_mod.DATA_AXIS]
 
-        @functools.partial(mesh_mod.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(mesh_mod.DATA_AXIS), out_specs=P(),
                            check_vma=False)
         def _ps(x):

@@ -2,7 +2,7 @@
 """benchdiff — diff two BENCH_*.json artifacts / perf-baseline snapshots
 into a pass/fail table with per-phase deltas.
 
-BENCH_r01–r05 exist but nothing ever compared them; this is the offline
+BENCH_r03–r05 exist but nothing ever compared them; this is the offline
 half of the perf-regression guard (telemetry/perfbase.py is the
 in-process half). Pure stdlib — runs anywhere, jax-free, in well under
 a second (the scripts/tier1.sh ``perfguard`` target runs it against the

@@ -1,9 +1,11 @@
 #!/usr/bin/env bash
 # tier1.sh — the blessed tier-1 entry points.
 #
-# The full tier-1 suite does not fit the 870s per-invocation cap on the
-# ~1.8x-slow CI container, which used to force ad-hoc hand-picked
-# two-part runs. This script splits the suite DETERMINISTICALLY:
+# Run in ONE process (`-p no:xdist`, as below), the full tier-1 suite
+# does not fit an 870s per-invocation cap; the driver instead runs it
+# under pytest-xdist (`-p xdist -n 6 --dist loadfile`, 8 cores, ~8 min).
+# For single-process runs this script splits the suite
+# DETERMINISTICALLY:
 # `tests/test_*.py` are sorted lexically and alternated by index, and
 # the `-m multiprocess` pod legs (real 2-process gloo clouds — minutes
 # each, clustered in a few files) are carved out into their own target

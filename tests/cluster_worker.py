@@ -21,11 +21,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 os.environ.setdefault("H2O3TPU_HEARTBEAT_INTERVAL_S", "0.5")
 os.environ.setdefault("H2O3TPU_CLUSTER_METRICS_INTERVAL_S", "0.2")
 os.environ.setdefault("H2O3TPU_CLUSTER_METRICS_STALE_S", "2.0")
-# share compiled executables with the other worker processes (identical
-# binaries out of jax's persistent cache; numerics unchanged)
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.environ.get("TMPDIR", "/tmp"), "h2o3tpu-test-xlacache"))
 
 sys.path.insert(0,
                 os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

@@ -8,15 +8,9 @@ CPU devices.
 
 import os
 
-# --xla_cpu_use_thunk_runtime=false: the new CPU thunk runtime in this
-# jaxlib intermittently segfaults inside backend_compile_and_load after
-# a few hundred compilations in one process (observed twice mid-suite,
-# different tests each time); the legacy runtime is stable.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
     _flags += " --xla_force_host_platform_device_count=8"
-if "--xla_cpu_use_thunk_runtime" not in _flags:
-    _flags += " --xla_cpu_use_thunk_runtime=false"
 os.environ["XLA_FLAGS"] = _flags.strip()
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

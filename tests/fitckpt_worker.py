@@ -22,11 +22,6 @@ import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-# the fit and resume legs compile the same kernel shapes — share the
-# executables through jax's persistent cache (identical binaries)
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.environ.get("TMPDIR", "/tmp"), "h2o3tpu-test-xlacache"))
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -34,10 +34,6 @@ os.environ["H2O3TPU_HEARTBEAT_INTERVAL_S"] = "0.25"
 # fresh load reads + quick adoption during the short test window
 os.environ["H2O3TPU_FLEET_LOAD_TTL_S"] = "0.2"
 os.environ["H2O3TPU_FLEET_ADOPT_S"] = "0.5"
-# both legs compile the SAME GBM kernel shapes — share the executables
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.environ.get("TMPDIR", "/tmp"), "h2o3tpu-test-xlacache"))
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -44,9 +44,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2"
                            if mode == "ref"
                            else "--xla_force_host_platform_device_count=1")
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.environ.get("TMPDIR", "/tmp"), "h2o3tpu-test-xlacache"))
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

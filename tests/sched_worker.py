@@ -33,12 +33,6 @@ os.environ["H2O3TPU_BATCH_MODELS"] = "off"
 # fast dead-peer detection for the kill leg (staleness = interval * 3)
 os.environ["H2O3TPU_HEARTBEAT_INTERVAL_S"] = "0.25"
 os.environ["H2O3TPU_SCHEDULER_POLL_S"] = "0.05"
-# all five worker processes (ref + run×2 + kill×2) compile the SAME
-# GBM kernel shapes — share the executables across the sequential legs
-# (identical binaries, so bit-parity is unaffected by who compiled)
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.environ.get("TMPDIR", "/tmp"), "h2o3tpu-test-xlacache"))
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -1,0 +1,321 @@
+"""The chip's compiler, without the chip: every program on the main path
+of ``chip_smoke.py`` is compiled for a described TPU v5e (2x2) at the
+smoke's real widths, in this process, with the installed libtpu.
+
+What interpret mode cannot show, this does: a kernel Mosaic refuses, a
+tile that outgrows the 16 MB of scoped VMEM, a program that outgrows
+the chip's HBM, a missing all-reduce on the four-chip mesh. Nothing
+runs — a compile that passes is not a chip run.
+
+This is the ONE file of chip-compiler tests: only one process may hold
+libtpu, so the topology is described inside a module-scoped fixture of
+this file (never at import, in a skipif, in parametrize or in
+conftest.py) and every compile happens in the test's own process with
+the persistent compile cache off (a cache entry written for a described
+device cannot be read back without one).
+
+The programs are taken from the system itself: a tiny fit on the CPU
+test mesh leaves each jitted entry point's call signature with the
+compile observer; the test re-lowers that same call with the row axis
+at the smoke's size, the arguments placed on the described devices, and
+the tree-kernel mode that ``auto`` resolves to on a TPU.
+"""
+
+import contextlib
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+import h2o3_tpu
+from h2o3_tpu.ops import pallas as plx
+from h2o3_tpu.parallel import mesh as mesh_mod
+from h2o3_tpu.telemetry import compile_observer
+
+# the smoke's widths (chip_smoke.py): the airlines frame, binned
+AIR_ROWS, F, B = 5_000_000, 10, 126
+AIR_CATS = (False,) * 6 + (True,) * 3 + (False,)
+GLM_ROWS, DL_ROWS, SCORE_ROWS = 2_000_000, 200_000, 100_000
+TINY_ROWS = 3000
+LEVELS = (0, 3, 5)                       # of depth-bucket 6
+
+S = jax.ShapeDtypeStruct
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _mesh(topo, n):
+    return Mesh(np.array(topo.devices[:n]).reshape(n, 1),
+                (mesh_mod.DATA_AXIS, mesh_mod.MODEL_AXIS))
+
+
+@contextlib.contextmanager
+def _as_global_mesh(mesh):
+    """The jitted entry points read the process mesh while they trace;
+    hand them the described chips for the length of one lowering."""
+    old = mesh_mod.get_mesh()
+    mesh_mod.set_global_mesh(mesh)
+    try:
+        yield mesh
+    finally:
+        mesh_mod.set_global_mesh(old)
+
+
+def _auto_on_tpu(n_shards):
+    mode, _ = plx.decide("auto", "tpu", n_shards, True)
+    return mode
+
+
+def _compiled_text(lowered):
+    return lowered.compile().as_text()
+
+
+# ------------------------------------------------------- the level pass
+
+
+def _lower_level_pass(mesh, d):
+    """One tree level as grow_tree runs it when ``auto`` resolves on a
+    TPU: the kernels where the level fits a tile, else XLA."""
+    from h2o3_tpu.models.tree import TreeScalars
+    from h2o3_tpu.ops.pallas import treekernel as tk
+    n = mesh_mod.padded_rows(AIR_ROWS, mesh)
+    L = 2 ** d
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    rep = NamedSharding(mesh, P())
+    kernels = (_auto_on_tpu(mesh.shape[mesh_mod.DATA_AXIS]) == "native"
+               and plx.tile_rows(F, B, L) > 0)
+    is_cat = jnp.asarray(np.array(AIR_CATS))
+    sc = TreeScalars(jnp.float32(10.0), jnp.float32(1.0),
+                     jnp.float32(1e-5), jnp.int32(6))
+    kw = dict(d=d, n_nodes=L, n_bins=B, block_rows=4096, mesh=mesh)
+
+    def level(bins, nid, stats, prev, cm, nb, lo, hi):
+        if kernels:
+            return tk.fused_level(bins, nid, stats, prev, cm, nb, is_cat,
+                                  None, lo, hi, sc, interpret=False, **kw)
+        return tk.xla_level(bins, nid, stats[0], stats[1], stats[2], prev,
+                            cm, nb, is_cat, None, lo, hi, sc, **kw)
+
+    prev = (S((L // 2, F, B, 3), jnp.float32, sharding=rep) if d else None)
+    lowered = jax.jit(level).lower(
+        S((n, F), jnp.int8, sharding=row), S((n,), jnp.int32, sharding=row),
+        S((3, n), jnp.float32,
+          sharding=NamedSharding(mesh, P(None, mesh_mod.DATA_AXIS))),
+        prev, S((F,), jnp.bool_, sharding=rep),
+        S((F,), jnp.int32, sharding=rep), S((L,), jnp.float32, sharding=rep),
+        S((L,), jnp.float32, sharding=rep))
+    return lowered, kernels
+
+
+@pytest.mark.parametrize("d", LEVELS)
+def test_level_pass_one_chip(topo, d):
+    lowered, kernels = _lower_level_pass(_mesh(topo, 1), d)
+    txt = _compiled_text(lowered)
+    assert ("tpu_custom_call" in txt) == kernels
+
+
+@pytest.mark.parametrize("d", LEVELS)
+def test_level_pass_four_chips(topo, d):
+    lowered, kernels = _lower_level_pass(_mesh(topo, 4), d)
+    txt = _compiled_text(lowered)
+    assert "all-reduce" in txt, "the histogram is not reduced over 'data'"
+    assert ("tpu_custom_call" in txt) == kernels
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_smoke_level_check(topo, chips):
+    """chip_smoke.py's own kernels-vs-XLA program, as it runs there."""
+    import chip_smoke
+    mesh = _mesh(topo, chips)
+    n = 65_536
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    vec = S((n,), jnp.float32, sharding=row)
+    chip_smoke.level_check(mesh, B, np.array(AIR_CATS), interpret=False) \
+        .lower(S((n, F), jnp.int8, sharding=row),
+               S((F,), jnp.int32, sharding=NamedSharding(mesh, P())),
+               vec, vec, vec).compile()
+
+
+def test_auto_on_tpu_takes_the_kernels_at_the_smoke_widths():
+    """What the two tests above compiled IS the kernel path."""
+    assert _auto_on_tpu(1) == "native"
+    assert all(plx.tile_rows(F, B, 2 ** d) > 0 for d in range(6))
+
+
+# ------------------------------------------- every Pallas kernel, native
+
+
+def _one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("d", LEVELS)
+def test_tree_hist_kernel_native(topo, d):
+    from h2o3_tpu.ops.pallas import treekernel as tk
+    one, n, L = _one_chip(topo), 1 << 20, 2 ** d
+    jax.jit(lambda b, i, s: tk._hist_call(
+        b, i, s, d=d, n_nodes=L, n_bins=B,
+        block_rows=plx.tile_rows(F, B, L), interpret=False)).lower(
+        S((n, F), jnp.int8, sharding=one), S((1, n), jnp.int32, sharding=one),
+        S((3, n), jnp.float32, sharding=one)).compile()
+
+
+@pytest.mark.parametrize("d", LEVELS)
+def test_tree_partition_kernel_native(topo, d):
+    from h2o3_tpu.ops.pallas import treekernel as tk
+    one, n, L = _one_chip(topo), 1 << 20, 2 ** d
+    vec = lambda dt: S((L,), dt, sharding=one)   # noqa: E731
+    jax.jit(lambda b, i, bf, bt, na, sp, cs, lm: tk._partition_call(
+        b, i, bf, bt, na, sp, cs, lm, n_bins=B,
+        block_rows=plx.tile_rows(F, B, L), interpret=False)).lower(
+        S((F, n), jnp.int8, sharding=one), S((1, n), jnp.int32, sharding=one),
+        vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), vec(jnp.bool_),
+        vec(jnp.bool_), S((L, B - 1), jnp.bool_, sharding=one)).compile()
+
+
+def test_opt_in_histogram_kernel_native(topo):
+    """ops/pallas_histogram.py, reachable only through its own switch,
+    at the block size ops/histogram.py gives it."""
+    from h2o3_tpu.ops.pallas_histogram import pallas_local_histogram
+    one, n = _one_chip(topo), 1 << 16
+    jax.jit(lambda b, i, s: pallas_local_histogram(
+        b, i, s, 32, B, block_rows=512)).lower(
+        S((n, F), jnp.int8, sharding=one), S((n,), jnp.int32, sharding=one),
+        S((n, 3), jnp.float32, sharding=one)).compile()
+
+
+# --------------------------------- the fits' and the scorer's own programs
+
+
+def _at_scale(tree, n_tiny, n_real, mesh):
+    """A recorded call signature with the row axis grown to ``n_real``
+    and every array placed on ``mesh`` (rows sharded over 'data')."""
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    rep = NamedSharding(mesh, P())
+
+    def place(x):
+        if not isinstance(x, S):
+            return x
+        if x.shape and x.shape[0] == n_tiny:
+            return S((n_real,) + x.shape[1:], x.dtype, sharding=row)
+        return S(x.shape, x.dtype, sharding=rep)
+
+    return jax.tree_util.tree_map(place, tree)
+
+
+def _lower_recorded(name, mesh, n_tiny, rows, **static_overrides):
+    jit_fn, aargs, akwargs = compile_observer.aot_source(name)
+    n_real = mesh_mod.padded_rows(rows, mesh)
+    akwargs = dict(_at_scale(akwargs, n_tiny, n_real, mesh))
+    akwargs.update(static_overrides)
+    aargs = _at_scale(aargs, n_tiny, n_real, mesh)
+    assert any(x.shape[:1] == (n_real,)
+               for x in jax.tree_util.tree_leaves(aargs)
+               if isinstance(x, S)), "no argument carries the row axis"
+    with _as_global_mesh(mesh):
+        return jit_fn.lower(*aargs, **akwargs)
+
+
+@pytest.fixture(scope="module")
+def tiny_gbm(tmp_path_factory):
+    """A GBM fit on the smoke's schema, small, on the CPU test mesh."""
+    from h2o3_tpu.io.stream import stream_import_csv
+    from h2o3_tpu.models.gbm import GBMEstimator
+    from h2o3_tpu.utils.synth import AIRLINES_RESPONSE, write_airlines_csv
+    path = str(tmp_path_factory.mktemp("chipcompile") / "air.csv")
+    write_airlines_csv(path, TINY_ROWS, seed=0)
+    fr = stream_import_csv(path)
+    model = GBMEstimator(ntrees=2, max_depth=6, seed=1).train(
+        fr, y=AIRLINES_RESPONSE)
+    assert model.bm.nbins_total == B and model.bm.bins.shape[1] == F
+    assert tuple(bool(c) for c in model.bm.is_cat) == AIR_CATS
+    yield model, fr.nrows_padded
+    h2o3_tpu.DKV.remove(model.key)
+    h2o3_tpu.DKV.remove(fr.key)
+
+
+@pytest.mark.allow_key_leak          # the module-scoped fit above
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gbm_boost_chunk(topo, tiny_gbm, chips):
+    """The 25-tree compiled scan of the flagship fit, 5M rows."""
+    _, n_tiny = tiny_gbm
+    mesh = _mesh(topo, chips)
+    tp = compile_observer.aot_source("gbm.boost_scan")[2]["tp"]
+    txt = _compiled_text(_lower_recorded(
+        "gbm.boost_scan", mesh, n_tiny, AIR_ROWS, ntrees=25,
+        tp=dataclasses.replace(tp, pallas=_auto_on_tpu(chips))))
+    assert "tpu_custom_call" in txt
+    assert ("all-reduce" in txt) == (chips > 1)
+
+
+@pytest.mark.allow_key_leak
+def test_predict_forest(topo, tiny_gbm):
+    from h2o3_tpu.models.tree import predict_forest
+    model, _ = tiny_gbm
+    one = _one_chip(topo)
+    forest = jax.tree_util.tree_map(
+        lambda a: S((50,) + a.shape[1:], a.dtype, sharding=one),
+        model.forest)
+    predict_forest.lower(forest, S((SCORE_ROWS, F), jnp.int8, sharding=one),
+                         B=B).compile()
+
+
+@pytest.mark.allow_key_leak
+def test_serving_jit_with_donation(topo, tiny_gbm, monkeypatch):
+    """serving/engine.py's accelerator branch: the donated-input jit of
+    the model's scoring program, at the 8-row bucket."""
+    from h2o3_tpu.serving.engine import donating_jit
+    model, _ = tiny_gbm
+    # closed-over device arrays would pin the lowering to the CPU mesh
+    monkeypatch.setattr(model, "forest", jax.tree_util.tree_map(
+        np.asarray, model.forest))
+    donating_jit(model).lower(
+        S((8, F), jnp.int8, sharding=_one_chip(topo))).compile()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_glm_irls_solve(topo, chips):
+    from h2o3_tpu.models.glm import GLMEstimator
+    r = np.random.RandomState(3)
+    X = r.randn(TINY_ROWS, 28).astype(np.float32)
+    cols = {f"x{i}": X[:, i] for i in range(28)}
+    cols["y"] = np.array(["b", "s"], object)[(X[:, 0] > 0).astype(int)]
+    fr = h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
+    GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
+                 max_iterations=2, standardize=True).train(fr, y="y")
+    txt = _compiled_text(_lower_recorded(
+        "glm.irls_solve", _mesh(topo, chips), fr.nrows_padded, GLM_ROWS))
+    assert ("all-reduce" in txt) == (chips > 1)
+
+
+def test_dl_train_chunk(topo):
+    from h2o3_tpu.models.deeplearning import DeepLearningEstimator
+    r = np.random.RandomState(5)
+    X = (r.rand(TINY_ROWS, 784) > 0.8).astype(np.float32)
+    cols = {f"p{i}": X[:, i] for i in range(784)}
+    cols["label"] = r.randint(0, 10, TINY_ROWS).astype(str)
+    fr = h2o3_tpu.Frame.from_numpy(cols, categorical=["label"])
+    DeepLearningEstimator(hidden=[200, 200], activation="rectifier",
+                          epochs=0.1, seed=1).train(fr, y="label")
+    # the smoke's fit: 200k rows give a 2048-row batch, 200-step chunks
+    _lower_recorded("dl.train_chunk", _mesh(topo, 1), fr.nrows_padded,
+                    DL_ROWS, n=DL_ROWS, batch=2048, nsteps=200).compile()

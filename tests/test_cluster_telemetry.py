@@ -250,7 +250,19 @@ def test_cluster_views_equal_local_on_single_process():
     assert a["__bytes__"] == b["__bytes__"]
 
 
-def test_cloud_nodes_carry_metrics_summary():
+@pytest.fixture()
+def declared_peaks(monkeypatch):
+    """The CPU test mesh has no published peaks, so utilization is not
+    measured there; a test of the MFU plumbing declares peaks through
+    the override variables (and drops them from the cache afterwards)."""
+    monkeypatch.setenv("H2O3TPU_PEAK_FLOPS", "1e11")
+    monkeypatch.setenv("H2O3TPU_PEAK_HBM_GBPS", "20")
+    yield roofline.device_peaks(refresh=True)
+    monkeypatch.undo()
+    roofline.device_peaks(refresh=True)
+
+
+def test_cloud_nodes_carry_metrics_summary(declared_peaks):
     """Satellite: /3/Cloud per-node blocks gain the fan-in summary and
     the published process identity (no more default-0 guess)."""
     from h2o3_tpu.api.server import _cloud
@@ -259,7 +271,7 @@ def test_cloud_nodes_carry_metrics_summary():
     for nd in out["nodes"]:
         assert "metrics_summary" in nd
         assert nd["process_index"] == 0
-        assert nd["gflops"] > 0
+        assert nd["gflops"] == declared_peaks["flops"] / 1e9
         ms = nd["metrics_summary"]
         assert {"jobs_inflight", "last_publish_age_s", "peak_hbm",
                 "stale"} <= set(ms)
@@ -333,13 +345,19 @@ def _mk_class_frame(n, f, seed=0):
     return h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
 
 
-def test_device_peaks_nonzero_and_tpu_table():
-    p = roofline.device_peaks()
-    assert p["flops"] > 0 and p["hbm_bytes_per_s"] > 0
+def test_device_peaks_table_and_unknown_device():
+    p = roofline.device_peaks(refresh=True)
     assert p["devices"] == 8          # the conftest mesh
+    # the CPU publishes no peak: nothing is invented for it
+    assert p["source"] == "unknown"
+    assert p["flops"] is None and p["hbm_bytes_per_s"] is None
     assert roofline.peaks_for("TPU v5 lite")["flops"] == 197e12
     assert roofline.peaks_for("TPU v5p")["flops"] == 459e12
-    assert roofline.peaks_for("", "cpu")["source"] == "cpu-estimate"
+    # a TPU the table does not list never borrows another chip's number
+    unknown = roofline.peaks_for("TPU v9x", "tpu")
+    assert unknown["source"] == "unknown" and unknown["flops"] is None
+    assert roofline.peaks_for("", "cpu")["source"] == "unknown"
+    assert roofline.peaks_for("NVIDIA H100", "gpu")["flops"] is None
 
 
 def test_analytic_estimators_positive_and_scaling():
@@ -352,7 +370,25 @@ def test_analytic_estimators_positive_and_scaling():
     assert d["flops"] > 0 and d["bytes"] > 0
 
 
-def test_gbm_fit_records_nonzero_mfu_in_gauge_and_capsule():
+def test_fit_on_device_without_peaks_records_work_but_no_mfu():
+    """No published peak for the device: the fit's FLOP/byte totals are
+    recorded, its utilization is not measured (no mfu anywhere)."""
+    assert roofline.device_peaks(refresh=True)["source"] == "unknown"
+    fr = _mk_class_frame(300, 4, seed=8)
+    from h2o3_tpu.models.gbm import GBMEstimator
+    est = GBMEstimator(ntrees=2, max_depth=3, seed=1)
+    m = est.train(fr, y="y")
+    rec = m.output["roofline"]
+    assert rec["flops"] > 0 and rec["bytes"] > 0
+    assert rec["mfu"] is None and rec["hbm_util"] is None
+    cap = flight_recorder.get_capsule(est._job.key)
+    fit = next(s for s in cap.to_dict()["spans"]
+               if s["name"] == "gbm.fit")
+    assert "mfu" not in fit["meta"]
+    assert fit["meta"]["roofline"]["flops"] > 0
+
+
+def test_gbm_fit_records_nonzero_mfu_in_gauge_and_capsule(declared_peaks):
     """Acceptance: a seeded GBM fit reports nonzero model_fit_mfu in
     the registry AND in its flight-recorder capsule's fit span."""
     fr = _mk_class_frame(600, 5, seed=3)
@@ -370,7 +406,7 @@ def test_gbm_fit_records_nonzero_mfu_in_gauge_and_capsule():
     assert fit["meta"]["roofline"]["flops"] > 0
 
 
-def test_dl_fit_records_nonzero_mfu_in_gauge_and_capsule():
+def test_dl_fit_records_nonzero_mfu_in_gauge_and_capsule(declared_peaks):
     """Acceptance: a DL fit reports nonzero model_fit_mfu too."""
     fr = _mk_class_frame(512, 8, seed=4)
     from h2o3_tpu.models.deeplearning import DeepLearningEstimator

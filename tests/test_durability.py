@@ -605,8 +605,7 @@ def test_init_restore_dir_reforms_cloud_in_fresh_process(tmp_path):
         "import os, sys, json\n"
         "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         "os.environ['XLA_FLAGS'] = "
-        "'--xla_force_host_platform_device_count=8 "
-        "--xla_cpu_use_thunk_runtime=false'\n"
+        "'--xla_force_host_platform_device_count=8'\n"
         f"sys.path.insert(0, {REPO!r})\n"
         "import h2o3_tpu\n"
         f"info = h2o3_tpu.init(backend='cpu', restore_dir={ckpt!r})\n"

@@ -1,6 +1,6 @@
 """Job-level infra-error retry (round-3 VERDICT item #10).
 
-A transient XLA/remote_compile INTERNAL error must not permanently fail
+A transient XLA INTERNAL/UNAVAILABLE error must not permanently fail
 a job (in round 2 one such blip killed an AutoML step for good); user
 errors must still fail fast with no retry. Since the fault-tolerance
 layer the retry policy is shared (core/watchdog.py): bounded attempts +
@@ -31,7 +31,7 @@ def test_infra_error_retried():
         calls["n"] += 1
         if calls["n"] == 1:
             raise FakeXlaRuntimeError(
-                "INTERNAL: From /job:tpu_worker/replica:0: remote_compile "
+                "INTERNAL: From /job:tpu_worker/replica:0: compile "
                 "failed: UNAVAILABLE: socket closed")
         return "ok"
 
@@ -49,7 +49,7 @@ def test_infra_retries_bounded_by_config(monkeypatch):
 
     def always_down(job):
         calls["n"] += 1
-        raise FakeXlaRuntimeError("INTERNAL: remote_compile failed")
+        raise FakeXlaRuntimeError("UNAVAILABLE: TPU worker process crashed")
 
     with pytest.raises(FakeXlaRuntimeError):
         Job("dead step").start(always_down)
@@ -70,11 +70,11 @@ def test_user_error_fails_fast():
 
 def test_background_job_records_failure():
     def always_down(job):
-        raise FakeXlaRuntimeError("INTERNAL: remote_compile failed")
+        raise FakeXlaRuntimeError("UNAVAILABLE: TPU worker process crashed")
 
     j = Job("bg dead").start(always_down, background=True).join(30)
     assert j.status == FAILED
-    assert "remote_compile" in j.exception
+    assert "TPU worker process crashed" in j.exception
 
 
 def test_is_infra_error_classification():

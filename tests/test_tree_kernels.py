@@ -61,7 +61,7 @@ def _assert_level_parity(bins, w, g, h, cm, nb, is_cat, constraints,
 
     @jax.jit
     def sweep_fused(bins, w, g, h, cm, nb, lo, hi):
-        stats = jnp.stack([w, w * g, w * h], axis=1).astype(jnp.float32)
+        stats = jnp.stack([w, w * g, w * h]).astype(jnp.float32)
         outs, prev = [], None
         nid = jnp.zeros((bins.shape[0],), jnp.int32)
         for d in range(depth + 1):
@@ -153,7 +153,7 @@ def test_parity_per_node_col_mask():
     def run(bins, w, g, h, cm):
         # two shared warmup levels, then a d=2 level through BOTH
         # paths with the per-node mask
-        stats = jnp.stack([w, w * g, w * h], axis=1).astype(jnp.float32)
+        stats = jnp.stack([w, w * g, w * h]).astype(jnp.float32)
         nid = jnp.zeros((bins.shape[0],), jnp.int32)
         prev = None
         for d in range(2):
