@@ -272,17 +272,21 @@ class Job:
                                        desc=self.description):
                     _body()
             finally:
-                for fin in self._finalizers:
-                    try:
-                        fin()
-                    except Exception:   # noqa: BLE001 - best-effort
-                        pass
-                flight_recorder.detach(handle, status=self.status)
-                telemetry.gauge("jobs_inflight").add(-1)
-                telemetry.counter("jobs_completed_total",
-                                  status=self.status).inc()
-                telemetry.histogram("job_duration_seconds").observe(
-                    (self.end_time or time.time()) - self.start_time)
+                # what a job still does once its root span has closed
+                # (the capsule wants that span whole): a root span of
+                # its own, so the tail is charged to the program too
+                with telemetry.span("job.finish", key=self.key):
+                    for fin in self._finalizers:
+                        try:
+                            fin()
+                        except Exception:   # noqa: BLE001 - best-effort
+                            pass
+                    flight_recorder.detach(handle, status=self.status)
+                    telemetry.gauge("jobs_inflight").add(-1)
+                    telemetry.counter("jobs_completed_total",
+                                      status=self.status).inc()
+                    telemetry.histogram("job_duration_seconds").observe(
+                        (self.end_time or time.time()) - self.start_time)
 
         if background:
             self._thread = threading.Thread(target=_run, daemon=True, name=self.key)

@@ -17,8 +17,6 @@ UniformAdaptive initializer match the reference's semantics.
 
 from __future__ import annotations
 
-import time
-
 from functools import partial
 from typing import Dict, List, Optional, Sequence
 
@@ -612,7 +610,6 @@ class DeepLearningEstimator(ModelBuilder):
         from h2o3_tpu.telemetry import stepprof
         while done < total_steps:
             k = min(chunk, total_steps - done)
-            _ct0 = time.time()
             stepprof.chunk_begin()
             with telemetry.span("deeplearning.chunk", steps=k):
                 params_net, opt_state, key = _train_steps_fused(
@@ -621,9 +618,6 @@ class DeepLearningEstimator(ModelBuilder):
                     jnp.int32((done * batch) % max(n, 1)),
                     jnp.float32(k), **sched, **step_kwargs)
                 stepprof.compute_done((params_net, opt_state))
-            telemetry.histogram("train_chunk_seconds",
-                                algo="deeplearning").observe(
-                time.time() - _ct0)
             telemetry.counter("train_iterations_total",
                               algo="deeplearning").inc(k)
             stepprof.chunk_end(steps=k)

@@ -800,19 +800,20 @@ class GBMEstimator(ModelBuilder):
                 (0, frame.nrows_padded - frame.nrows)))
 
         shared_bm = getattr(self, "_cv_shared_bm", None)
-        if ckpt is not None:
-            bm = rebin_for_scoring(ckpt.bm, frame)
-        elif shared_bm is not None:
-            # CV fold models reuse the main model's full-data bin edges
-            # (deliberate: per-fold edge re-sketches cost more than the
-            # sketch approximation is worth; the histogram is adaptive
-            # per node anyway)
-            bm = shared_bm
-        else:
-            # weighted edges: the row-weight ≡ row-multiplicity contract
-            # (pyunit_weights_gbm) must hold through the bin sketch too
-            bm = bin_frame(frame, x, nbins=p["nbins"],
-                           nbins_cats=p["nbins_cats"], weights=wh_host)
+        with telemetry.span("gbm.bin"):
+            if ckpt is not None:
+                bm = rebin_for_scoring(ckpt.bm, frame)
+            elif shared_bm is not None:
+                # CV fold models reuse the main model's full-data bin edges
+                # (deliberate: per-fold edge re-sketches cost more than the
+                # sketch approximation is worth; the histogram is adaptive
+                # per node anyway)
+                bm = shared_bm
+            else:
+                # weighted edges: the row-weight ≡ row-multiplicity contract
+                # (pyunit_weights_gbm) must hold through the bin sketch too
+                bm = bin_frame(frame, x, nbins=p["nbins"],
+                               nbins_cats=p["nbins_cats"], weights=wh_host)
 
         w, w_scale = self._normalize_uniform_weights(w, wh_host)
         if w_scale != 1.0:
@@ -978,7 +979,6 @@ class GBMEstimator(ModelBuilder):
                 scoring_history = list(fc_state["scoring_history"])
             while done < ntrees:
                 kk = min(_chunk, ntrees - done)
-                _ct0 = time.time()
                 stepprof.chunk_begin()
                 with telemetry.span("gbm.chunk", trees=kk):
                     tr_k, margins, vm_, gains, devs = _boost_scan_multi(
@@ -988,8 +988,6 @@ class GBMEstimator(ModelBuilder):
                         ntrees=kk, B=bm.nbins_total, use_val=use_val,
                         tree0=prior_T + done)
                     stepprof.compute_done((margins, vm_, devs))
-                telemetry.histogram("train_chunk_seconds",
-                                    algo="gbm").observe(time.time() - _ct0)
                 telemetry.counter("train_iterations_total",
                                   algo="gbm").inc(kk)
                 stepprof.chunk_end(trees=kk)
@@ -1034,50 +1032,55 @@ class GBMEstimator(ModelBuilder):
                                 for f in Tree._fields))
             model = GBMModel(p, output, forest, bm, f0, "multinomial")
             if not light:
-                probs = jax.nn.softmax(model._margins(bm), axis=1)
-                model.training_metrics = mm.multinomial_metrics(
-                    probs, y_dev, w, domain=rc.domain)
+                with telemetry.span("gbm.rescore"):
+                    probs = jax.block_until_ready(
+                        jax.nn.softmax(model._margins(bm), axis=1))
+                with telemetry.span("gbm.metrics"):
+                    model.training_metrics = mm.multinomial_metrics(
+                        probs, y_dev, w, domain=rc.domain)
         else:
             if category == ModelCategory.BINOMIAL:
                 dist = get_distribution("bernoulli")
             else:
                 dist = get_distribution(dist_name, **p)
-            yv = np.nan_to_num(rc.to_numpy()).astype(np.float32)
-            # host weighted mean from the weight mirror — no device
-            # sync (w is numerically equal, host caches are replicated)
-            mean_y = (float(np.sum(yv * wh_host))
-                      / max(float(np.sum(wh_host)), 1e-12))
-            yv = np.pad(yv, (0, bm.bins.shape[0] - frame.nrows))
-            y_dev = put_sharded(yv, row_sharding(mesh))
-            # offset_column: per-row base margin (GBM.java offset
-            # handling; init_f solved WITH the offset in place)
-            off = None
-            if p.get("offset_column") and p["offset_column"] in frame:
-                onp = np.nan_to_num(
-                    frame.col(p["offset_column"]).to_numpy()
-                ).astype(np.float32)
-                onp = np.pad(onp, (0, bm.bins.shape[0] - frame.nrows))
-                off = put_sharded(jnp.asarray(onp), row_sharding(mesh))
-            if ckpt is not None:
-                f0 = ckpt.f0
-                margin = put_sharded(
-                    ckpt._margins(bm).astype(jnp.float32), row_sharding(mesh))
-                if off is not None:
-                    margin = margin + off
-            elif off is None:
-                f0 = np.float32(dist.init_margin(mean_y))
-                margin = jnp.full((bm.bins.shape[0],), f0, jnp.float32)
-                margin = put_sharded(margin, row_sharding(mesh))
-            else:
-                # Newton solve of the offset-adjusted init
-                # (DistributionFactory init task role)
-                c = jnp.float32(dist.init_margin(mean_y))
-                for _ in range(25):
-                    gsum = jnp.sum(w * dist.grad(y_dev, off + c))
-                    hsum = jnp.sum(w * dist.hess(y_dev, off + c))
-                    c = c - gsum / jnp.maximum(hsum, 1e-12)
-                f0 = np.float32(c)
-                margin = off + f0
+            with telemetry.span("gbm.init"):
+                yv = np.nan_to_num(rc.to_numpy()).astype(np.float32)
+                # host weighted mean from the weight mirror — no device
+                # sync (w is numerically equal, host caches are replicated)
+                mean_y = (float(np.sum(yv * wh_host))
+                          / max(float(np.sum(wh_host)), 1e-12))
+                yv = np.pad(yv, (0, bm.bins.shape[0] - frame.nrows))
+                y_dev = put_sharded(yv, row_sharding(mesh))
+                # offset_column: per-row base margin (GBM.java offset
+                # handling; init_f solved WITH the offset in place)
+                off = None
+                if p.get("offset_column") and p["offset_column"] in frame:
+                    onp = np.nan_to_num(
+                        frame.col(p["offset_column"]).to_numpy()
+                    ).astype(np.float32)
+                    onp = np.pad(onp, (0, bm.bins.shape[0] - frame.nrows))
+                    off = put_sharded(jnp.asarray(onp), row_sharding(mesh))
+                if ckpt is not None:
+                    f0 = ckpt.f0
+                    margin = put_sharded(
+                        ckpt._margins(bm).astype(jnp.float32),
+                        row_sharding(mesh))
+                    if off is not None:
+                        margin = margin + off
+                elif off is None:
+                    f0 = np.float32(dist.init_margin(mean_y))
+                    margin = jnp.full((bm.bins.shape[0],), f0, jnp.float32)
+                    margin = put_sharded(margin, row_sharding(mesh))
+                else:
+                    # Newton solve of the offset-adjusted init
+                    # (DistributionFactory init task role)
+                    c = jnp.float32(dist.init_margin(mean_y))
+                    for _ in range(25):
+                        gsum = jnp.sum(w * dist.grad(y_dev, off + c))
+                        hsum = jnp.sum(w * dist.hess(y_dev, off + c))
+                        c = c - gsum / jnp.maximum(hsum, 1e-12)
+                    f0 = np.float32(c)
+                    margin = off + f0
             output["init_f"] = float(f0)
             voff = None
             if vbm is not None and p.get("offset_column") and \
@@ -1112,7 +1115,6 @@ class GBMEstimator(ModelBuilder):
                     gains_total = fc_state["gains_total"].copy()
                 while done < ntrees:
                     k = min(_chunk, ntrees - done)
-                    _ct0 = time.time()
                     stepprof.chunk_begin()
                     with telemetry.span("gbm.chunk", trees=k):
                         tr_k, margin, gains = _boost_scan(
@@ -1121,9 +1123,6 @@ class GBMEstimator(ModelBuilder):
                             dist=dist, sample_rate=float(p["sample_rate"]),
                             ntrees=k, tree0=prior_T + done)
                         stepprof.compute_done((margin, gains))
-                    telemetry.histogram(
-                        "train_chunk_seconds",
-                        algo="gbm").observe(time.time() - _ct0)
                     telemetry.counter("train_iterations_total",
                                       algo="gbm").inc(k)
                     stepprof.chunk_end(trees=k)
@@ -1174,7 +1173,6 @@ class GBMEstimator(ModelBuilder):
                     scoring_history = list(fc_state["scoring_history"])
                 while done < ntrees:
                     k = min(_chunk, ntrees - done)
-                    _ct0 = time.time()
                     stepprof.chunk_begin()
                     with telemetry.span("gbm.chunk", trees=k):
                         tr_k, margin, vm_, gains, devs = \
@@ -1187,9 +1185,6 @@ class GBMEstimator(ModelBuilder):
                                 ntrees=k, B=bm.nbins_total,
                                 use_val=use_val, tree0=prior_T + done)
                         stepprof.compute_done((margin, vm_, devs))
-                    telemetry.histogram(
-                        "train_chunk_seconds",
-                        algo="gbm").observe(time.time() - _ct0)
                     telemetry.counter("train_iterations_total",
                                       algo="gbm").inc(k)
                     stepprof.chunk_end(trees=k)
@@ -1227,18 +1222,24 @@ class GBMEstimator(ModelBuilder):
             model = GBMModel(p, output, forest, bm, f0, dist_name)
             if light:
                 model.output["default_threshold"] = 0.5
-            elif category == ModelCategory.BINOMIAL:
-                pfin = dist.link_inv(model._margins(bm, off))
-                model.training_metrics = mm.binomial_metrics(pfin, y_dev, w)
-                model.output["default_threshold"] = \
-                    model.training_metrics["max_f1_threshold"]
             else:
                 # recompute margins from the (possibly stop-truncated)
-                # forest — `margin` may include discarded trees
-                mfin = model._margins(bm, off)
-                model.training_metrics = mm.regression_metrics(
-                    dist.link_inv(mfin), y_dev, w,
-                    deviance_fn=lambda yy, pp: dist.deviance(yy, mfin))
+                # forest — `margin` may include discarded trees. The
+                # span ends when the device has scored (the metrics
+                # below would wait for it anyway)
+                with telemetry.span("gbm.rescore"):
+                    mfin = jax.block_until_ready(model._margins(bm, off))
+                with telemetry.span("gbm.metrics"):
+                    if category == ModelCategory.BINOMIAL:
+                        model.training_metrics = mm.binomial_metrics(
+                            dist.link_inv(mfin), y_dev, w)
+                        model.output["default_threshold"] = \
+                            model.training_metrics["max_f1_threshold"]
+                    else:
+                        model.training_metrics = mm.regression_metrics(
+                            dist.link_inv(mfin), y_dev, w,
+                            deviance_fn=lambda yy, pp: dist.deviance(
+                                yy, mfin))
 
         if fc is not None:
             # training finished: a completed model must never resume
@@ -1415,7 +1416,6 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
     while done < ntrees and not all(stopped):
         k = min(_chunk, ntrees - done)
         alive = M - sum(stopped)
-        _ct0 = time.time()
         stepprof.chunk_begin()
         with telemetry.span("gbm.chunk", trees=k, batch=M):
             tr_b, margins, gains_b, devs_b = _boost_scan_batched(
@@ -1423,8 +1423,6 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
                 constraints, interaction_sets, tp=tp0, dist=dist,
                 ntrees=k, tree0=done)
             stepprof.compute_done((margins, devs_b))
-        telemetry.histogram("train_chunk_seconds",
-                            algo="gbm").observe(time.time() - _ct0)
         telemetry.counter("train_iterations_total",
                           algo="gbm").inc(k * alive)
         stepprof.chunk_end(trees=k, batch=M)

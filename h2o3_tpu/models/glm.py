@@ -169,14 +169,15 @@ def _irls_iter(X1, coef, y, w, off, l1, l2, family: str, link: str,
     path reuses one compiled program (GLM.java fitIRLSM per-lambda loop).
     """
     fam = Family(family, tweedie_power, link, theta=theta)
-    eta = X1 @ coef + off
-    mu = fam.linkinv(eta)
-    d = fam.dmu_deta(eta, mu)
-    var = fam.variance(mu)
-    # working response net of the fixed offset (GLMTask with offset)
-    z = eta - off + (y - mu) / jnp.where(jnp.abs(d) < 1e-10, 1e-10, d)
-    w_irls = w * d * d / jnp.maximum(var, 1e-10)
-    dev = jnp.sum(w * fam.deviance(y, mu))
+    with jax.named_scope("glm.reweight"):
+        eta = X1 @ coef + off
+        mu = fam.linkinv(eta)
+        d = fam.dmu_deta(eta, mu)
+        var = fam.variance(mu)
+        # working response net of the fixed offset (GLMTask with offset)
+        z = eta - off + (y - mu) / jnp.where(jnp.abs(d) < 1e-10, 1e-10, d)
+        w_irls = w * d * d / jnp.maximum(var, 1e-10)
+        dev = jnp.sum(w * fam.deviance(y, mu))
 
     mesh = get_mesh()
     from h2o3_tpu.parallel.mesh import MODEL_AXIS
@@ -187,17 +188,19 @@ def _irls_iter(X1, coef, y, w, off, l1, l2, family: str, link: str,
         xtx, xtz, _ = gram_model_sharded(X1, w_irls, z, mesh=mesh)
     else:
         xtx, xtz, _ = gram(X1, w_irls, z, mesh=mesh)
-    nobs = jnp.maximum(jnp.sum(w), 1.0)
-    A = xtx / nobs
-    q = xtz / nobs
-    Pp1 = X1.shape[1]
-    penalize = jnp.concatenate([jnp.ones(Pp1 - 1), jnp.zeros(1)]).astype(A.dtype)
-    if use_l1:
-        new_coef = admm_l1_quadratic(A + l2 * jnp.diag(penalize), q, l1,
-                                     penalize)
-    else:
-        new_coef = cholesky_solve_regularized(A, q, l2, penalize)
-    delta = jnp.max(jnp.abs(new_coef - coef))
+    with jax.named_scope("glm.newton_solve"):
+        nobs = jnp.maximum(jnp.sum(w), 1.0)
+        A = xtx / nobs
+        q = xtz / nobs
+        Pp1 = X1.shape[1]
+        penalize = jnp.concatenate(
+            [jnp.ones(Pp1 - 1), jnp.zeros(1)]).astype(A.dtype)
+        if use_l1:
+            new_coef = admm_l1_quadratic(A + l2 * jnp.diag(penalize), q,
+                                         l1, penalize)
+        else:
+            new_coef = cholesky_solve_regularized(A, q, l2, penalize)
+        delta = jnp.max(jnp.abs(new_coef - coef))
     return new_coef, delta, dev
 
 
@@ -220,7 +223,10 @@ def _irls_solve(X1, coef, y, w, off, l1, l2, beta_eps, max_iter,
       quasi-separable data): undamped Newton oscillates when the MLE
       diverges, so the step is chosen as the best of {full, 1/2, ...,
       1/128, none} by penalized objective — nine cheap matvecs, all
-      fused on device."""
+      fused on device.
+
+    Returns ``(coef, it)``: the coefficients and the iterations the
+    loop ran (an int32 scalar, read with the coefficients)."""
     fam = Family(family, tweedie_power, link, theta=theta)
     steps = jnp.concatenate([2.0 ** -jnp.arange(8, dtype=jnp.float32),
                              jnp.zeros(1, jnp.float32)])
@@ -234,28 +240,34 @@ def _irls_solve(X1, coef, y, w, off, l1, l2, beta_eps, max_iter,
         rel = jnp.abs(obj_prev - obj) / jnp.maximum(jnp.abs(obj), 1e-10)
         return (delta > beta_eps) & (rel > obj_eps) & (it < max_iter)
 
+    # scope names are what a device trace shows of this program
+    # (benchmark/program_trace.py): metadata only, the program is the same
+    @jax.named_scope("glm.irls_iter")
     def body(state):
         coef, _, _, obj, it = state
         full, _, _ = _irls_iter(X1, coef, y, w, off, l1, l2,
                                 family, link, tweedie_power,
                                 theta, use_l1=use_l1)
-        # candidates coef + s*(full-coef); objectives in ONE batched pass
-        cands = coef[None, :] + steps[:, None] * (full - coef)[None, :]
-        mus = fam.linkinv(X1 @ cands.T + off[:, None])       # [N, 9]
-        devs = jnp.sum(w[:, None] * fam.deviance(y[:, None], mus), axis=0)
-        pens = jax.vmap(pen_of)(cands)
-        objs = devs + pens
-        k = jnp.argmin(objs)
-        new_coef = cands[k]
-        delta = jnp.max(jnp.abs(new_coef - coef))
+        with jax.named_scope("glm.line_search"):
+            # candidates coef + s*(full-coef); objectives in ONE batched
+            # pass
+            cands = coef[None, :] + steps[:, None] * (full - coef)[None, :]
+            mus = fam.linkinv(X1 @ cands.T + off[:, None])       # [N, 9]
+            devs = jnp.sum(w[:, None] * fam.deviance(y[:, None], mus),
+                           axis=0)
+            pens = jax.vmap(pen_of)(cands)
+            objs = devs + pens
+            k = jnp.argmin(objs)
+            new_coef = cands[k]
+            delta = jnp.max(jnp.abs(new_coef - coef))
         return new_coef, delta, obj, objs[k], it + 1
 
     # finite sentinels: ±inf would make rel = inf/inf = NaN and the
     # NaN > eps comparison (False) would skip the loop entirely
-    coef, _, _, _, _ = jax.lax.while_loop(
+    coef, _, _, _, it = jax.lax.while_loop(
         cond, body, (coef, jnp.float32(1e30), jnp.float32(-1e30),
                      jnp.float32(1e30), jnp.int32(0)))
-    return coef
+    return coef, it
 
 
 @partial(jax.jit, static_argnames=("family", "link", "use_l1"))
@@ -267,17 +279,18 @@ def _irls_solve_path(X1, coef, y, w, off, l1s, l2s, beta_eps, max_iter,
     lambda-search semantics). A 30-step search previously paid 30
     dispatches per fit; with 3-fold CV and multiple models that
     multiplied into pyunit_glm_seed's 600s timeout. Returns the final
-    (smallest-lambda) coefficients — what the single-model path keeps."""
+    (smallest-lambda) coefficients — what the single-model path keeps —
+    the path ``[L, P+1]`` and the iterations each lambda ran ``[L]``."""
 
     def solve_one(c, l12):
         l1, l2 = l12
-        c = _irls_solve(X1, c, y, w, off, l1, l2, beta_eps, max_iter,
-                        family, link, tweedie_power, theta, obj_eps,
-                        use_l1=use_l1)
-        return c, c
+        c, it = _irls_solve(X1, c, y, w, off, l1, l2, beta_eps, max_iter,
+                            family, link, tweedie_power, theta, obj_eps,
+                            use_l1=use_l1)
+        return c, (c, it)
 
-    coef, path = jax.lax.scan(solve_one, coef, (l1s, l2s))
-    return coef, path
+    coef, (path, its) = jax.lax.scan(solve_one, coef, (l1s, l2s))
+    return coef, path, its
 
 
 @observed_jit("glm.irls_solve_batched")
@@ -292,7 +305,7 @@ def _irls_solve_batched(X1, coef0, y, w, off, l1s, l2s, beta_eps,
     sequentially within ONE model). l1s/l2s/obj_epss ride the vmapped
     axis; X1/y/w/off broadcast. The vmapped while_loop runs until every
     lane converges, freezing finished lanes, so an M-combo sweep costs
-    one dispatch instead of M."""
+    one dispatch instead of M. Returns ``(coefs [M, P+1], its [M])``."""
 
     def one(l1, l2, oe):
         return _irls_solve(X1, coef0, y, w, off, l1, l2, beta_eps,
@@ -763,25 +776,27 @@ class GLMEstimator(ModelBuilder):
 
     def _fit_irlsm(self, X1, yv, w, fam: Family, l1: float, l2: float,
                    coef0, nobs: float, max_iter: int,
-                   beta_eps: float, off=None) -> jax.Array:
+                   beta_eps: float, off=None):
         if off is None:
             off = jnp.zeros((X1.shape[0],), jnp.float32)
         coef = jnp.asarray(coef0, jnp.float32)
-        coef = _irls_solve(X1, coef, yv, w, off, jnp.float32(l1),
+        # device arrays (coefficients, iterations run): the lambda path
+        # warm-starts from the first without a host sync per lambda
+        # (30-step searches × CV folds paid a blocking round trip each —
+        # pyunit_glm_seed timeout)
+        return _irls_solve(X1, coef, yv, w, off, jnp.float32(l1),
                            jnp.float32(l2), jnp.float32(beta_eps),
                            jnp.int32(max_iter),
                            fam.name, fam.link, jnp.float32(fam.p),
                            jnp.float32(fam.theta),
                            jnp.float32(self._objective_eps()),
                            use_l1=l1 > 0)
-        return coef   # device array: the lambda path warm-starts from it
-        # without a host sync per lambda (30-step searches × CV folds
-        # paid a blocking round trip each — pyunit_glm_seed timeout)
 
     def _fit_cod(self, X1, yv, w, fam: Family, l1: float, l2: float,
                  coef0: np.ndarray, max_iter: int, beta_eps: float,
-                 bounds, off=None) -> np.ndarray:
-        """IRLS outer loop with a COD (box-constrained) inner solve."""
+                 bounds, off=None):
+        """IRLS outer loop with a COD (box-constrained) inner solve;
+        returns the coefficients and the iterations run."""
         Pp1 = X1.shape[1]
         if bounds is None:
             lo = jnp.full((Pp1,), -jnp.inf, jnp.float32)
@@ -792,14 +807,15 @@ class GLMEstimator(ModelBuilder):
         if off is None:
             off = jnp.zeros((X1.shape[0],), jnp.float32)
         coef = jnp.asarray(coef0, jnp.float32)
-        for _ in range(max_iter):
+        it = 0
+        for it in range(1, max_iter + 1):
             coef, delta = _irls_iter_cod(
                 X1, coef, yv, w, off, jnp.float32(l1), jnp.float32(l2),
                 lo, hi, fam.name, fam.link, jnp.float32(fam.p),
                 jnp.float32(fam.theta))
             if float(delta) < beta_eps:
                 break
-        return np.asarray(coef)
+        return np.asarray(coef), it
 
     def _bounds_of(self, p, coef_names) -> Optional[tuple]:
         """lower/upper coefficient bounds from beta_constraints /
@@ -847,7 +863,7 @@ class GLMEstimator(ModelBuilder):
 
     def _fit_lbfgs(self, X1, yv, w, fam: Family, l2: float,
                    coef0: np.ndarray, nobs: float, max_iter: int,
-                   off=None) -> np.ndarray:
+                   off=None):
         if off is None:
             off = jnp.zeros((X1.shape[0],), jnp.float32)
         l2d = jnp.float32(l2)
@@ -858,8 +874,8 @@ class GLMEstimator(ModelBuilder):
             return _glm_value_grad(jnp.asarray(c, jnp.float32), X1, yv, w,
                                    off, l2d, fam.name, fam.link, pw, th)
 
-        coef, _, _ = lbfgs(vgrad, coef0, max_iter=max_iter)
-        return np.asarray(coef)
+        coef, _, n_iter = lbfgs(vgrad, coef0, max_iter=max_iter)
+        return np.asarray(coef), int(n_iter)
 
     def _fit_multinomial(self, X1, y_int, w, K: int, l2: float,
                          nobs: float, max_iter: int,
@@ -910,35 +926,38 @@ class GLMEstimator(ModelBuilder):
             di_frame = expand_interactions(frame, inter)
             x = list(x) + [c for c in di_frame.names
                            if c not in frame.names]
-        di = build_datainfo(di_frame, x, standardize=bool(p["standardize"]),
-                            use_all_factor_levels=bool(p["use_all_factor_levels"]),
-                            missing_values_handling=p["missing_values_handling"])
-        ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
-        X1 = jax.device_put(jnp.concatenate([di.X, ones], axis=1),
-                            row_sharding(mesh))
+        from h2o3_tpu import telemetry
+        with telemetry.span("glm.design"):
+            di = build_datainfo(
+                di_frame, x, standardize=bool(p["standardize"]),
+                use_all_factor_levels=bool(p["use_all_factor_levels"]),
+                missing_values_handling=p["missing_values_handling"])
+            ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
+            X1 = jax.device_put(jnp.concatenate([di.X, ones], axis=1),
+                                row_sharding(mesh))
 
-        w = frame.valid_weights()
-        if p.get("weights_column"):
-            wc = frame.col(p["weights_column"]).numeric_view()
-            w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
-        # (CV fast path: standardization stats stay full-frame, like
-        # the shared bin edges on the tree side)
-        w = self._cv_masked_weights(w, frame)
+            w = frame.valid_weights()
+            if p.get("weights_column"):
+                wc = frame.col(p["weights_column"]).numeric_view()
+                w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
+            # (CV fast path: standardization stats stay full-frame, like
+            # the shared bin edges on the tree side)
+            w = self._cv_masked_weights(w, frame)
 
-        # offset_column: fixed per-row addition to eta (GLM.java offset)
-        off = None
-        if p.get("offset_column") and p["offset_column"] in frame:
-            if fam_name == "multinomial":
-                # class-uniform offsets cancel in softmax — warn and
-                # ignore like the reference (hex/glm/GLM.java:978)
-                log.warning("offset_column has no effect on multinomial "
-                            "and will be ignored")
-            else:
-                ov = frame.col(p["offset_column"]).numeric_view()
-                off = jnp.where(jnp.isnan(ov), 0.0,
-                                ov).astype(jnp.float32)
-        off_or0 = off if off is not None else \
-            jnp.zeros((X1.shape[0],), jnp.float32)
+            # offset_column: fixed per-row addition to eta (GLM.java offset)
+            off = None
+            if p.get("offset_column") and p["offset_column"] in frame:
+                if fam_name == "multinomial":
+                    # class-uniform offsets cancel in softmax — warn and
+                    # ignore like the reference (hex/glm/GLM.java:978)
+                    log.warning("offset_column has no effect on multinomial "
+                                "and will be ignored")
+                else:
+                    ov = frame.col(p["offset_column"]).numeric_view()
+                    off = jnp.where(jnp.isnan(ov), 0.0,
+                                    ov).astype(jnp.float32)
+            off_or0 = off if off is not None else \
+                jnp.zeros((X1.shape[0],), jnp.float32)
 
         rc = frame.col(y)
         cmus, csds = coef_stats(di)
@@ -1031,25 +1050,27 @@ class GLMEstimator(ModelBuilder):
             return model
 
         # single-coefficient-vector families
-        if category == ModelCategory.BINOMIAL:
-            yraw = adapt_domain(rc, rc.domain)
-            yv = np.pad(np.maximum(yraw, 0).astype(np.float32),
-                        (0, X1.shape[0] - frame.nrows))
-            wna = np.pad((yraw >= 0).astype(np.float32),
-                         (0, X1.shape[0] - frame.nrows))
-            w = w * jnp.asarray(wna)
-        else:
-            yn = rc.to_numpy()
-            wna = np.pad((~np.isnan(yn)).astype(np.float32),
-                         (0, X1.shape[0] - frame.nrows))
-            w = w * jnp.asarray(wna)
-            yv = np.pad(np.nan_to_num(yn).astype(np.float32),
-                        (0, X1.shape[0] - frame.nrows))
-        y_dev = put_sharded(yv, row_sharding(mesh))
-        nobs = float(jnp.sum(w))
+        with telemetry.span("glm.response"):
+            if category == ModelCategory.BINOMIAL:
+                yraw = adapt_domain(rc, rc.domain)
+                yv = np.pad(np.maximum(yraw, 0).astype(np.float32),
+                            (0, X1.shape[0] - frame.nrows))
+                wna = np.pad((yraw >= 0).astype(np.float32),
+                             (0, X1.shape[0] - frame.nrows))
+                w = w * jnp.asarray(wna)
+            else:
+                yn = rc.to_numpy()
+                wna = np.pad((~np.isnan(yn)).astype(np.float32),
+                             (0, X1.shape[0] - frame.nrows))
+                w = w * jnp.asarray(wna)
+                yv = np.pad(np.nan_to_num(yn).astype(np.float32),
+                            (0, X1.shape[0] - frame.nrows))
+            y_dev = put_sharded(yv, row_sharding(mesh))
+            nobs = float(jnp.sum(w))
 
         alpha = float(p["alpha"] if p["alpha"] is not None else 0.5)
-        lambdas = _lambda_path(p, X1, y_dev, w, nobs, alpha, mesh)
+        with telemetry.span("glm.lambda_path"):
+            lambdas = _lambda_path(p, X1, y_dev, w, nobs, alpha, mesh)
         if p.get("compute_p_values") and any(l != 0.0 for l in lambdas):
             # fail before the (possibly long) lambda-path fit
             raise ValueError("compute_p_values requires no regularization "
@@ -1065,11 +1086,13 @@ class GLMEstimator(ModelBuilder):
         coef = np.zeros(X1.shape[1])
         best = None
         coef_path = None
+        # (glm.solve span, iterations it ran): the IRLS count stays on
+        # the device until the coefficients are read
+        solves = []
         fuse_path = (len(lambdas) > 1 and bounds is None
                      and solver not in ("coordinate_descent",
                                         "coordinate_descent_naive",
                                         "l_bfgs", "lbfgs"))
-        from h2o3_tpu import telemetry
         from h2o3_tpu.core import recovery as _recovery
         from h2o3_tpu.core.watchdog import maybe_fail
         from h2o3_tpu.telemetry import stepprof
@@ -1080,11 +1103,10 @@ class GLMEstimator(ModelBuilder):
             l1s = jnp.asarray([lam * alpha for lam in lambdas], jnp.float32)
             l2s = jnp.asarray([lam * (1.0 - alpha) for lam in lambdas],
                               jnp.float32)
-            _st0 = time.time()
             stepprof.chunk_begin()
             with telemetry.span("glm.solve", solver=solver,
-                                lambdas=len(lambdas)):
-                best, coef_path = _irls_solve_path(
+                                lambdas=len(lambdas)) as sp:
+                best, coef_path, its = _irls_solve_path(
                     X1, jnp.asarray(coef, jnp.float32), y_dev, w, off_or0,
                     l1s, l2s, jnp.float32(p["beta_epsilon"]),
                     jnp.int32(p["max_iterations"]), fam.name, fam.link,
@@ -1092,11 +1114,8 @@ class GLMEstimator(ModelBuilder):
                     jnp.float32(self._objective_eps()),
                     use_l1=alpha > 0)
                 stepprof.compute_done((best, coef_path))
-            telemetry.histogram("train_chunk_seconds",
-                                algo="glm").observe(time.time() - _st0)
+            solves.append((sp, its))
             stepprof.chunk_end(lambdas=len(lambdas))
-            telemetry.counter("train_iterations_total", algo="glm").inc(
-                len(lambdas) * int(p["max_iterations"]))
             job.update(1.0, f"lambda path ({len(lambdas)})")
         else:
             # in-fit checkpointer (core/recovery.py): the IRLS outer
@@ -1122,33 +1141,26 @@ class GLMEstimator(ModelBuilder):
                     continue            # resumed past this lambda
                 l1 = lam * alpha
                 l2 = lam * (1.0 - alpha)
-                _st0 = time.time()
                 stepprof.chunk_begin()
                 with telemetry.span("glm.solve", solver=solver,
-                                    lam=float(lam)):
+                                    lam=float(lam)) as sp:
                     if solver in ("coordinate_descent",
                                   "coordinate_descent_naive"):
-                        coef = self._fit_cod(X1, y_dev, w, fam, l1, l2,
-                                             coef,
-                                             int(p["max_iterations"]),
-                                             float(p["beta_epsilon"]),
-                                             bounds, off=off_or0)
+                        coef, its = self._fit_cod(
+                            X1, y_dev, w, fam, l1, l2, coef,
+                            int(p["max_iterations"]),
+                            float(p["beta_epsilon"]), bounds, off=off_or0)
                     elif solver in ("l_bfgs", "lbfgs") and l1 == 0:
-                        coef = self._fit_lbfgs(X1, y_dev, w, fam, l2,
-                                               coef, nobs,
-                                               int(p["max_iterations"]),
-                                               off=off_or0)
+                        coef, its = self._fit_lbfgs(
+                            X1, y_dev, w, fam, l2, coef, nobs,
+                            int(p["max_iterations"]), off=off_or0)
                     else:
-                        coef = self._fit_irlsm(X1, y_dev, w, fam, l1, l2,
-                                               coef, nobs,
-                                               int(p["max_iterations"]),
-                                               float(p["beta_epsilon"]),
-                                               off=off_or0)
+                        coef, its = self._fit_irlsm(
+                            X1, y_dev, w, fam, l1, l2, coef, nobs,
+                            int(p["max_iterations"]),
+                            float(p["beta_epsilon"]), off=off_or0)
                     stepprof.compute_done(coef)
-                telemetry.histogram("train_chunk_seconds",
-                                    algo="glm").observe(time.time() - _st0)
-                telemetry.counter("train_iterations_total",
-                                  algo="glm").inc(int(p["max_iterations"]))
+                solves.append((sp, its))
                 stepprof.chunk_end(lam=float(lam))
                 job.update(1.0 / len(lambdas),
                            f"lambda {li + 1}/{len(lambdas)}")
@@ -1161,7 +1173,15 @@ class GLMEstimator(ModelBuilder):
                 maybe_fail("device_oom")
             if fc is not None:
                 fc.clear()
-        coef = np.asarray(best)   # ONE host materialization after the path
+        with telemetry.span("glm.readback"):
+            # ONE host materialization after the path: the coefficients
+            # and, with them, the iterations each solve ran
+            coef, ran = jax.device_get((best, [its for _, its in solves]))
+            coef = np.asarray(coef)
+        for (sp, _), n in zip(solves, ran):
+            sp.annotate(iterations=int(np.sum(n)))
+        telemetry.counter("train_iterations_total", algo="glm").inc(
+            int(sum(np.sum(n) for n in ran)))
 
         output["lambda_best"] = float(lambdas[-1])
         # a CV sweep selects lambda by summed holdout deviance over this
@@ -1184,15 +1204,17 @@ class GLMEstimator(ModelBuilder):
         if coef_path is not None:
             model._coef_path = np.asarray(coef_path)      # [L, P+1]
             model._lambda_path_vals = list(lambdas)
-        mu = fam.linkinv(X1 @ jnp.asarray(coef, jnp.float32) + off_or0)
-        if category == ModelCategory.BINOMIAL:
-            model.training_metrics = mm.binomial_metrics(mu, y_dev, w)
-            model.output["default_threshold"] = \
-                model.training_metrics["max_f1_threshold"]
-        else:
-            model.training_metrics = mm.regression_metrics(
-                mu, y_dev, w, deviance_fn=lambda a, b: fam.deviance(a, b))
-        _finish(model, frame, validation_frame)
+        with telemetry.span("glm.metrics"):
+            mu = fam.linkinv(X1 @ jnp.asarray(coef, jnp.float32) + off_or0)
+            if category == ModelCategory.BINOMIAL:
+                model.training_metrics = mm.binomial_metrics(mu, y_dev, w)
+                model.output["default_threshold"] = \
+                    model.training_metrics["max_f1_threshold"]
+            else:
+                model.training_metrics = mm.regression_metrics(
+                    mu, y_dev, w,
+                    deviance_fn=lambda a, b: fam.deviance(a, b))
+            _finish(model, frame, validation_frame)
         return model
 
 
@@ -1407,10 +1429,9 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
         idx = np.where((l1_all > 0) == use_l1)[0]
         if idx.size == 0:
             continue
-        _st0 = time.time()
         stepprof.chunk_begin()
         with telemetry.span("glm.solve_batched", solver="irlsm",
-                            width=int(idx.size)):
+                            width=int(idx.size)) as sp:
             out = _irls_solve_batched(
                 X1, coef0, y_dev, w, off_or0,
                 jnp.asarray(l1_all[idx]), jnp.asarray(l2_all[idx]),
@@ -1419,12 +1440,11 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
                 jnp.float32(fam.p), jnp.float32(fam.theta),
                 jnp.asarray(oe_all[idx]), use_l1=use_l1)
             stepprof.compute_done(out)
-        telemetry.histogram("train_chunk_seconds",
-                            algo="glm").observe(time.time() - _st0)
-        telemetry.counter("train_iterations_total", algo="glm").inc(
-            int(idx.size) * int(p0["max_iterations"]))
         stepprof.chunk_end(width=int(idx.size))
-        coefs[idx] = np.asarray(out)
+        coefs[idx], its = jax.device_get(out)
+        sp.annotate(iterations=int(its.sum()))
+        telemetry.counter("train_iterations_total", algo="glm").inc(
+            int(its.sum()))
 
     # ---- per-model unstack into ordinary Model objects ---------------
     models: List[Model] = []

@@ -415,25 +415,32 @@ class ModelBuilder:
         """``custom_metric_func`` is the water/udf CFunc role: a callable
         ``fn(y_values, preds_dict, weights) -> float`` evaluated on the
         training frame and attached to training_metrics as 'custom'."""
-        x = self.resolve_x(training_frame, x, y)
-        nfolds = int(self.params.get("nfolds") or 0)
-        # an explicit fold column triggers CV regardless of nfolds
-        # (hex/ModelBuilder.java computeCrossValidation entry conditions)
-        if self.params.get("fold_column") and nfolds < 2 \
-                and self.cv_from_fold_column:
-            nfolds = 2      # actual count comes from the fold column
-        # predictive admission (core/memgov.py): estimate the fit's
-        # device footprint and reserve it BEFORE the job dispatches —
-        # an over-budget fit first spills cold frames, then rejects
-        # here with an actionable error naming projected vs available
-        # bytes (never an opaque XLA RESOURCE_EXHAUSTED minutes in).
-        # The reservation releases when the job ends, whatever status.
-        from h2o3_tpu.core import memgov as _memgov
-        _rsv = _memgov.governor.admit_fit(self.algo, self.params,
-                                          training_frame, x,
-                                          validation_frame)
+        from h2o3_tpu import telemetry
+        # admission is a span of its own: it ends before the job's opens
+        with telemetry.span("fit.admit", algo=self.algo):
+            x = self.resolve_x(training_frame, x, y)
+            nfolds = int(self.params.get("nfolds") or 0)
+            # an explicit fold column triggers CV regardless of nfolds
+            # (hex/ModelBuilder.java computeCrossValidation entry
+            # conditions)
+            if self.params.get("fold_column") and nfolds < 2 \
+                    and self.cv_from_fold_column:
+                nfolds = 2      # actual count comes from the fold column
+            # predictive admission (core/memgov.py): estimate the fit's
+            # device footprint and reserve it BEFORE the job dispatches —
+            # an over-budget fit first spills cold frames, then rejects
+            # here with an actionable error naming projected vs
+            # available bytes (never an opaque XLA RESOURCE_EXHAUSTED
+            # minutes in). The reservation releases when the job ends,
+            # whatever status.
+            from h2o3_tpu.core import memgov as _memgov
+            _rsv = _memgov.governor.admit_fit(self.algo, self.params,
+                                              training_frame, x,
+                                              validation_frame)
         # the model key must exist BEFORE training starts: the real h2o-py
-        # captures job.dest at submission time (h2o-py/h2o/job.py:48)
+        # captures job.dest at submission time (h2o-py/h2o/job.py:48).
+        # The job is made outside the admission span: it parents under
+        # the caller's span (a REST request's), not under admission
         if not dest_key:
             dest_key = make_key(f"model_{self.algo}")
         try:
@@ -472,10 +479,9 @@ class ModelBuilder:
                 raise ValueError(
                     "fold_assignment is incompatible with fold_column "
                     "(hex/ModelBuilder fold-spec validation)")
-            from h2o3_tpu import telemetry
             from h2o3_tpu.telemetry import roofline, stepprof
             with telemetry.span(f"{self.algo}.fit", algo=self.algo,
-                                nfolds=nfolds), \
+                                nfolds=nfolds) as fit_span, \
                     _recovery.fit_checkpoint_scope(_fit_ckpt_dir):
                 rf_probe = roofline.fit_probe(self.algo)
                 # step profiler: the chunk loops charge their phase
@@ -501,14 +507,17 @@ class ModelBuilder:
                 # roofline accounting INSIDE the span: the MFU/HBM
                 # numbers annotate the fit span and therefore land in
                 # the job's flight-recorder capsule (never raises)
-                _rf = roofline.record_model_fit(
-                    self, model, training_frame, x,
-                    seconds=time.time() - t_fit, probe=rf_probe)
-                stepprof.finish(_sp, model_key=dest_key,
-                                seconds=time.time() - t_fit,
-                                mfu=(_rf or {}).get("mfu"))
-            telemetry.histogram("model_fit_seconds",
-                                algo=self.algo).observe(time.time() - t0)
+                with telemetry.span("fit.account"):
+                    _rf = roofline.record_model_fit(
+                        self, model, training_frame, x,
+                        seconds=time.time() - t_fit, probe=rf_probe,
+                        span=fit_span)
+                    stepprof.finish(_sp, model_key=dest_key,
+                                    seconds=time.time() - t_fit,
+                                    mfu=(_rf or {}).get("mfu"))
+                    telemetry.histogram(
+                        "model_fit_seconds",
+                        algo=self.algo).observe(time.time() - t0)
             if custom_metric_func is not None and y is not None:
                 # "python:key" CFunc references (water/udf/CFuncRef)
                 from h2o3_tpu.core.udf import resolve_udf

@@ -304,36 +304,42 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
                 block_rows=params.block_rows, mesh=mesh,
                 interpret=(params.pallas == "interpret"))
         else:
-            if prev_hist is None:
-                hist = histogram(bins, nid, w, g, h, n_nodes=L, n_bins=B,
-                                 mesh=mesh, block_rows=params.block_rows)
-            else:
-                # sibling subtraction: histogram only the LEFT children
-                # (even node slots), derive right = parent − left. Halves
-                # the histogram matmul at every level ≥ 1 (the
-                # LightGBM/XGBoost smaller-child trick, made static-shape
-                # by always picking left; the reference recomputes both
-                # children, hex/tree/ScoreBuildHistogram2.java).
-                even = (nid % 2 == 0).astype(jnp.float32)
-                lh = histogram(bins, nid >> 1, w * even, g, h,
-                               n_nodes=L // 2, n_bins=B, mesh=mesh,
-                               block_rows=params.block_rows)
-                rh = prev_hist - lh
-                # f32 cancellation guard: w and h are nonnegative sums,
-                # so clamp tiny negative residue (|err| ≲ parent·2^-23);
-                # g may be legitimately negative and stays as computed
-                rh = rh.at[..., 0].set(jnp.maximum(rh[..., 0], 0.0))
-                rh = rh.at[..., 2].set(jnp.maximum(rh[..., 2], 0.0))
-                hist = jnp.stack([lh, rh], axis=1).reshape(L, *lh.shape[1:])
-            bg, bf, bt, bnal, blv, brv, leftmask = _best_splits(
-                hist, nb, cm, params, constraints=constraints, lo=lo,
-                hi=hi, scalars=sc, is_cat=is_cat)
-            split = bg > sc.msi
-            if sc.depth_limit is not None:
-                # depth-bucketed program: levels past the ACTUAL depth
-                # never split (one compiled program per DEPTH_BUCKET,
-                # not per depth)
-                split = split & (jnp.int32(d) < sc.depth_limit)
+            with jax.named_scope("tree.hist"):
+                if prev_hist is None:
+                    hist = histogram(bins, nid, w, g, h, n_nodes=L,
+                                     n_bins=B, mesh=mesh,
+                                     block_rows=params.block_rows)
+                else:
+                    # sibling subtraction: histogram only the LEFT
+                    # children (even node slots), derive right = parent −
+                    # left. Halves the histogram matmul at every level
+                    # ≥ 1 (the LightGBM/XGBoost smaller-child trick, made
+                    # static-shape by always picking left; the reference
+                    # recomputes both children,
+                    # hex/tree/ScoreBuildHistogram2.java).
+                    even = (nid % 2 == 0).astype(jnp.float32)
+                    lh = histogram(bins, nid >> 1, w * even, g, h,
+                                   n_nodes=L // 2, n_bins=B, mesh=mesh,
+                                   block_rows=params.block_rows)
+                    rh = prev_hist - lh
+                    # f32 cancellation guard: w and h are nonnegative
+                    # sums, so clamp tiny negative residue (|err| ≲
+                    # parent·2^-23); g may be legitimately negative and
+                    # stays as computed
+                    rh = rh.at[..., 0].set(jnp.maximum(rh[..., 0], 0.0))
+                    rh = rh.at[..., 2].set(jnp.maximum(rh[..., 2], 0.0))
+                    hist = jnp.stack([lh, rh], axis=1).reshape(
+                        L, *lh.shape[1:])
+            with jax.named_scope("tree.split_scan"):
+                bg, bf, bt, bnal, blv, brv, leftmask = _best_splits(
+                    hist, nb, cm, params, constraints=constraints, lo=lo,
+                    hi=hi, scalars=sc, is_cat=is_cat)
+                split = bg > sc.msi
+                if sc.depth_limit is not None:
+                    # depth-bucketed program: levels past the ACTUAL
+                    # depth never split (one compiled program per
+                    # DEPTH_BUCKET, not per depth)
+                    split = split & (jnp.int32(d) < sc.depth_limit)
             nid_next = None
         prev_hist = hist
         feats = feats.at[d, :L].set(jnp.where(split, bf, 0))
@@ -385,9 +391,10 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
         if nid_next is not None:
             nid = nid_next
         else:
-            nid = _level_goleft(feats[d], threshs[d], na_lefts[d],
-                                is_splits[d], cat_splits[d],
-                                left_words[d], nid, bins, B)
+            with jax.named_scope("tree.partition"):
+                nid = _level_goleft(feats[d], threshs[d], na_lefts[d],
+                                    is_splits[d], cat_splits[d],
+                                    left_words[d], nid, bins, B)
 
     # leaf Newton values from final assignment (GammaPass analogue)
     nleaf = 2 ** D
@@ -441,9 +448,11 @@ def _route(tree: Tree, bins, B: int):
     D = tree.feat.shape[0]
     nid = jnp.zeros((N,), jnp.int32)
     for d in range(D):
-        nid = _level_goleft(tree.feat[d], tree.thresh[d], tree.na_left[d],
-                            tree.is_split[d], tree.cat_split[d],
-                            tree.left_words[d], nid, bins, B)
+        with jax.named_scope("forest.level"):
+            nid = _level_goleft(tree.feat[d], tree.thresh[d],
+                                tree.na_left[d], tree.is_split[d],
+                                tree.cat_split[d], tree.left_words[d],
+                                nid, bins, B)
     return nid
 
 

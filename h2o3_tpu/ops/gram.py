@@ -29,12 +29,11 @@ def _local_gram(X, wz, block_rows: int):
     C = min(block_rows, N)
     nblk = (N + C - 1) // C
     Npad = nblk * C
-    if Npad != N:
-        X = jnp.pad(X, ((0, Npad - N), (0, 0)))
-        wz = jnp.pad(wz, ((0, Npad - N), (0, 0)))
-    Xb = X.reshape(nblk, C, Pdim)
-    wzb = wz.reshape(nblk, C, 2)
 
+    # scope names are what a device trace shows of this code:
+    # gram.blocks is the cutting into row blocks (pad, reshape and the
+    # scan's own slicing), gram.accumulate the products of one block
+    @jax.named_scope("gram.accumulate")
     def step(acc, xs):
         xtx, xtz, ws = acc
         Xc, wzc = xs
@@ -48,7 +47,13 @@ def _local_gram(X, wz, block_rows: int):
 
     init = (jnp.zeros((Pdim, Pdim), jnp.float32),
             jnp.zeros((Pdim,), jnp.float32), jnp.float32(0.0))
-    (xtx, xtz, ws), _ = jax.lax.scan(step, init, (Xb, wzb))
+    with jax.named_scope("gram.blocks"):
+        if Npad != N:
+            X = jnp.pad(X, ((0, Npad - N), (0, 0)))
+            wz = jnp.pad(wz, ((0, Npad - N), (0, 0)))
+        Xb = X.reshape(nblk, C, Pdim)
+        wzb = wz.reshape(nblk, C, 2)
+        (xtx, xtz, ws), _ = jax.lax.scan(step, init, (Xb, wzb))
     return xtx, xtz, ws
 
 
@@ -72,9 +77,10 @@ def gram(X, w, z, *, mesh, block_rows: int = 8192):
         out_specs=(P(), P(), P()), check_vma=False)
     def _task(X_l, wz_l):
         xtx, xtz, ws = _local_gram(X_l, wz_l, block_rows)
-        return (jax.lax.psum(xtx, DATA_AXIS),
-                jax.lax.psum(xtz, DATA_AXIS),
-                jax.lax.psum(ws, DATA_AXIS))
+        with jax.named_scope("gram.psum"):
+            return (jax.lax.psum(xtx, DATA_AXIS),
+                    jax.lax.psum(xtz, DATA_AXIS),
+                    jax.lax.psum(ws, DATA_AXIS))
 
     return _task(X, wz)
 

@@ -243,7 +243,7 @@ def _hist_call(bins, nid, stats, *, d, n_nodes, n_bins, block_rows,
         out_specs=pl.BlockSpec((3 * Lh, F * n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((3 * Lh, F * n_bins), jnp.float32),
         scratch_shapes=[pltpu.VMEM((3 * Lh, F * n_bins), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="tree_hist",
     )(jnp.pad(bins, ((0, n_pad - N), (0, 0))), _pad_lanes(nid, n_pad),
       _pad_lanes(stats, n_pad))
 
@@ -275,7 +275,7 @@ def _partition_call(bins, nid, bf, bt, bnal, isp, cs, lmask, *, n_bins,
                   full(L, 1), full(n_bins - 1, L)],
         out_specs=_lane_tile(1, C),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-        interpret=interpret,
+        interpret=interpret, name="tree_partition",
     )(_pad_lanes(bins, n_pad), _pad_lanes(nid, n_pad),
       col(bf), col(bt), col(bnal), col(isp), col(cs),
       lmask.astype(jnp.float32).T)
@@ -341,21 +341,26 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
         out_specs=(P(),) * 9 + (P(DATA_AXIS),), check_vma=False)
     def _task(bins_l, nid_l, stats_l, prev, cm2, nb2, iscat_a, cons_a,
               lo2, hi2, knobs, dl):
+        # the scope names grow_tree's XLA sequence gives the same steps
         nid_l = nid_l[None, :]
-        lh = _hist_call(bins_l, nid_l, stats_l, d=d, n_nodes=n_nodes,
-                        n_bins=n_bins, block_rows=block_rows,
-                        interpret=interpret)
-        lh = jax.lax.psum(lh, DATA_AXIS)
-        hist, bg, bf, bt, bnal, blv, brv, lmask, split, cs = \
-            _level_boundary(
-                lh, prev if d > 0 else None, cm2, nb2[0],
-                iscat_a[0] != 0 if has_cats else None,
-                cons_a[0] if has_cons else None, lo2[0], hi2[0], knobs,
-                dl, d=d, n_nodes=n_nodes, n_bins=n_bins, n_features=F)
-        newnid_l = _partition_call(bins_l.T, nid_l, bf, bt, bnal, split,
-                                   cs, lmask, n_bins=n_bins,
-                                   block_rows=block_rows,
-                                   interpret=interpret)[0]
+        with jax.named_scope("tree.hist"):
+            lh = _hist_call(bins_l, nid_l, stats_l, d=d, n_nodes=n_nodes,
+                            n_bins=n_bins, block_rows=block_rows,
+                            interpret=interpret)
+            lh = jax.lax.psum(lh, DATA_AXIS)
+        with jax.named_scope("tree.split_scan"):
+            hist, bg, bf, bt, bnal, blv, brv, lmask, split, cs = \
+                _level_boundary(
+                    lh, prev if d > 0 else None, cm2, nb2[0],
+                    iscat_a[0] != 0 if has_cats else None,
+                    cons_a[0] if has_cons else None, lo2[0], hi2[0],
+                    knobs, dl, d=d, n_nodes=n_nodes, n_bins=n_bins,
+                    n_features=F)
+        with jax.named_scope("tree.partition"):
+            newnid_l = _partition_call(bins_l.T, nid_l, bf, bt, bnal,
+                                       split, cs, lmask, n_bins=n_bins,
+                                       block_rows=block_rows,
+                                       interpret=interpret)[0]
         return (hist, bg, bf, bt, bnal, blv, brv, lmask, split, newnid_l)
 
     return _task(bins, nid, stats, prev, cm2, nb2, iscat_in, cons_in,

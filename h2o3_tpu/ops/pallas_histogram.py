@@ -107,6 +107,6 @@ def pallas_local_histogram(bins, nid, stats, n_nodes: int, n_bins: int,
         out_shape=jax.ShapeDtypeStruct((n_nodes * 3, F * n_bins),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((n_nodes * 3, F * n_bins), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="histogram",
     )(bins, nid.reshape(-1, 1), stats)
     return out.reshape(n_nodes, 3, F, n_bins).transpose(0, 2, 3, 1)

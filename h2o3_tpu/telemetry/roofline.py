@@ -321,11 +321,13 @@ def fit_probe(algo: str) -> Dict:
 
 
 def record_model_fit(builder, model, frame, x, seconds: float,
-                     probe: Optional[Dict] = None) -> Optional[Dict]:
+                     probe: Optional[Dict] = None,
+                     span=None) -> Optional[Dict]:
     """Compute this fit's FLOP/byte totals, emit the
     ``model_fit_mfu{algo}`` / ``model_fit_hbm_util{algo}`` gauges,
-    annotate the active (fit) span so the numbers ride the flight
-    recorder capsule, and return the record. Never raises."""
+    annotate the fit span (``span``, else the active one) so the numbers
+    ride the flight recorder capsule, and return the record. Never
+    raises."""
     try:
         if mode() == "off" or seconds <= 0:
             return None
@@ -374,9 +376,11 @@ def record_model_fit(builder, model, frame, x, seconds: float,
             roofline_meta["kernel_cost"] = kc
         # unrounded: a toy fit's MFU on a big mesh is legitimately tiny
         # and must survive into the capsule as nonzero
+        annotate = span.annotate if span is not None \
+            else spans_mod.annotate
         if mfu is not None and hbm is not None:
-            spans_mod.annotate(mfu=mfu, hbm_util=hbm)
-        spans_mod.annotate(roofline=roofline_meta)
+            annotate(mfu=mfu, hbm_util=hbm)
+        annotate(roofline=roofline_meta)
         # per-fit record on the MODEL: model_fit_mfu{algo} is a
         # latest-wins gauge, so concurrent fits of the same algo
         # (scheduler-spread grids) overwrite each other there — the

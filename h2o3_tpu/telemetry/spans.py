@@ -20,6 +20,12 @@ registry; ``GET /3/Metrics`` serves both views.
 
 Timeline events recorded while a span is active carry its id
 (utils/timeline.py), tying the flat event ring to the span tree.
+
+Every span is also a ``jax.profiler.TraceAnnotation("h2o3.<name>")``
+over the same interval: while a profiler session is open the span lands
+in the trace's host plane, on the device trace's clock, so device idle
+time can be charged to program phases (benchmark/program_trace.py).
+With no session open the annotation is a flag test.
 """
 
 from __future__ import annotations
@@ -32,6 +38,8 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from h2o3_tpu.telemetry.registry import counter, histogram
 
 _CAPACITY = 1024
@@ -41,6 +49,10 @@ _ids = itertools.count(1)
 
 _current: contextvars.ContextVar[Optional["Span"]] = \
     contextvars.ContextVar("h2o3tpu_span", default=None)
+
+# the profiler-trace name of a span: fixed, so that a trace reader can
+# tell the program's spans from a harness's own annotations
+TRACE_PREFIX = "h2o3."
 
 
 class Span:
@@ -118,7 +130,10 @@ def span(name: str, **meta):
     sp._peak_base = _device_peak()
     sp._token = _current.set(sp)
     try:
-        yield sp
+        # the one place the program writes into a profiler trace:
+        # exactly the interval the span times
+        with TraceAnnotation(TRACE_PREFIX + name):
+            yield sp
     finally:
         _current.reset(sp._token)
         sp.end = time.time()

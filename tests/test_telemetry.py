@@ -393,3 +393,202 @@ def test_rapids_device_mean_all_na(monkeypatch):
     dv = rapids('(mean (cols_py tele_allna ["b"]) 1)', sess)
     want = float(np.nanmean(np.asarray(fr.col("b").to_numpy())))
     assert abs(dv - want) < 2e-4 * max(1.0, abs(want))
+
+
+# ------------------------------------- spans on the profiler's clock (PR 27)
+
+
+def _mark():
+    """The id number of a span opened and closed now."""
+    with telemetry.span("t.mark") as sp:
+        pass
+    return int(sp.id[3:])
+
+
+def _spans_since(mark, names=None):
+    """Finished spans opened after ``_mark()`` gave ``mark``, in the
+    order they were opened."""
+    out = [s for s in telemetry.spans_snapshot(10 ** 6)
+           if int(s["id"][3:]) > mark
+           and (names is None or s["name"] in names)]
+    return sorted(out, key=lambda s: int(s["id"][3:]))
+
+
+def _host_events(trace_dir, prefix):
+    """``(name, start_ns, end_ns)`` of the host-plane events of the one
+    trace under ``trace_dir`` whose name starts with ``prefix``."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return sorted((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if not plane.name.startswith("/device:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith(prefix))
+
+
+def test_span_lands_in_a_profiler_trace_nested_as_opened(tmp_path):
+    """Every span is a TraceAnnotation named h2o3.<name> over the
+    interval it times: inside a profiler session it is an event of the
+    host plane, nested as the spans were."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("t.trace_outer"):
+            with telemetry.span("t.trace_inner", k=1):
+                jnp.ones((8, 8)).sum().block_until_ready()
+            with telemetry.span("t.trace_second"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    evs = {n: (s, e) for n, s, e in _host_events(tmp_path, "h2o3.t.trace")}
+    assert set(evs) == {"h2o3.t.trace_outer", "h2o3.t.trace_inner",
+                        "h2o3.t.trace_second"}
+    (os_, oe), (is_, ie), (ss, se) = (evs["h2o3.t.trace_outer"],
+                                      evs["h2o3.t.trace_inner"],
+                                      evs["h2o3.t.trace_second"])
+    assert os_ <= is_ <= ie <= ss <= se <= oe
+    # outside a session the span is what it was: timed, in the ring
+    with telemetry.span("t.trace_off") as sp:
+        pass
+    assert sp.end >= sp.start and \
+        telemetry.spans_snapshot(1)[0]["name"] == "t.trace_off"
+
+
+def test_the_program_writes_into_a_trace_at_one_place():
+    import pathlib
+    pkg = pathlib.Path(h2o3_tpu.__file__).parent
+    sites = [str(p.relative_to(pkg)) for p in pkg.rglob("*.py")
+             if "TraceAnnotation(" in p.read_text()]
+    assert sites == ["telemetry/spans.py"]
+
+
+def _glm_frame(n=4000, f=4, seed=5):
+    r = np.random.RandomState(seed)
+    X = r.randn(n, f)
+    eta = X @ np.linspace(0.9, -0.6, f)
+    y = (r.rand(n) < 1.0 / (1.0 + np.exp(-eta))).astype(int)
+    cols = {f"x{i}": X[:, i] for i in range(f)}
+    cols["y"] = np.array(["n", "p"], object)[y]
+    return h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
+
+
+@pytest.mark.parametrize("path, params, span_name", [
+    ("single", {"lambda_": 0.0}, "glm.solve"),
+    ("fused_path", {"lambda_search": True, "nlambdas": 5, "alpha": 0.0},
+     "glm.solve"),
+    ("per_lambda", {"lambda_": [0.01, 0.001], "alpha": 0.0,
+                    "solver": "coordinate_descent"}, "glm.solve"),
+    ("batched", None, "glm.solve_batched"),
+])
+def test_glm_counts_the_iterations_it_ran(path, params, span_name):
+    """train_iterations_total{algo=glm} rises by the IRLS iterations
+    run — not by max_iterations — and the solve spans carry them."""
+    from h2o3_tpu.models.glm import GLMEstimator, fit_glm_batched
+    fr = _glm_frame()
+    max_it = 50
+    before = telemetry.REGISTRY.value("train_iterations_total", algo="glm")
+    t0 = _mark()
+    if path == "batched":
+        base = {"family": "binomial", "max_iterations": max_it}
+        plist = [dict(GLMEstimator(**base, lambda_=lam, alpha=0.0).params)
+                 for lam in (0.0, 0.01, 0.1)]
+        models = fit_glm_batched(
+            GLMEstimator, plist, fr, y="y",
+            x=[n for n in fr.names if n != "y"])
+        solves = len(models)
+    else:
+        GLMEstimator(family="binomial", max_iterations=max_it,
+                     **params).train(fr, y="y")
+        solves = {"single": 1, "fused_path": 5, "per_lambda": 2}[path]
+    added = telemetry.REGISTRY.value("train_iterations_total",
+                                     algo="glm") - before
+    spans = _spans_since(t0, {span_name})
+    assert spans and all("iterations" in s["meta"] for s in spans)
+    assert added == sum(s["meta"]["iterations"] for s in spans)
+    assert solves <= added < solves * max_it
+
+
+def test_glm_job_opens_each_phase_span_once_in_order():
+    from h2o3_tpu.models.glm import GLMEstimator
+    fr = _glm_frame(seed=6)
+    GLMEstimator(family="binomial", lambda_=0.0).train(fr, y="y")  # warm
+    mark, t0 = _mark(), time.time()
+    GLMEstimator(family="binomial", lambda_=0.0).train(fr, y="y")
+    wall = time.time() - t0
+    phases = ["fit.admit", "glm.design", "glm.response", "glm.lambda_path",
+              "glm.solve", "glm.readback", "glm.metrics", "fit.account",
+              "job.finish"]
+    got = _spans_since(mark, set(phases))
+    assert [s["name"] for s in got] == phases
+    by = {s["name"]: s for s in _spans_since(mark)}
+    # leaves of the tree job → glm.fit → phase; admission and the job's
+    # tail are roots beside the job
+    assert by["glm.fit"]["parent_id"] == by["job"]["id"]
+    for n in phases[1:7]:
+        assert by[n]["parent_id"] == by["glm.fit"]["id"], n
+    assert by["fit.account"]["parent_id"] == by["glm.fit"]["id"]
+    assert by["fit.admit"]["parent_id"] is None
+    assert by["job.finish"]["parent_id"] is None
+    # one after the other (1 ms: the snapshot's clock), inside the job
+    for a, b in zip(got, got[1:]):
+        assert a["start_ms"] + a["duration_ms"] <= b["start_ms"] + 1.0, \
+            (a["name"], b["name"])
+    # the existing bound, over every span a job now opens
+    t1 = time.time()
+    for _ in range(500):
+        with telemetry.span("t.overhead_glm"):
+            pass
+    per_span = (time.time() - t1) / 500
+    assert len(by) <= 16
+    assert len(by) * per_span < 0.02 * wall, (len(by), per_span, wall)
+
+
+def test_gbm_job_opens_its_phase_spans():
+    from h2o3_tpu.models.gbm import GBMEstimator
+    fr = _mk_class_frame(n=400, seed=3)
+    mark = _mark()
+    GBMEstimator(ntrees=3, max_depth=3, seed=1).train(fr, y="y")
+    got = _spans_since(mark, {"gbm.bin", "gbm.init", "gbm.chunk",
+                              "gbm.rescore", "gbm.metrics"})
+    assert [s["name"] for s in got] == ["gbm.bin", "gbm.init", "gbm.chunk",
+                                        "gbm.rescore", "gbm.metrics"]
+    assert "train_chunk_seconds" not in telemetry.to_prometheus()
+
+
+def _lowered_text(what):
+    """The lowered program ``what`` of a small fit, with locations."""
+    from h2o3_tpu.telemetry import compile_observer
+    if what == "irls_solve":
+        from h2o3_tpu.models.glm import GLMEstimator
+        GLMEstimator(family="binomial", lambda_=0.0).train(
+            _glm_frame(n=1000, seed=7), y="y")
+        fn, args, kwargs = compile_observer.aot_source("glm.irls_solve")
+        return fn.lower(*args, **kwargs).as_text(debug_info=True)
+    from h2o3_tpu.models.gbm import GBMEstimator
+    from h2o3_tpu.models.tree import predict_forest
+    m = GBMEstimator(ntrees=2, max_depth=3, seed=2).train(
+        _mk_class_frame(n=500, seed=4), y="y")
+    if what == "boost_scan":
+        fn, args, kwargs = compile_observer.aot_source("gbm.boost_scan")
+        return fn.lower(*args, **kwargs).as_text(debug_info=True)
+    return predict_forest.lower(
+        m.forest, m.bm.bins, B=m.bm.nbins_total).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("what, scopes", [
+    ("irls_solve", ("glm.irls_iter", "glm.reweight", "glm.newton_solve",
+                    "glm.line_search", "gram.blocks", "gram.accumulate",
+                    "gram.psum")),
+    ("boost_scan", ("tree.hist", "tree.split_scan", "tree.partition")),
+    ("predict_forest", ("forest.level",)),
+])
+def test_lowered_programs_name_their_device_work(what, scopes):
+    """jax.named_scope names ride the lowered program's locations (the
+    HLO op_name metadata a device trace shows); the programs' own names
+    stay."""
+    text = _lowered_text(what)
+    for s in scopes:
+        assert f"/{s}/" in text or f'"{s}/' in text, s
+    assert {"irls_solve": "jit(_irls_solve)",
+            "boost_scan": "jit(_boost_scan_jit)",
+            "predict_forest": "jit(predict_forest)"}[what] in text
