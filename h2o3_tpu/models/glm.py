@@ -37,12 +37,12 @@ from h2o3_tpu.frame.datainfo import (DataInfo, build_datainfo,
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models import metrics as mm
 from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
-                                   adapt_domain, infer_category)
+                                   adapt_domain, infer_category,
+                                   response_on_device)
 from h2o3_tpu.ops.gram import gram
 from h2o3_tpu.ops.optimize import (admm_l1_quadratic,
                                    cholesky_solve_regularized, lbfgs)
-from h2o3_tpu.parallel.mesh import (get_mesh, put_sharded,
-                                    row_sharding)
+from h2o3_tpu.parallel.mesh import get_mesh, row_sharding
 from h2o3_tpu.telemetry import observed_jit
 from h2o3_tpu.utils.log import get_logger
 
@@ -935,6 +935,8 @@ class GLMEstimator(ModelBuilder):
             ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
             X1 = jax.device_put(jnp.concatenate([di.X, ones], axis=1),
                                 row_sharding(mesh))
+            # the response column joins the design row for row, as it lies
+            assert X1.shape[0] == frame.nrows_padded
 
             w = frame.valid_weights()
             if p.get("weights_column"):
@@ -972,12 +974,10 @@ class GLMEstimator(ModelBuilder):
                 raise ValueError("ordinal family requires a categorical "
                                  "response (ordered levels)")
             K = rc.cardinality
-            yv = _fetch_np(rc.data)[: frame.nrows].astype(np.int32)
-            resp_na = _fetch_np(rc.na_mask)[: frame.nrows]
-            yv = np.pad(yv, (0, X1.shape[0] - frame.nrows))
-            w = w * jnp.asarray(np.pad((~resp_na).astype(np.float32),
-                                       (0, X1.shape[0] - frame.nrows)))
-            y_dev = put_sharded(yv, row_sharding(mesh))
+            with telemetry.span("glm.response", on_device=True,
+                                host_bytes=0):
+                y_dev, w = response_on_device(rc, w, categorical=True,
+                                              dtype="int32")
             l2 = _l2_of(p)
             P = X1.shape[1] - 1
             l2d = jnp.float32(l2)
@@ -1017,12 +1017,10 @@ class GLMEstimator(ModelBuilder):
                 raise ValueError("compute_p_values is not supported for "
                                  "multinomial GLM (reference restriction)")
             K = rc.cardinality
-            yv = _fetch_np(rc.data)[: frame.nrows].astype(np.int32)
-            resp_na = _fetch_np(rc.na_mask)[: frame.nrows]
-            yv = np.pad(yv, (0, X1.shape[0] - frame.nrows))
-            w = w * jnp.asarray(np.pad((~resp_na).astype(np.float32),
-                                       (0, X1.shape[0] - frame.nrows)))
-            y_dev = put_sharded(yv, row_sharding(mesh))
+            with telemetry.span("glm.response", on_device=True,
+                                host_bytes=0):
+                y_dev, w = response_on_device(rc, w, categorical=True,
+                                              dtype="int32")
             nobs = float(jnp.sum(w))
             l2 = _l2_of(p)
             msolver = str(p["solver"]).lower()
@@ -1050,22 +1048,10 @@ class GLMEstimator(ModelBuilder):
             return model
 
         # single-coefficient-vector families
-        with telemetry.span("glm.response"):
-            if category == ModelCategory.BINOMIAL:
-                yraw = adapt_domain(rc, rc.domain)
-                yv = np.pad(np.maximum(yraw, 0).astype(np.float32),
-                            (0, X1.shape[0] - frame.nrows))
-                wna = np.pad((yraw >= 0).astype(np.float32),
-                             (0, X1.shape[0] - frame.nrows))
-                w = w * jnp.asarray(wna)
-            else:
-                yn = rc.to_numpy()
-                wna = np.pad((~np.isnan(yn)).astype(np.float32),
-                             (0, X1.shape[0] - frame.nrows))
-                w = w * jnp.asarray(wna)
-                yv = np.pad(np.nan_to_num(yn).astype(np.float32),
-                            (0, X1.shape[0] - frame.nrows))
-            y_dev = put_sharded(yv, row_sharding(mesh))
+        with telemetry.span("glm.response", on_device=True, host_bytes=0):
+            y_dev, w = response_on_device(
+                rc, w, categorical=category == ModelCategory.BINOMIAL)
+            # the phase's one wait: for device work glm.design queued
             nobs = float(jnp.sum(w))
 
         alpha = float(p["alpha"] if p["alpha"] is not None else 0.5)
@@ -1382,6 +1368,7 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
     ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
     X1 = jax.device_put(jnp.concatenate([di.X, ones], axis=1),
                         row_sharding(mesh))
+    assert X1.shape[0] == frame.nrows_padded
     w = frame.valid_weights()
     if p0.get("weights_column"):
         wc = frame.col(p0["weights_column"]).numeric_view()
@@ -1399,21 +1386,8 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
                    "coef_means": cmus.tolist(), "coef_sds": csds.tolist(),
                    "standardized": bool(p0["standardize"]),
                    "nclasses": rc.cardinality if rc.is_categorical else 1}
-    if category == ModelCategory.BINOMIAL:
-        yraw = adapt_domain(rc, rc.domain)
-        yv = np.pad(np.maximum(yraw, 0).astype(np.float32),
-                    (0, X1.shape[0] - frame.nrows))
-        wna = np.pad((yraw >= 0).astype(np.float32),
-                     (0, X1.shape[0] - frame.nrows))
-        w = w * jnp.asarray(wna)
-    else:
-        yn = rc.to_numpy()
-        wna = np.pad((~np.isnan(yn)).astype(np.float32),
-                     (0, X1.shape[0] - frame.nrows))
-        w = w * jnp.asarray(wna)
-        yv = np.pad(np.nan_to_num(yn).astype(np.float32),
-                    (0, X1.shape[0] - frame.nrows))
-    y_dev = put_sharded(yv, row_sharding(mesh))
+    y_dev, w = response_on_device(
+        rc, w, categorical=category == ModelCategory.BINOMIAL)
 
     # ---- one vmapped solve per use_l1 partition ----------------------
     l1_all = np.array([lams[m] * alphas[m] for m in range(M)], np.float32)

@@ -18,11 +18,15 @@ from __future__ import annotations
 
 import time
 import weakref
+from functools import partial
 from typing import Dict, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.parallel.mesh import fetch_replicated as _fetch_np
+from h2o3_tpu.parallel.mesh import row_sharding
 
 from h2o3_tpu.core.job import Job
 from h2o3_tpu.core.kv import DKV, make_key
@@ -73,6 +77,37 @@ def adapt_domain(test_col, train_domain: List[str]) -> np.ndarray:
     out = out.copy()
     out[_fetch_np(test_col.na_mask)[: test_col.nrows]] = -1
     return out
+
+
+@partial(jax.jit, static_argnames=("categorical", "dtype"))
+def _response_program(data, na_mask, w, *, categorical: bool, dtype: str):
+    if categorical:
+        yraw = jnp.where(na_mask, -1, data)
+        present = yraw >= 0
+        y = jnp.maximum(yraw, 0)
+    else:
+        present = ~na_mask
+        y = jnp.where(na_mask, 0, data)
+    row = row_sharding()
+    return (jax.lax.with_sharding_constraint(y.astype(dtype), row),
+            jax.lax.with_sharding_constraint(w * present.astype(w.dtype),
+                                             row))
+
+
+def response_on_device(col, w, *, categorical: bool, dtype: str = "float32"):
+    """(y, w') for TRAINING on ``col``, built on the device from the
+    column's resident ``data`` and ``na_mask``: a missing response gets
+    y = 0 and weight 0 — the mesh-padding rows are NA by construction,
+    so they need no padding here. A categorical response gives its codes
+    (the training domain is the column's own, so there is nothing to map
+    — scoring another frame goes through adapt_domain), a numeric one its
+    values, as ``dtype``. One program, no host copy."""
+    if col.data is None:
+        raise ValueError(f"response column {col.name!r} is of type "
+                         f"{col.type}; it has to be numeric or categorical")
+    assert col.data.shape == w.shape, (col.data.shape, w.shape)
+    return _response_program(col.data, col.na_mask, w,
+                             categorical=categorical, dtype=dtype)
 
 
 def checkpoint_error(algo: str, field: str, message: str) -> ValueError:
@@ -173,7 +208,6 @@ class Model:
         module map) so models stay picklable for checkpoints."""
         fn = _SERVE_JIT_CACHE.get(self)
         if fn is None:
-            import jax
             fn = jax.jit(self._serve_dev)
             _SERVE_JIT_CACHE[self] = fn
         return fn
@@ -349,7 +383,6 @@ class ModelBuilder:
         fold_mask = getattr(self, "_cv_fold_mask", None)
         if fold_mask is None:
             return w
-        import jax.numpy as jnp
         fm = np.zeros(frame.nrows_padded, np.float32)
         fm[: frame.nrows] = fold_mask.astype(np.float32)
         return w * jnp.asarray(fm)

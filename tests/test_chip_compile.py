@@ -40,6 +40,7 @@ from h2o3_tpu.telemetry import compile_observer
 AIR_ROWS, F, B = 5_000_000, 10, 126
 AIR_CATS = (False,) * 6 + (True,) * 3 + (False,)
 GLM_ROWS, DL_ROWS, SCORE_ROWS = 2_000_000, 200_000, 100_000
+HIGGS_ROWS = 11_000_000                  # benchmark cell glm-higgs.fit-11m
 TINY_ROWS = 3000
 LEVELS = (0, 3, 5)                       # of depth-bucket 6
 
@@ -305,6 +306,26 @@ def test_glm_irls_solve(topo, chips):
     txt = _compiled_text(_lower_recorded(
         "glm.irls_solve", _mesh(topo, chips), fr.nrows_padded, GLM_ROWS))
     assert ("all-reduce" in txt) == (chips > 1)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("categorical", [True, False])
+def test_glm_response_on_device(topo, categorical, chips):
+    """models/model.py's response program at the benchmark cell's rows:
+    elementwise on row-sharded columns, so no chip talks to another."""
+    from h2o3_tpu.models.model import _response_program
+    mesh = _mesh(topo, chips)
+    n = mesh_mod.padded_rows(HIGGS_ROWS, mesh)
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    with _as_global_mesh(mesh):
+        lowered = _response_program.lower(
+            S((n,), jnp.int32 if categorical else jnp.float32, sharding=row),
+            S((n,), jnp.bool_, sharding=row),
+            S((n,), jnp.float32, sharding=row),
+            categorical=categorical, dtype="float32")
+    txt = _compiled_text(lowered)
+    assert "all-gather" not in txt and "all-reduce" not in txt
+    assert "collective-permute" not in txt and "all-to-all" not in txt
 
 
 def test_dl_train_chunk(topo):
