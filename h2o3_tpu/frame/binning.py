@@ -179,16 +179,24 @@ def _numeric_edges(x: np.ndarray, nbins: int,
             return np.zeros((0,), dtype=np.float32)
         rng = np.random.RandomState(abs(hash((lo, hi))) % (2**31))
         return np.sort(rng.uniform(lo, hi, nbins - 1)).astype(np.float32)
-    if v.size > 200_000:  # sketch on a sample, like the reference's ExactQuantilesToUse cap
-        rng = np.random.RandomState(0xC0FFEE)
-        idx = rng.randint(0, v.size, 200_000)
-        v = v[idx]
-        wv = None if wv is None else wv[idx]
-    u, inv = np.unique(v, return_inverse=True)
+    # every row enters the cdf, as the reference's QuantilesGlobal pass
+    # over the whole column does: a sketch on a sample moves the cuts of
+    # a many-valued column by its sampling error, and a split's gain
+    # with them (2% at the weakest node of a depth-6 tree on 1M rows).
+    # Equal weights (every fit passes a weight vector, nearly always
+    # ones) need the counts alone; weights that differ are summed by
+    # distinct value in sorted order, at an argsort's price (five
+    # times the counts' on a 48M-row column: PERF.md, PR 29)
+    if wv is None or wv.min() == wv.max():
+        u, cnt = np.unique(v, return_counts=True)
+        wu = cnt.astype(np.float64)
+    else:
+        order = np.argsort(v)
+        vs = v[order]
+        first = np.flatnonzero(np.concatenate(([True], vs[1:] != vs[:-1])))
+        u, wu = vs[first], np.add.reduceat(wv[order], first)
     if u.size < 2:
         return np.zeros((0,), dtype=np.float32)
-    wu = np.bincount(inv, weights=wv, minlength=u.size) if wv is not None \
-        else np.bincount(inv, minlength=u.size).astype(np.float64)
     cdf = np.cumsum(wu)
     cdf /= cdf[-1]
     qs = np.linspace(0.0, 1.0, nbins + 1)[1:-1]
@@ -253,10 +261,21 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
     domains = [c.domain for c in cols]
 
     # per-feature edges / cardinalities (host, once); batch the
-    # device→host fetches of every numeric column into one round trip
+    # device→host fetches of every numeric column into one round trip.
+    # A column's cuts sort all its rows (numpy releases the GIL), so a
+    # few columns are cut at a time
+    numeric_edges = edges_override
     if edges_override is None:
+        from concurrent.futures import ThreadPoolExecutor
         from h2o3_tpu.frame.column import prefetch_host
-        prefetch_host([c for i, c in enumerate(cols) if not is_cat[i]])
+        numeric = [i for i in range(F) if not is_cat[i]]
+        prefetch_host([cols[i] for i in numeric])
+        host = [cols[i].to_numpy() for i in numeric]
+        with ThreadPoolExecutor(4) as pool:
+            numeric_edges = dict(zip(numeric, pool.map(
+                lambda x: _numeric_edges(x, nbins, histogram_type,
+                                         w=weights), host)))
+        del host
     edge_list: List[np.ndarray] = []
     nb = np.zeros((F,), dtype=np.int32)
     div = np.ones((F,), dtype=np.int32)   # code→bin divisor (card>nbins_cats)
@@ -273,11 +292,7 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
                 nb[i] = card
             edge_list.append(np.zeros((0,), dtype=np.float32))
         else:
-            if edges_override is not None:
-                e = edges_override[i]
-            else:
-                e = _numeric_edges(c.to_numpy(), nbins, histogram_type,
-                                   w=weights)
+            e = numeric_edges[i]
             nb[i] = len(e) + 1
             edge_list.append(e)
 

@@ -39,7 +39,7 @@ from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
                                    infer_category, resolve_checkpoint_model,
                                    validate_checkpoint_params)
 from h2o3_tpu.models.tree import (Tree, TreeParams, bucket_depth,
-                                  exact_f32_for, grow_tree, predict_forest,
+                                  grow_tree, predict_forest,
                                   scalars_of, stack_trees)
 from h2o3_tpu.ops import pallas as pallas_ops
 from h2o3_tpu.parallel.mesh import get_mesh, row_sharding
@@ -400,7 +400,6 @@ class DRFEstimator(ModelBuilder):
             col_sample_rate=float(p["col_sample_rate_per_tree"]),
             nbins_total=bm.nbins_total,
             cat_feats=tuple(bool(v) for v in bm.is_cat),
-            exact_f32=exact_f32_for(bm),
             pallas=pallas_ops.resolve_tree_mode())
 
         # target matrix ys [Npad, K]: indicators for classification

@@ -40,7 +40,7 @@ from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
                                    validate_checkpoint_params)
 from h2o3_tpu.models.tree import (Tree, TreeParams, TreeScalars,
                                   bucket_depth, concat_forests,
-                                  exact_f32_for, grow_tree,
+                                  grow_tree, kernel_levels,
                                   predict_forest, predict_tree,
                                   stack_trees, unstack_model_trees)
 from h2o3_tpu.ops import pallas as pallas_ops
@@ -317,8 +317,16 @@ def _neutral_tp(tp: TreeParams) -> TreeParams:
                       nbins_total=tp.nbins_total,
                       block_rows=tp.block_rows,
                       cat_feats=tp.cat_feats,
-                      exact_f32=tp.exact_f32,   # static: changes the program
                       pallas=tp.pallas)         # static: kernel backend
+
+
+def _level_paths(tp: TreeParams, n_features: int) -> dict:
+    """What a ``gbm.chunk`` span says of the level passes: how many of a
+    tree's levels (at its compile depth) ran through the Pallas kernels
+    and how many through the XLA sequence (models/tree.kernel_levels)."""
+    fused = kernel_levels(tp, n_features)
+    return {"levels_kernel": sum(fused),
+            "levels_xla": len(fused) - sum(fused)}
 
 
 def _boost_step_impl(bins, nb, y, w, margin, key, knobs, *, tp, dist,
@@ -833,8 +841,8 @@ class GBMEstimator(ModelBuilder):
             # put a 12K-iteration inner scan in every tree at 50M and
             # underfeed the MXU contraction
             block_rows=16384 if bm.bins.shape[0] > 8_388_608 else 4096,
-            exact_f32=exact_f32_for(bm),
             pallas=pallas_ops.resolve_tree_mode())
+        paths = _level_paths(tp, bm.bins.shape[1])
 
         constraints = _build_constraints(p, x, frame, category)
         interaction_sets = _build_interaction_sets(p, x)
@@ -980,7 +988,7 @@ class GBMEstimator(ModelBuilder):
             while done < ntrees:
                 kk = min(_chunk, ntrees - done)
                 stepprof.chunk_begin()
-                with telemetry.span("gbm.chunk", trees=kk):
+                with telemetry.span("gbm.chunk", trees=kk, **paths):
                     tr_k, margins, vm_, gains, devs = _boost_scan_multi(
                         bm.bins, bm.nbins, y_dev, w, margins, key,
                         vb_, vy_, vw_, vm_, interaction_sets, tp=tp,
@@ -1116,7 +1124,7 @@ class GBMEstimator(ModelBuilder):
                 while done < ntrees:
                     k = min(_chunk, ntrees - done)
                     stepprof.chunk_begin()
-                    with telemetry.span("gbm.chunk", trees=k):
+                    with telemetry.span("gbm.chunk", trees=k, **paths):
                         tr_k, margin, gains = _boost_scan(
                             bm.bins, bm.nbins, y_dev, w, margin, key,
                             constraints, interaction_sets, tp=tp,
@@ -1174,7 +1182,7 @@ class GBMEstimator(ModelBuilder):
                 while done < ntrees:
                     k = min(_chunk, ntrees - done)
                     stepprof.chunk_begin()
-                    with telemetry.span("gbm.chunk", trees=k):
+                    with telemetry.span("gbm.chunk", trees=k, **paths):
                         tr_k, margin, vm_, gains, devs = \
                             _boost_scan_scored(
                                 bm.bins, bm.nbins, y_dev, w, margin, key,
@@ -1355,7 +1363,6 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
             nbins_total=bm.nbins_total,
             cat_feats=tuple(bool(v) for v in bm.is_cat),
             block_rows=16384 if bm.bins.shape[0] > 8_388_608 else 4096,
-            exact_f32=exact_f32_for(bm),
             pallas=pallas_ops.resolve_tree_mode())
 
     tps = [_tp_of(b.params) for b in builders]
@@ -1417,7 +1424,8 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
         k = min(_chunk, ntrees - done)
         alive = M - sum(stopped)
         stepprof.chunk_begin()
-        with telemetry.span("gbm.chunk", trees=k, batch=M):
+        with telemetry.span("gbm.chunk", trees=k, batch=M,
+                            **_level_paths(tp0, bm.bins.shape[1])):
             tr_b, margins, gains_b, devs_b = _boost_scan_batched(
                 bm.bins, bm.nbins, y_dev, w, margins, keys, knobs_b,
                 constraints, interaction_sets, tp=tp0, dist=dist,

@@ -22,11 +22,8 @@ from h2o3_tpu.parallel.mesh import get_mesh
 
 AUC_NBINS = 400  # hex/AUC2.java:24
 
-# metric sums always run true-f32 matmuls: a single one-hot matmul per
-# pass, so the 6-pass TPU emulation is cheap here — and served metrics
-# must hit the reference pyunits' 1e-5 equality bars (bf16x3 residue
-# was the round-2 pyunit_weights_gbm "10x bug" that was really 2e-5)
-_PREC = jax.lax.Precision.HIGHEST
+# metric sums are float32 sums on every backend (ops/segments.py):
+# served metrics must hit the reference pyunits' 1e-5 equality bars
 
 # Every metric runs ONE jitted device pass (the MetricBuilder-inside-
 # MRTask single sweep) and finishes scalars on host — un-jitted
@@ -43,10 +40,10 @@ def _binomial_pass(p, y, w, *, mesh):
                    w * (p - y) ** 2,
                    -w * (y * jnp.log(pc) + (1 - y) * jnp.log(1 - pc)),
                    w * y], axis=1),
-        n_nodes=1, mesh=mesh, precision=_PREC)
+        n_nodes=1, mesh=mesh)
     bins = jnp.clip((pc * AUC_NBINS).astype(jnp.int32), 0, AUC_NBINS - 1)
     hist = segment_sum(bins, jnp.stack([w * y, w * (1.0 - y)], axis=1),
-                       n_nodes=AUC_NBINS, mesh=mesh, precision=_PREC)
+                       n_nodes=AUC_NBINS, mesh=mesh)
     return sums[0], hist
 
 
@@ -146,9 +143,9 @@ def _multinomial_pass(probs, y, w, *, mesh):
     sums = segment_sum(
         jnp.zeros_like(y), jnp.stack([w, -w * jnp.log(py), w * onehot_err,
                                       w * sse], axis=1),
-        n_nodes=1, mesh=mesh, precision=_PREC)
+        n_nodes=1, mesh=mesh)
     cm = segment_sum((y * K + pred).astype(jnp.int32), w[:, None],
-                     n_nodes=K * K, mesh=mesh, precision=_PREC)
+                     n_nodes=K * K, mesh=mesh)
     return sums[0], cm
 
 
@@ -164,7 +161,7 @@ def _multinomial_score_hists(probs, y, w, *, mesh):
         b = jnp.clip((probs[:, k] * AUC_NBINS).astype(jnp.int32),
                      0, AUC_NBINS - 1)
         hk = segment_sum((y * AUC_NBINS + b).astype(jnp.int32), w[:, None],
-                         n_nodes=K * AUC_NBINS, mesh=mesh, precision=_PREC)
+                         n_nodes=K * AUC_NBINS, mesh=mesh)
         out.append(hk.reshape(K, AUC_NBINS))
     return jnp.stack(out)                    # [K(prob), K(true), B]
 
@@ -254,7 +251,7 @@ def _regression_pass(pred, y, w, dev, *, mesh):
         jnp.zeros(y.shape[0], jnp.int32),
         jnp.stack([w, w * (y - pred) ** 2, w * jnp.abs(y - pred),
                    w * rmsle_term, w * y, w * y * y, w * dev], axis=1),
-        n_nodes=1, mesh=mesh, precision=_PREC)
+        n_nodes=1, mesh=mesh)
     return sums[0]
 
 

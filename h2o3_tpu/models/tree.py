@@ -112,16 +112,6 @@ class TreeParams:
     cat_feats: tuple = ()            # per-feature is-categorical flags —
                                      # schema-static, activates the
                                      # sorted-prefix subset-split path
-    exact_f32: bool = False          # true-f32 LEAF-value sums (vs TPU
-                                     # bf16x3) on small problems where
-                                     # pyunits assert 1e-5 metric
-                                     # equality. Histograms stay bf16x3
-                                     # (HIGHEST inside the level loop
-                                     # multiplies compile time); split
-                                     # ties may still flip across row
-                                     # orders — uniform-weight
-                                     # normalization covers the exact-
-                                     # equality contracts instead
     pallas: str = "off"              # level-pass backend:
                                      # "off" = XLA, "native"/"interpret"
                                      # = ops/pallas/treekernel. STATIC
@@ -136,13 +126,19 @@ class TreeParams:
         return any(self.cat_feats)
 
 
-def exact_f32_for(bm) -> bool:
-    """True-f32 LEAF-sum mode for pyunit-scale problems: TPU bf16x3
-    residue (~1e-5 relative) in leaf values fails reference
-    metric-equality assertions, and a single leaf matmul at HIGHEST is
-    free below this size (histograms are excluded — see TreeParams)."""
-    return (bm.bins.shape[0] * bm.bins.shape[1] * bm.nbins_total
-            <= (1 << 26))
+def kernel_levels(params: TreeParams, n_features: int) -> tuple:
+    """Per level of ``params.max_depth``, whether grow_tree runs it
+    through the Pallas level kernels (ops/pallas/treekernel.py) — the
+    fit's STATIC ``params.pallas`` knob, and per LEVEL whether the
+    level's shapes fit a VMEM tile (ops/pallas.tile_rows — deep levels'
+    accumulators do not; they take the XLA sequence). The one place
+    that decides it: grow_tree follows it and the fits report it on
+    their ``*.chunk`` spans (``levels_kernel`` / ``levels_xla``)."""
+    if params.pallas not in ("native", "interpret"):
+        return (False,) * params.max_depth
+    from h2o3_tpu.ops.pallas import tile_rows
+    return tuple(tile_rows(n_features, params.nbins_total, 2 ** d) > 0
+                 for d in range(params.max_depth))
 
 
 def row_feature_values(bins, f_r):
@@ -264,21 +260,16 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
     allowed = jnp.ones((1, F), bool)   # per-node feature set (interactions)
     pair_allow = None                  # lazy [F, F] compatibility matrix
 
-    # exact_f32 scopes to the LEAF value sums only: HIGHEST-precision
-    # matmuls inside the level loop multiply XLA compile time (6-pass
-    # f32 emulation unrolled through the boosting scan — observed 600s+
-    # pyunit wallclock vs 90s), while the leaf segment_sum is a single
-    # small matmul whose exactness the weight≡duplication metric
-    # contracts actually observe
-    prec = jax.lax.Precision.HIGHEST if params.exact_f32 else None
     # Pallas level kernels (ops/pallas/treekernel.py): the histogram
-    # and row-partition passes over the bin-major tiles, selected per
-    # fit via the STATIC params.pallas knob and per LEVEL by whether
-    # the level's shapes fit a VMEM tile (ops/pallas.tile_rows — deep
-    # levels' accumulators do not; they take the XLA sequence below).
-    # The stats block {w, w·g, w·h} is level-invariant, so it is built
-    # once here (the XLA path rebuilds the same values inside
+    # and row-partition passes over the bin-major tiles, where
+    # kernel_levels says so; the XLA sequence below elsewhere. Either
+    # way every sum of {w, w·g, w·h} is a float32 sum (ops/histogram.py
+    # split3). The stats block is level-invariant, so it is built once
+    # here (the XLA path rebuilds the same values inside
     # ops/histogram.py).
+    fused = kernel_levels(params, F)
+    # asked for, which is more than any(fused): a level that then fits
+    # no tile is a counted fallback, most of all when none fits
     use_kernels = params.pallas in ("native", "interpret")
     if use_kernels:
         from h2o3_tpu.ops import pallas as pallas_policy
@@ -293,7 +284,7 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
             cm = _mtries_mask(sub, L, F, mtries) & col_mask[None, :]
         if interaction_sets is not None:
             cm = (cm if cm.ndim == 2 else cm[None, :]) & allowed
-        use_fused = use_kernels and pallas_policy.tile_rows(F, B, L) > 0
+        use_fused = fused[d]
         if use_kernels and not use_fused:
             pallas_policy.record_fallback("level_fits_no_tile")
         if use_fused:
@@ -398,9 +389,10 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
 
     # leaf Newton values from final assignment (GammaPass analogue)
     nleaf = 2 ** D
-    stats = jnp.stack([w, w * g, w * h], axis=1)
-    leaf_stats = segment_sum(nid, stats, n_nodes=nleaf, mesh=mesh,
-                             block_rows=params.block_rows, precision=prec)
+    with jax.named_scope("tree.leaf_sums"):
+        stats = jnp.stack([w, w * g, w * h], axis=1)
+        leaf_stats = segment_sum(nid, stats, n_nodes=nleaf, mesh=mesh,
+                                 block_rows=params.block_rows)
     G, H = leaf_stats[:, 1], leaf_stats[:, 2]
     leaf = jnp.where(leaf_stats[:, 0] > 0,
                      -G / (H + sc.reg_lambda + 1e-10), 0.0)

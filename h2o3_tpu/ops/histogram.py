@@ -8,9 +8,16 @@ reduce = elementwise histogram add up the thread/node trees
 TPU-native: scatter-add is MXU-hostile, so the accumulation is recast as
 two matmuls per row-block (SURVEY §7 "hard parts" #1):
 
-    left  [3L, C] = (one_hot(node) ⊗ [w, g, h])ᵀ     (C = block rows)
-    right [C, FB] = one_hot(feature-bin)             (0/1, bf16)
-    hist += left @ right                             → [3L, FB]
+    left  [9L, C] = one_hot(node) ⊗ pieces([w, g, h])   (C = block rows)
+    right [C, FB] = one_hot(feature-bin)                (0/1)
+    acc  += left @ right                                → [9L, FB]
+
+Both operands are bfloat16 and every sum is a float32 sum: the one-hot
+is exact in bfloat16, and each float32 statistic enters as three
+bfloat16 pieces that add up to it bit for bit (``split3``), so one MXU
+pass a piece multiplies exactly and accumulates in float32; the three
+[3L, FB] slabs are added at the end (``sum_pieces``). The same code runs
+on every backend and at every size.
 
 The contraction over C rows runs on the systolic array; ``lax.scan`` over
 row blocks bounds memory (the F/J chunk loop analogue); ``psum`` over the
@@ -43,78 +50,136 @@ import os as _os
 _USE_PALLAS_FLAG = _os.environ.get("H2O3_TPU_PALLAS_HIST") == "1"
 
 
-def _block_hist(bins_blk, nid_blk, stats_blk, n_nodes: int, n_bins: int,
-                precision=None):
-    """One row-block's [3L, FB] partial histogram via MXU matmul."""
+def split3(v):
+    """A float32 array as three float32 arrays that each hold a bfloat16
+    value (8 significand bits) and add up to ``v`` bit for bit: the
+    leading 8 bits, the next 8, the last 8. The MXU multiplies bfloat16
+    operands exactly and adds in float32, so three one-pass products
+    of the pieces with a 0/1 operand, added, are the float32 sum.
+
+    The bits are masked, not rounded through ``astype``: a compiler
+    that is allowed excess precision may drop a float32 → bfloat16 →
+    float32 round trip, and the split would then silently be no split.
+    Subnormal pieces may flush to zero; inf and NaN do not survive
+    (they poison a one-hot product anyway: 0 * inf)."""
+    def top(x):
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        return jax.lax.bitcast_convert_type(bits & jnp.int32(-65536),
+                                            jnp.float32)
+    v = v.astype(jnp.float32)
+    hi = top(v)
+    rest = v - hi
+    mid = top(rest)
+    return hi, mid, rest - mid
+
+
+def piece_rows(n_nodes: int) -> int:
+    """Rows of ``stat_rows``' block: 3 pieces x ``n_nodes`` x 3 stats,
+    up to the 16 sublanes of a bfloat16 tile."""
+    return -(-9 * n_nodes // 16) * 16
+
+
+def stat_rows(nid, stats, n_nodes: int):
+    """The statistics operand of the histogram product, rows on the
+    lanes: ``nid`` [1, C] int32 and ``stats`` [3, C] float32 ({w, w·g,
+    w·h}) → bfloat16 [piece_rows(n_nodes), C]. Row ``p·3L + 3·node + s``
+    holds piece ``p`` (``split3``) of stat ``s`` where the row's node is
+    ``node``, else 0. One function for the XLA path and for the body of
+    the Pallas kernels (only what Mosaic lowers: iota, compare, select,
+    bit masks), so both feed the MXU the same operand.
+
+    The stat is SELECTED into its rows (never a masked add): a NaN stat
+    must not bleed into its siblings' rows the way 0*NaN would."""
+    L3 = 3 * n_nodes
+    k = jax.lax.broadcasted_iota(jnp.int32, (piece_rows(n_nodes), 1), 0)
+    piece = k // L3
+    rem = k - piece * L3
+    node = rem // 3
+    stat = rem - 3 * node
+
+    def of_stat(x):                                          # [3, C] -> [M, C]
+        return jnp.where(stat == 0, x[0:1],
+                         jnp.where(stat == 1, x[1:2], x[2:3]))
+
+    hi, mid, lo = split3(stats)
+    val = jnp.where(piece == 0, of_stat(hi),
+                    jnp.where(piece == 1, of_stat(mid), of_stat(lo)))
+    hit = (nid == node) & (piece < 3)
+    return jnp.where(hit, val, 0.0).astype(jnp.bfloat16)
+
+
+def sum_pieces(acc, n_nodes: int):
+    """[piece_rows(n_nodes), FB] products of ``stat_rows`` → the
+    float32 sums [3L, FB]."""
+    L3 = 3 * n_nodes
+    return acc[:L3] + acc[L3:2 * L3] + acc[2 * L3:3 * L3]
+
+
+def _block_hist(bins_blk, nid_blk, stats_blk, n_nodes: int, n_bins: int):
+    """One row-block's [piece_rows, FB] partial products via MXU matmul:
+    ``bins_blk`` [C, F], ``nid_blk`` [1, C], ``stats_blk`` [3, C]."""
     C, F = bins_blk.shape
-    # right: 0/1 indicator of (feature, bin) per row — exact in bf16
+    # right: 0/1 indicator of (feature, bin) per row — exact in bf16;
+    # left: the float32 stats as bf16 pieces, so one pass is exact
     onehot_fb = (bins_blk[:, :, None] ==
                  jnp.arange(n_bins, dtype=jnp.int32)[None, None, :])
-    right = onehot_fb.reshape(C, F * n_bins).astype(jnp.float32)
-    # left: stats routed to the row's node. f32 on both sides: the stats
-    # side would lose ~0.4% in bf16, corrupting gains; XLA's bf16x3 pass
-    # keeps the MXU busy for f32 contractions. ``precision=HIGHEST``
-    # (small-problem mode) trades MXU rate for true-f32 accumulation —
-    # the reference pyunits assert metric equality at 1e-5 relative,
-    # which bf16x3 residue can miss (pyunit_weights_gbm, 1.9e-5 off).
-    node_oh = (nid_blk[:, None] ==
-               jnp.arange(n_nodes, dtype=jnp.int32)[None, :]).astype(jnp.float32)
-    left = (node_oh[:, :, None] * stats_blk[:, None, :])  # [C, L, 3]
-    left = left.reshape(C, n_nodes * 3)
+    right = onehot_fb.reshape(C, F * n_bins).astype(jnp.bfloat16)
     return jax.lax.dot_general(
-        left.T, right, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=precision)
+        stat_rows(nid_blk, stats_blk, n_nodes), right,
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _local_histogram(bins, nid, stats, n_nodes: int, n_bins: int,
-                     block_rows: int, precision=None):
-    """Scan row blocks of one shard, accumulating the [L,F,B,3] histogram."""
+                     block_rows: int):
+    """Scan row blocks of one shard (``bins`` [N, F], ``nid`` [N],
+    ``stats`` [3, N]), accumulating the [L,F,B,3] histogram."""
     N, F = bins.shape
     C = min(block_rows, N)
     nblk = (N + C - 1) // C
     Npad = nblk * C
     if Npad != N:
+        # padding rows carry zero stats so they contribute nothing
         bins = jnp.pad(bins, ((0, Npad - N), (0, 0)))
         nid = jnp.pad(nid, (0, Npad - N))
-        stats = jnp.pad(stats, ((0, Npad - N), (0, 0)))  # w=0 ⇒ no effect? see below
-        # padding rows carry zero stats so they contribute nothing
+        stats = jnp.pad(stats, ((0, 0), (0, Npad - N)))
     bins_b = bins.reshape(nblk, C, F)
-    nid_b = nid.reshape(nblk, C)
-    stats_b = stats.reshape(nblk, C, 3)
+    nid_b = nid.reshape(nblk, 1, C)
+    stats_b = stats.reshape(3, nblk, C).transpose(1, 0, 2)
 
     def step(acc, xs):
         b, n, s = xs
-        return acc + _block_hist(b, n, s, n_nodes, n_bins,
-                                 precision=precision), None
+        return acc + _block_hist(b, n, s, n_nodes, n_bins), None
 
-    init = jnp.zeros((n_nodes * 3, F * n_bins), jnp.float32)
+    init = jnp.zeros((piece_rows(n_nodes), F * n_bins), jnp.float32)
     acc, _ = jax.lax.scan(step, init, (bins_b, nid_b, stats_b))
     # [3L, FB] -> [L, F, B, 3]
-    return acc.reshape(n_nodes, 3, F, n_bins).transpose(0, 2, 3, 1)
+    return sum_pieces(acc, n_nodes).reshape(
+        n_nodes, 3, F, n_bins).transpose(0, 2, 3, 1)
 
 
 def histogram(bins, nid, w, g, h, *, n_nodes: int, n_bins: int,
-              mesh, block_rows: int = 16384, precision=None):
+              mesh, block_rows: int = 16384):
     """All-reduced histogram [n_nodes, F, n_bins, {w,g,h}] over the mesh.
 
     Inputs are row-sharded over 'data'; output is replicated. Padding rows
     must have w == 0; stats accumulate {w, w·g, w·h} exactly as the
-    reference accumulates {w, wY, wYY}.
+    reference accumulates {w, wY, wYY}: float32 sums, on any backend
+    (``split3``).
     """
-    stats = jnp.stack([w, w * g, w * h], axis=1).astype(jnp.float32)
+    stats = jnp.stack([w, w * g, w * h]).astype(jnp.float32)   # [3, N]
     ndata = mesh.shape[DATA_AXIS]
     N = bins.shape[0]
     if N % ndata != 0:
         pad = ndata - N % ndata
         bins = jnp.pad(bins, ((0, pad), (0, 0)))
         nid = jnp.pad(nid, (0, pad))
-        stats = jnp.pad(stats, ((0, pad), (0, 0)))
+        stats = jnp.pad(stats, ((0, 0), (0, pad)))
 
     use_pallas = jax.default_backend() == "tpu" and _USE_PALLAS_FLAG
 
     @functools.partial(
         shard_map, mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
+        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(None, DATA_AXIS)),
         out_specs=P(), check_vma=False)
     def _task(bins_l, nid_l, stats_l):
         if use_pallas:
@@ -124,7 +189,7 @@ def histogram(bins, nid, w, g, h, *, n_nodes: int, n_bins: int,
                                           block_rows=min(block_rows, 512))
         else:
             hist = _local_histogram(bins_l, nid_l, stats_l, n_nodes, n_bins,
-                                    block_rows, precision=precision)
+                                    block_rows)
         # psum over 'data' only: inputs are replicated over 'model', so
         # including it would scale every stat by the model-axis size
         return jax.lax.psum(hist, DATA_AXIS)

@@ -136,21 +136,23 @@ def tile_rows(n_features: int, n_bins: int, n_nodes: int,
     levels). A level that gets 0 takes the XLA composition. Pure math,
     no backend.
 
-    The per-row costs are what Mosaic allocated in compile sweeps for a
-    v5e (libtpu 0.0.34) over F in 4..60, B in 17..256, levels 0..8,
-    with a margin of 1.5x or more. The histogram kernel's row costs one
-    f32 lane-padded [F·B] one-hot row plus its [C, 3Lh] temporaries
-    (each at least one 128-lane tile wide); the partition kernel keeps
-    rows on the lanes and costs the sublanes of its [L, C], [B-1, C]
-    and [F, C] blocks.
+    The costs are what Mosaic allocated in compile sweeps for a v5e
+    (libtpu 0.0.34) over F in 4..60, B in 17..256, levels 0..8: the
+    largest tile it accepted was twice this or more at every shape.
+    The histogram kernel keeps one float32 accumulator of
+    ``piece_rows`` x F·B (three bfloat16 pieces a statistic,
+    ops/histogram.stat_rows: three times the rows of the sums it
+    yields) and pays per tile row the bfloat16 [F·B] one-hot row and
+    its column of the [piece_rows, C] statistics operand; the
+    partition kernel keeps rows on the lanes and costs the sublanes of
+    its [L, C], [B-1, C] and [F, C] blocks.
     """
     if n_bins > MAX_KERNEL_BINS:
         return 0
     fb = 4 * _up(n_features * n_bins, 128)           # one f32 [., F·B] row
-    lh3 = 3 * max(n_nodes // 2, 1)
-    hist_row = (5 * (fb + 2048 + 8 * _up(lh3, 128))) // 4
-    # accumulator scratch + double-buffered output block
-    hist_fixed = 3 * _up(lh3, 8) * fb
+    pieces = _up(9 * max(n_nodes // 2, 1), 16)  # ops/histogram.piece_rows
+    hist_row = (5 * (fb // 2 + 6 * pieces + 1024)) // 4
+    hist_fixed = (9 * pieces * fb) // 8
     part_row = 4 * (2 * _up(n_nodes, 8) + 2 * _up(n_bins - 1, 8)
                     + 2 * _up(n_features, 32) + 32)
     # double-buffered [B-1, L] f32 left-set block

@@ -10,7 +10,10 @@ tree-boosting systems do (Booster arxiv 2011.02022; XGBoost-GPU arxiv
 1806.11248), on every mesh alike:
 
 - a per-shard histogram kernel streams the tiles through VMEM and
-  accumulates the [3L, F·B] histogram in a VMEM scratch on the MXU;
+  accumulates the histogram in a VMEM scratch on the MXU — both
+  operands bfloat16, the float32 statistics as three pieces split on
+  the tile in VMEM (ops/histogram.stat_rows), so every sum is a
+  float32 sum; the three [3L, F·B] slabs are added outside;
 - the cross-shard ``psum`` (the MRTask reduce tree,
   water/MRTask.java:891 — a no-op on one shard) and the level boundary
   in plain XLA: sibling subtraction against the parent level and the
@@ -42,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu.ops import pallas as pallas_policy
+from h2o3_tpu.ops.histogram import piece_rows, stat_rows, sum_pieces
 from h2o3_tpu.ops.split_scan import best_splits
 from h2o3_tpu.parallel.mesh import DATA_AXIS
 
@@ -68,29 +72,27 @@ def _pad_lanes(arr, n_pad: int):
 
 # ----------------------------------------------------- kernel block bodies
 #
-# Layout: PER-ROW VALUES RIDE THE LANES ON THEIR WAY IN. Node ids reach
-# a kernel as a [1, C] row, stats as a [3, C] block, and the partition
-# kernel reads the bins transposed, [F, C] — the last (lane) axis is the
-# row axis, so they are lane-dense in HBM. The row-major alternative,
+# Layout: PER-ROW VALUES RIDE THE LANES. Node ids reach a kernel as a
+# [1, C] row, stats as a [3, C] block, and the partition kernel reads
+# the bins transposed, [F, C] — the last (lane) axis is the row axis,
+# so they are lane-dense in HBM and in VMEM. The row-major alternative,
 # [N, 1] and [N, 3] operands, pads each to 128 lanes: 2.7 GB apiece in
-# HBM at 5M rows (the boost scan's temporaries compiled to 14 GB). The
-# partition kernel computes in that orientation throughout. The
-# histogram kernel transposes its two small blocks back to columns
-# inside VMEM (and takes the bins tile row-major, [C, F]): its product
-# has to be the XLA path's own ``left.T @ right`` for interpret-mode bit
-# parity, which a lane-major ``left`` breaks in the last bit.
+# HBM at 5M rows (the boost scan's temporaries compiled to 14 GB). Both
+# kernels compute in that orientation; only the histogram kernel's bins
+# tile is row-major, [C, F], because its one-hot is the product's
+# [C, F·B] right operand.
 
 
 def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int):
-    """One tile's [3Lh, F·B] partial histogram — VMEM one-hots feeding
-    the MXU. ``bins`` [C, F], ``nid`` [C, 1], ``stats`` [C, 3]: the
-    row-major forms of ops/histogram._block_hist, whose values (not
-    just sums) this matches — the one-hot indicators are exact 0/1, the
-    stats ride untouched and the contraction is the same
-    ``left.T @ right``, so the f32 accumulation sees identical operands
-    in an identical order. At levels d >= 1 only LEFT-child rows
-    accumulate, into their PARENT's slot (the sibling-subtraction trick
-    of grow_tree, kept inside the kernel).
+    """One tile's [piece_rows(Lh), F·B] partial products — VMEM one-hots
+    feeding the MXU. ``bins`` [C, F], ``nid`` [1, C], ``stats`` [3, C]:
+    the forms of ops/histogram._block_hist, whose values (not just
+    sums) this matches — the one-hot indicators are exact 0/1, the
+    stats operand is the same ``stat_rows`` and the contraction the
+    same ``left @ right``, so the f32 accumulation sees identical
+    operands in an identical order. At levels d >= 1 only LEFT-child
+    rows accumulate, into their PARENT's slot (the sibling-subtraction
+    trick of grow_tree, kept inside the kernel).
 
     The (feature, bin) indicator is built by EXPANDING the row's bins
     across the F·B lanes with a [C, F] x [F, F·B] 0/1 selection matmul
@@ -102,7 +104,7 @@ def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int):
     FB = F * n_bins
     assert n_bins <= pallas_policy.MAX_KERNEL_BINS, n_bins
     if d > 0:
-        even = ((nid % 2) == 0).astype(jnp.float32)      # [C, 1]
+        even = ((nid % 2) == 0).astype(jnp.float32)      # [1, C]
         stats = stats * even
         nid = nid >> 1
     lane_f = jax.lax.broadcasted_iota(jnp.int32, (F, FB), 1) // n_bins
@@ -112,20 +114,10 @@ def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int):
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)              # [C, FB]
     lane_b = jax.lax.broadcasted_iota(jnp.int32, (1, FB), 1) % n_bins
-    right = (row_bin == lane_b.astype(jnp.float32)).astype(jnp.float32)
-    lane3 = jax.lax.broadcasted_iota(jnp.int32, (C, n_nodes_h * 3), 1)
-    node_of_k = lane3 // 3
-    stat_of_k = lane3 - 3 * node_of_k
-    node_hit = (nid == node_of_k).astype(jnp.float32)    # [C, 3Lh]
-    # stat broadcast via SELECT (not masked add): a NaN stat lane must
-    # not bleed into its siblings' columns the way 0*NaN would
-    stat_b = jnp.where(stat_of_k == 0, stats[:, 0:1],
-                       jnp.where(stat_of_k == 1, stats[:, 1:2],
-                                 stats[:, 2:3]))
-    left = node_hit * stat_b                             # [C, 3Lh]
+    right = (row_bin == lane_b.astype(jnp.float32)).astype(jnp.bfloat16)
     return jax.lax.dot_general(
-        left.T, right, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        stat_rows(nid, stats, n_nodes_h), right,
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _level_boundary(lh, prev_hist, cm, nb, is_cat, constraints, lo, hi,
@@ -191,10 +183,15 @@ def _partition_block(bins, nid, bf, bt, bnal, isp, cs, leftmask, *,
     isna = b_r == (n_bins - 1)
     go_num = b_r <= t_r
     # leftmask[nid, b_r] without a 2D gather: 0/1 matmul over nodes,
-    # then a sublane select over bins (exact — operands are indicators)
+    # then a sublane select over bins. Both operands are indicators,
+    # exact in the MXU's one bfloat16 pass — so that pass is NAMED: a
+    # float32 product with no precision at all is what the precision
+    # contract (tests/test_gbm_reference_parity.py) takes for a
+    # forgotten one. (HIGHEST here costs the kernel 2.6x on a v5e; a
+    # bfloat16 pair is a dot the CPU runtime refuses in this program.)
     row_mask = jax.lax.dot_general(
         leftmask, jnp.where(noh, 1.0, 0.0).astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.float32)                      # [B-1, C]
     bio = jax.lax.broadcasted_iota(jnp.int32, (n_bins - 1, C), 0)
     inset = jnp.sum(jnp.where(bio == b_r, row_mask, 0.0), axis=0,
@@ -215,7 +212,7 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += _hist_block(bins_ref[:], nid_ref[:].T, stats_ref[:].T,
+    acc_ref[:] += _hist_block(bins_ref[:], nid_ref[:], stats_ref[:],
                               n_nodes_h=n_nodes_h, n_bins=n_bins, d=d)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -230,22 +227,24 @@ def _lane_tile(k: int, C: int):
 def _hist_call(bins, nid, stats, *, d, n_nodes, n_bins, block_rows,
                interpret):
     """Per-shard histogram kernel over ``bins`` [N, F], ``nid`` [1, N],
-    ``stats`` [3, N] → [3Lh, F·B] (caller psums)."""
+    ``stats`` [3, N] → float32 sums [3Lh, F·B] (caller psums)."""
     N, F = bins.shape
     C, nblk, n_pad = _tile_geometry(N, block_rows)
     Lh = max(n_nodes // 2, 1)
+    acc = (piece_rows(Lh), F * n_bins)
     pallas_policy.record_launch("tree_hist")
-    return pl.pallas_call(
+    pieces = pl.pallas_call(
         functools.partial(_hist_kernel, d=d, n_nodes_h=Lh, n_bins=n_bins),
         grid=(nblk,),
         in_specs=[pl.BlockSpec((C, F), lambda i: (i, 0)),
                   _lane_tile(1, C), _lane_tile(3, C)],
-        out_specs=pl.BlockSpec((3 * Lh, F * n_bins), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((3 * Lh, F * n_bins), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((3 * Lh, F * n_bins), jnp.float32)],
+        out_specs=pl.BlockSpec(acc, lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(acc, jnp.float32),
+        scratch_shapes=[pltpu.VMEM(acc, jnp.float32)],
         interpret=interpret, name="tree_hist",
     )(jnp.pad(bins, ((0, n_pad - N), (0, 0))), _pad_lanes(nid, n_pad),
       _pad_lanes(stats, n_pad))
+    return sum_pieces(pieces, Lh)
 
 
 def _partition_kernel(bins_ref, nid_ref, bf_ref, bt_ref, bnal_ref,

@@ -10,12 +10,14 @@ ScoreBuildHistogram2 (hex/tree/DHistogram.java:585-674) stays on-chip.
 
 Layout per grid step i over row blocks of C rows:
     bins_blk  [C, F] int32      (feature-bin ids; NA bin = B-1)
-    nid_blk   [C, 1] int32      (current leaf per row)
-    stats_blk [C, 3] f32        ({w, w·g, w·h}; 0 on padding rows)
-    right     [C, F·B]  = one-hot(bins)       built in VMEM
-    left      [C, 3L]   = one-hot(nid) ⊗ stats
-    acc      += leftᵀ @ right                  (MXU, f32)
-Final step writes acc → out [3L, F·B]; caller reshapes to [L, F, B, 3].
+    nid_blk   [1, C] int32      (current leaf per row, on the lanes)
+    stats_blk [3, C] f32        ({w, w·g, w·h}; 0 on padding rows)
+    right     [C, F·B]  = one-hot(bins)       built in VMEM, bf16
+    left      [9L, C]   = one-hot(nid) ⊗ bf16 pieces of the stats
+                          (ops/histogram.stat_rows: float32 sums)
+    acc      += left @ right                   (MXU, f32)
+Final step writes acc → out; the caller adds the three slabs and
+reshapes to [L, F, B, 3].
 """
 
 from __future__ import annotations
@@ -26,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from h2o3_tpu.ops.histogram import piece_rows, stat_rows, sum_pieces
 
 
 def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
@@ -49,22 +53,9 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
     for f in range(1, F):
         right += (lane == fb[:, f:f + 1]).astype(jnp.float32)
 
-    # left [C, 3L] with column k ↦ (node k//3, stat k%3), built without
-    # any minor-dim reshape (Mosaic-unsupported): three masked
-    # broadcast-multiplies against the lane iota
-    nid = nid_ref[:]                       # [C, 1]
-    stats = stats_ref[:]                   # [C, 3]
-    lane3 = jax.lax.broadcasted_iota(jnp.int32, (C, n_nodes * 3), 1)
-    node_of_k = lane3 // 3
-    stat_of_k = lane3 - 3 * node_of_k
-    node_hit = (nid == node_of_k).astype(jnp.float32)        # [C, 3L]
-    left = jnp.zeros((C, n_nodes * 3), jnp.float32)
-    for s in range(3):
-        sel = (stat_of_k == s).astype(jnp.float32)
-        left += sel * node_hit * stats[:, s:s + 1]
-
     acc_ref[:] += jax.lax.dot_general(
-        left, right, (((0,), (0,)), ((), ())),
+        stat_rows(nid_ref[:], stats_ref[:], n_nodes),
+        right.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -74,7 +65,8 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
 
 def pallas_local_histogram(bins, nid, stats, n_nodes: int, n_bins: int,
                            block_rows: int = 512, interpret: bool = False):
-    """Single-shard histogram [L, F, B, 3] via the Pallas kernel.
+    """Single-shard histogram [L, F, B, 3] via the Pallas kernel, from
+    ``bins`` [N, F], ``nid`` [N] and ``stats`` [3, N].
 
     Drop-in replacement for ops/histogram._local_histogram on TPU
     backends (CPU tests run it with interpret=True).
@@ -88,25 +80,26 @@ def pallas_local_histogram(bins, nid, stats, n_nodes: int, n_bins: int,
     if Npad != N:   # padding rows carry zero stats → no contribution
         bins = jnp.pad(bins, ((0, Npad - N), (0, 0)))
         nid = jnp.pad(nid, (0, Npad - N))
-        stats = jnp.pad(stats, ((0, Npad - N), (0, 0)))
+        stats = jnp.pad(stats, ((0, 0), (0, Npad - N)))
 
     kern = functools.partial(_hist_kernel, n_nodes=n_nodes, n_bins=n_bins)
+    acc = (piece_rows(n_nodes), F * n_bins)
     out = pl.pallas_call(
         kern,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((C, F), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((C, 1), lambda i: (i, 0),
+            pl.BlockSpec((1, C), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((C, 3), lambda i: (i, 0),
+            pl.BlockSpec((3, C), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((n_nodes * 3, F * n_bins), lambda i: (0, 0),
+        out_specs=pl.BlockSpec(acc, lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_nodes * 3, F * n_bins),
-                                       jnp.float32),
-        scratch_shapes=[pltpu.VMEM((n_nodes * 3, F * n_bins), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct(acc, jnp.float32),
+        scratch_shapes=[pltpu.VMEM(acc, jnp.float32)],
         interpret=interpret, name="histogram",
-    )(bins, nid.reshape(-1, 1), stats)
-    return out.reshape(n_nodes, 3, F, n_bins).transpose(0, 2, 3, 1)
+    )(bins, nid.reshape(1, -1), stats)
+    return sum_pieces(out, n_nodes).reshape(
+        n_nodes, 3, F, n_bins).transpose(0, 2, 3, 1)

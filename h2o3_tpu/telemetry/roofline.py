@@ -165,7 +165,10 @@ def analytic_tree_cost(rows: int, features: int, trees: int, depth: int,
     """Histogram-build matmuls — the tree FLOPs that touch the MXU: per
     row per tree, levels 0..depth-1 contract [3·2^l, C] x [C, F·B]
     (ops/histogram.py _block_hist; same count bench.py's historical
-    mfu_pct used). Bytes: each level re-streams the int8 binned matrix,
+    mfu_pct used). A float32 product counted once: that each statistic
+    enters as three bfloat16 pieces, so the MXU does three times this,
+    is how the sums stay float32, not more work done.
+    Bytes: each level re-streams the int8 binned matrix,
     the 3-stat payload, and the node-id vector."""
     flops = 2.0 * 3.0 * (2 ** depth - 1) * features * bins * rows * trees
     bytes_ = float(rows) * trees * depth * (features + 3 * 4 + 4)

@@ -41,6 +41,7 @@ AIR_ROWS, F, B = 5_000_000, 10, 126
 AIR_CATS = (False,) * 6 + (True,) * 3 + (False,)
 GLM_ROWS, DL_ROWS, SCORE_ROWS = 2_000_000, 200_000, 100_000
 HIGGS_ROWS = 11_000_000                  # benchmark cell glm-higgs.fit-11m
+AIR48_ROWS = 48_000_000                  # cell gbm-airlines-d6.fit-48m (F, B)
 TINY_ROWS = 3000
 LEVELS = (0, 3, 5)                       # of depth-bucket 6
 
@@ -201,7 +202,7 @@ def test_opt_in_histogram_kernel_native(topo):
     jax.jit(lambda b, i, s: pallas_local_histogram(
         b, i, s, 32, B, block_rows=512)).lower(
         S((n, F), jnp.int8, sharding=one), S((n,), jnp.int32, sharding=one),
-        S((n, 3), jnp.float32, sharding=one)).compile()
+        S((3, n), jnp.float32, sharding=one)).compile()
 
 
 # --------------------------------- the fits' and the scorer's own programs
@@ -266,6 +267,24 @@ def test_gbm_boost_chunk(topo, tiny_gbm, chips):
         tp=dataclasses.replace(tp, pallas=_auto_on_tpu(chips))))
     assert "tpu_custom_call" in txt
     assert ("all-reduce" in txt) == (chips > 1)
+
+
+@pytest.mark.allow_key_leak
+def test_gbm_boost_chunk_at_the_benchmark_cell(topo, tiny_gbm):
+    """The 2-tree scan of ``gbm-airlines-d6.fit-48m`` as the fit asks for
+    it: 48M rows on one chip, depth 6, the row blocks ``_fit`` takes
+    past 8M rows. Every level of both trees runs the two kernels (the
+    scan's body holds them once), and the program fits the chip."""
+    from h2o3_tpu.models.tree import kernel_levels
+    _, n_tiny = tiny_gbm
+    tp = compile_observer.aot_source("gbm.boost_scan")[2]["tp"]
+    tp = dataclasses.replace(tp, pallas=_auto_on_tpu(1), block_rows=16384)
+    assert tp.max_depth == 6 and all(kernel_levels(tp, F))
+    txt = _compiled_text(_lower_recorded(
+        "gbm.boost_scan", _mesh(topo, 1), n_tiny, AIR48_ROWS, ntrees=2,
+        tp=tp))
+    assert txt.count("tpu_custom_call") >= 2 * tp.max_depth
+    assert "all-reduce" not in txt
 
 
 @pytest.mark.allow_key_leak

@@ -441,7 +441,9 @@ def test_histogram_cost_analysis_agrees_with_analytic_2x():
                if isinstance(e, dict))
     assert cost > 0
     ndev = roofline.device_peaks()["devices"]
-    analytic_per_device = 2.0 * 3 * L * n * F * B / ndev
+    # the product's left operand holds three bfloat16 pieces a statistic
+    # (ops/histogram.stat_rows): piece_rows(L) rows, not 3·L
+    analytic_per_device = 2.0 * H.piece_rows(L) * n * F * B / ndev
     ratio = analytic_per_device / cost
     assert 0.5 <= ratio <= 2.0, ratio
 

@@ -23,8 +23,8 @@ def test_pallas_matches_xla_histogram(L, B, F, N):
     h = r.rand(N).astype(np.float32)
     stats = jnp.stack([jnp.asarray(w), jnp.asarray(w * g),
                        jnp.asarray(w * h)], axis=1)
-    ref = _local_histogram(bins, nid, stats, L, B, block_rows=256)
-    out = pallas_local_histogram(bins, nid, stats, L, B, block_rows=256,
+    ref = _local_histogram(bins, nid, stats.T, L, B, block_rows=256)
+    out = pallas_local_histogram(bins, nid, stats.T, L, B, block_rows=256,
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-4)
