@@ -143,7 +143,7 @@ def walk_forest(arrays: Dict[str, np.ndarray], bins: np.ndarray,
 def route_tree_nids(feat, thresh, na_left, is_split, bins: np.ndarray,
                     B: int, cat_split=None, left_words=None) -> np.ndarray:
     """Terminal leaf id per row for ONE tree [D, L] (RuleFit rule
-    membership is a leaf-id range check — models/rulefit.py _route_nids
+    membership is a leaf-id range check — models/tree.py _route
     twin on the host). Categorical subset splits test the row's bin bit
     in the node's packed left-set words."""
     D = feat.shape[0]

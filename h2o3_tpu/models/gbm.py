@@ -42,7 +42,8 @@ from h2o3_tpu.models.tree import (Tree, TreeParams, TreeScalars,
                                   bucket_depth, concat_forests,
                                   grow_tree, kernel_levels,
                                   predict_forest, predict_tree,
-                                  stack_trees, unstack_model_trees)
+                                  select_levels, stack_trees,
+                                  unstack_model_trees)
 from h2o3_tpu.ops import pallas as pallas_ops
 from h2o3_tpu.parallel.mesh import (get_mesh, put_sharded,
                                     row_sharding)
@@ -327,6 +328,14 @@ def _level_paths(tp: TreeParams, n_features: int) -> dict:
     fused = kernel_levels(tp, n_features)
     return {"levels_kernel": sum(fused),
             "levels_xla": len(fused) - sum(fused)}
+
+
+def _route_paths(forest: Tree) -> dict:
+    """What a ``gbm.rescore`` span says of the routing: how many of a
+    tree's levels cost selects alone and how many pay real gathers
+    (models/tree.select_levels)."""
+    sel = select_levels(forest.feat.shape[-2])
+    return {"levels_select": sum(sel), "levels_gather": len(sel) - sum(sel)}
 
 
 def _boost_step_impl(bins, nb, y, w, margin, key, knobs, *, tp, dist,
@@ -1040,7 +1049,7 @@ class GBMEstimator(ModelBuilder):
                                 for f in Tree._fields))
             model = GBMModel(p, output, forest, bm, f0, "multinomial")
             if not light:
-                with telemetry.span("gbm.rescore"):
+                with telemetry.span("gbm.rescore", **_route_paths(forest)):
                     probs = jax.block_until_ready(
                         jax.nn.softmax(model._margins(bm), axis=1))
                 with telemetry.span("gbm.metrics"):
@@ -1235,7 +1244,7 @@ class GBMEstimator(ModelBuilder):
                 # forest — `margin` may include discarded trees. The
                 # span ends when the device has scored (the metrics
                 # below would wait for it anyway)
-                with telemetry.span("gbm.rescore"):
+                with telemetry.span("gbm.rescore", **_route_paths(forest)):
                     mfin = jax.block_until_ready(model._margins(bm, off))
                 with telemetry.span("gbm.metrics"):
                     if category == ModelCategory.BINOMIAL:

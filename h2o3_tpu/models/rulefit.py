@@ -18,29 +18,15 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.frame.binning import rebin_for_scoring
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models.model import Model, ModelBuilder, ModelCategory, infer_category
-from h2o3_tpu.models.tree import row_feature_values
+from h2o3_tpu.models.tree import _route
 from h2o3_tpu.utils.log import get_logger
 
 log = get_logger("h2o3_tpu.rulefit")
-
-
-def _route_nids(tree, bins, B: int):
-    """Final leaf id per row for one tree (predict_tree sans leaf gather)."""
-    from h2o3_tpu.models.tree import _level_goleft
-    N = bins.shape[0]
-    D = tree.feat.shape[0]
-    nid = jnp.zeros((N,), jnp.int32)
-    for d in range(D):
-        nid = _level_goleft(tree.feat[d], tree.thresh[d], tree.na_left[d],
-                            tree.is_split[d], tree.cat_split[d],
-                            tree.left_words[d], nid, bins, B)
-    return nid
 
 
 def _extract_rules(forest, tree_idx: int, D: int) -> List[dict]:
@@ -135,7 +121,7 @@ class RuleFitModel(Model):
                 by_tree.setdefault(r["tree"], []).append(r)
             for t, rl in sorted(by_tree.items()):
                 tree = type(tm.forest)(*(a[t] for a in tm.forest))
-                nid = np.asarray(_route_nids(tree, bm.bins, B))[: frame.nrows]
+                nid = np.asarray(_route(tree, bm.bins, B))[: frame.nrows]
                 for r in rl:
                     cols[r["name"]] = ((nid >= r["lo"]) & (nid < r["hi"])
                                        ).astype(np.float64)
@@ -222,7 +208,7 @@ class RuleFitEstimator(ModelBuilder):
                 # binomial GBM trains 1 tree/iter; trees stack plainly
                 for t in range(T):
                     tree = type(forest)(*(a[t] for a in forest))
-                    nid = np.asarray(_route_nids(tree, tm.bm.bins, B))
+                    nid = np.asarray(_route(tree, tm.bm.bins, B))
                     for r in _extract_rules(forest, t, D):
                         r["model"] = di
                         r["name"] = f"M{di}T{t}N{r['lo']}"

@@ -180,30 +180,61 @@ def _pack_leftmask(leftmask, W: int):
                    axis=1)
 
 
+# The widest table a per-row lookup ``table[nid]`` may read and still be
+# a chain of selects: the chip's compiler takes a 1-D lookup from up to
+# 64 entries that way (1.3-1.8 ms over 48M rows, v5e) and makes a real
+# gather of it from 128 on (454 ms; 599 ms from a 2-D table — PERF.md
+# §6, PR 30). tests/test_chip_compile.py holds the compiler to it.
+SELECT_NODES = 64
+
+
+def select_levels(depth: int) -> tuple:
+    """Per level of a depth-``depth`` tree, whether routing rows through
+    it costs selects alone (level d has 2^d live nodes; a level wider
+    than SELECT_NODES pays real gathers, _left_word one of them). What
+    the fits report on their ``*.rescore`` spans (``levels_select`` /
+    ``levels_gather``)."""
+    return tuple(2 ** d <= SELECT_NODES for d in range(depth))
+
+
+def _left_word(lw_d, nid, widx):
+    """``lw_d[nid, widx]``, the row's word of its node's packed left-set,
+    without a per-row gather from the 2-D table (12.4 ns a row on a v5e,
+    once per word: 27 s of a 35 s fit at 48M rows) and without an
+    ``[N, W]`` intermediate (its minor dim is padded to 128 lanes:
+    25.7 GB at 50M rows). A level of selects looks each of the W columns
+    up as the level's other tables are and keeps the row's own; a wide
+    level makes ONE gather from the flat table, where it made W."""
+    L, W = lw_d.shape
+    if L > SELECT_NODES:
+        # the NA bin's word index is W when B-1 is a multiple of 32;
+        # the isna branch decides those rows, any word will do
+        return lw_d.reshape(-1)[nid * W + jnp.minimum(widx, W - 1)]
+    word = lw_d[:, 0][nid]
+    for k in range(1, W):
+        word = jnp.where(widx == k, lw_d[:, k][nid], word)
+    return word
+
+
 def _level_goleft(feat_d, thresh_d, nal_d, isp_d, cat_d, lw_d, nid, bins,
-                  B: int):
-    """Row routing for one tree level — shared by training, scoring,
-    leaf assignment and path counting (the DecidedNode assignment pass).
-    Numeric splits compare bin <= t; categorical subset splits test the
-    row's bin bit in the node's packed left-set."""
+                  B: int, d: int):
+    """Row routing for level ``d`` of a tree — shared by training,
+    scoring, leaf assignment and path counting (the DecidedNode
+    assignment pass). Numeric splits compare bin <= t; categorical
+    subset splits test the row's bin bit in the node's packed left-set.
+    The level's tables are cut to its 2^d live nodes (``nid`` < 2^d),
+    so that the narrow levels of a deep tree are narrow tables too."""
+    feat_d, thresh_d, nal_d, isp_d, cat_d, lw_d = (
+        t[:2 ** d] for t in (feat_d, thresh_d, nal_d, isp_d, cat_d, lw_d))
     f_r = feat_d[nid]
     t_r = thresh_d[nid]
     nal_r = nal_d[nid]
     isp_r = isp_d[nid]
+    cs_r = cat_d[nid]
     b_r = row_feature_values(bins, f_r).astype(jnp.int32)
     isna = b_r == (B - 1)
     go_num = b_r <= t_r
-    W = lw_d.shape[1]
-    cs_r = cat_d[nid]
-    widx = (b_r >> 5).astype(jnp.uint32)
-    # select the row's bitset word WITHOUT an [N, W] u32 intermediate:
-    # TPU tiling pads the minor dim to 128, so [50M, 4] u32 becomes a
-    # 25.7GB allocation (observed gbm-full compile OOM). A static loop
-    # of per-word [N] gathers fuses into selects instead.
-    word = jnp.zeros_like(b_r, dtype=jnp.uint32)
-    for k in range(W):
-        word = word | jnp.where(widx == jnp.uint32(k), lw_d[nid, k],
-                                jnp.uint32(0))
+    word = _left_word(lw_d, nid, b_r >> 5)
     inset = ((word >> (b_r & 31).astype(jnp.uint32)) & 1) == 1
     go_split = jnp.where(cs_r, inset, go_num)
     goleft = jnp.where(isp_r, jnp.where(isna, nal_r, go_split), True)
@@ -385,7 +416,7 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
             with jax.named_scope("tree.partition"):
                 nid = _level_goleft(feats[d], threshs[d], na_lefts[d],
                                     is_splits[d], cat_splits[d],
-                                    left_words[d], nid, bins, B)
+                                    left_words[d], nid, bins, B, d)
 
     # leaf Newton values from final assignment (GammaPass analogue)
     nleaf = 2 ** D
@@ -444,7 +475,7 @@ def _route(tree: Tree, bins, B: int):
             nid = _level_goleft(tree.feat[d], tree.thresh[d],
                                 tree.na_left[d], tree.is_split[d],
                                 tree.cat_split[d], tree.left_words[d],
-                                nid, bins, B)
+                                nid, bins, B, d)
     return nid
 
 
@@ -459,15 +490,16 @@ def feature_path_counts(stacked: Tree, bins, B: int, F: int):
         D = tree.feat.shape[0]
         nid = jnp.zeros((N,), jnp.int32)
         for d in range(D):
-            f_r = tree.feat[d][nid]
-            isp_r = tree.is_split[d][nid]
+            # cut as _level_goleft cuts them: the same lookups, made once
+            f_r = tree.feat[d, :2 ** d][nid]
+            isp_r = tree.is_split[d, :2 ** d][nid]
             onehot = (f_r[:, None] ==
                       jnp.arange(F, dtype=jnp.int32)[None, :])
             counts = counts + jnp.where(isp_r[:, None] & onehot, 1, 0)
             nid = _level_goleft(tree.feat[d], tree.thresh[d],
                                 tree.na_left[d], tree.is_split[d],
                                 tree.cat_split[d], tree.left_words[d],
-                                nid, bins, B)
+                                nid, bins, B, d)
         return counts, None
 
     counts0 = jnp.zeros((bins.shape[0], F), jnp.int32)

@@ -404,5 +404,5 @@ def xla_level(bins, nid, w, g, h, prev_hist, col_mask, nb, is_cat,
         cs = jnp.zeros_like(split)
         words = jnp.zeros((L, 1), jnp.uint32)
     newnid = _level_goleft(feat_d, thresh_d, nal_d, split, cs, words,
-                           nid, bins, B)
+                           nid, bins, B, d)
     return (hist, bg, bf, bt, bnal, blv, brv, leftmask, split, newnid)

@@ -24,6 +24,7 @@ the tree-kernel mode that ``auto`` resolves to on a TPU.
 import contextlib
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -297,6 +298,57 @@ def test_predict_forest(topo, tiny_gbm):
         model.forest)
     predict_forest.lower(forest, S((SCORE_ROWS, F), jnp.int8, sharding=one),
                          B=B).compile()
+
+
+def _gather_operand_shapes(txt):
+    """The shape of the table each ``gather`` of a compiled module reads
+    (its first operand, looked up by name where it is defined)."""
+    shape_of = dict(re.findall(
+        r"%([\w.\-]+) = \w+\[([\d,]*)\]", txt))
+    return [tuple(int(n) for n in shape_of[op].split(",") if n)
+            for op in re.findall(r" gather\(%([\w.\-]+),", txt)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.bool_, jnp.uint32])
+def test_a_lookup_from_select_nodes_entries_is_selects(topo, dtype):
+    """models/tree.SELECT_NODES is the chip compiler's rule, not ours:
+    a per-row lookup from a table that wide compiles to selects."""
+    from h2o3_tpu.models.tree import SELECT_NODES
+    one = _one_chip(topo)
+    txt = _compiled_text(jax.jit(lambda t, i: t[i]).lower(
+        S((SELECT_NODES,), dtype, sharding=one),
+        S((1 << 20,), jnp.int32, sharding=one)))
+    assert not _gather_operand_shapes(txt)
+
+
+@pytest.mark.allow_key_leak
+def test_predict_forest_at_the_benchmark_cell(topo, tiny_gbm):
+    """The end-of-fit re-scoring of ``gbm-airlines-d6.fit-48m``: 2 trees
+    of depth 6 over the 48M resident rows, three categorical columns,
+    four bitset words a node. Routing a level costs selects and nothing
+    else: a per-row gather from the 2-D ``[Lmax, W]`` words table took
+    12.4 ns a row, 27 s of a 35 s job (PERF.md §6, PR 30) — it must not
+    come back through a refactor, nor as an ``[N, W]`` / ``[N, L]``
+    intermediate, which the chip pads to 128 lanes (25 GB here)."""
+    from h2o3_tpu.models.tree import predict_forest
+    model, _ = tiny_gbm
+    one = _one_chip(topo)
+    forest = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype, sharding=one), model.forest)
+    assert forest.left_words.shape == (2, 6, 32, (B - 1 + 31) // 32)
+    n = mesh_mod.padded_rows(AIR48_ROWS, _mesh(topo, 1))
+    compiled = predict_forest.lower(
+        forest, S((n, F), jnp.int8, sharding=one), B=B).compile()
+    tables = _gather_operand_shapes(compiled.as_text())
+    assert not [t for t in tables if len(t) > 1], (
+        f"per-row gathers from 2-D tables {tables}: the words table again?")
+    # every table of these levels is small enough for the chip's
+    # compiler to take the lookup as selects; a gather left here is a
+    # lookup at ~12 ns a row where the others cost under one
+    assert not tables, f"per-row gathers from tables of shape {tables}"
+    # sixteen [N] vectors of 4 B live between a level's fusions (3.14 GB,
+    # the parent's too); one [N, k] vector padded to 128 lanes is 6-25 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
 @pytest.mark.allow_key_leak
