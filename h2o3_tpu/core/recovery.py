@@ -356,10 +356,12 @@ class FitCheckpointer:
         return True
 
     # -- read ----------------------------------------------------------
-    def load(self) -> Optional[Tuple[int, Dict[str, Any]]]:
+    def load(self, needs=()) -> Optional[Tuple[int, Dict[str, Any]]]:
         """(unit, state) of the last snapshot, or None. Counts
         ``fit_resumes_total{algo}`` on success; quarantines on any
-        failure (bit-flip, truncation, version drift)."""
+        failure (bit-flip, truncation, version drift, or a state that
+        lacks one of the fields in ``needs`` — what the resuming fit
+        would read: a fit is resumed whole or not at all)."""
         if not os.path.exists(self.path):
             return None
         try:
@@ -375,6 +377,9 @@ class FitCheckpointer:
                     f"{self.algo!r}")
             unit = int(payload["unit"])
             state = payload["state"]
+            missing = sorted(set(needs) - set(state))
+            if missing:
+                raise ValueError(f"fit snapshot lacks {missing}")
         except Exception as e:  # noqa: BLE001 - quarantine boundary
             quarantine_snapshot(self.path, e)
             return None

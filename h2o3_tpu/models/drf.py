@@ -39,8 +39,8 @@ from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
                                    infer_category, resolve_checkpoint_model,
                                    validate_checkpoint_params)
 from h2o3_tpu.models.tree import (Tree, TreeParams, bucket_depth,
-                                  grow_tree, predict_forest,
-                                  scalars_of, stack_trees)
+                                  grow_tree, predict_forest, scalars_of,
+                                  stack_trees, trees_per_chunk)
 from h2o3_tpu.ops import pallas as pallas_ops
 from h2o3_tpu.parallel.mesh import get_mesh, row_sharding
 from h2o3_tpu.utils.log import get_logger
@@ -306,18 +306,8 @@ class DRFEstimator(ModelBuilder):
         ht = str(p.get("histogram_type", "auto")).lower()
         ht = {"auto": "quantiles", "quantilesglobal": "quantiles",
               "uniformadaptive": "uniform"}.get(ht, ht)
-        w = frame.valid_weights()
-        if p.get("weights_column"):
-            wc = frame.col(p["weights_column"]).numeric_view()
-            w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
-        w = self._cv_masked_weights(w, frame)
         rc = frame.col(y)
-        wh_host = self._host_weights(frame, y)     # host mirror of w
-        resp_na_host = np.isnan(rc.to_numpy())
-        if resp_na_host.any():
-            w = w * jnp.asarray(np.pad(
-                (~resp_na_host).astype(np.float32),
-                (0, frame.nrows_padded - frame.nrows)))
+        w, wh_host = self._training_weights(frame, y)
         # checkpoint restart (SharedTree _checkpoint semantics): reuse
         # the donor's bin edges so its trees stay valid, continue the
         # PRNG key chain, and append trees up to the new ntrees
@@ -450,11 +440,7 @@ class DRFEstimator(ModelBuilder):
         if _cap > 0:
             _deadline = time.time() + _cap
             # chunk shrinks with per-tree cost so the deadline can bind
-            # (see GBM: a 25-tree chunk at depth bucket >=10 outruns an
-            # AutoML slice before the first boundary check)
-            _cost = (2.0 ** tp.max_depth / 64.0) * (bm.nbins_total / 65.0) \
-                * max(1.0, bm.bins.shape[0] / 5_242_880.0)
-            _chunk = max(1, min(25, int(round(25.0 / max(_cost, 1.0)))))
+            _chunk = trees_per_chunk(tp, bm.bins.shape[0], capped=True)
             chunks, osum_acc, ocnt_acc, gains_acc = [], None, None, None
             done = 0
             while done < ntrees:

@@ -387,6 +387,29 @@ class ModelBuilder:
         fm[: frame.nrows] = fold_mask.astype(np.float32)
         return w * jnp.asarray(fm)
 
+    def _training_weights(self, frame: Frame, y: str):
+        """(w, wh_host) for a tree fit: the device row weights — padding
+        mask x user weight column x CV fold mask, with the rows whose
+        response is NA weighted out (the reference's ModelBuilder drops
+        them from training and from the training metrics) — and their
+        equal HOST mirror (_host_weights). Every host-side consumer (bin
+        sketch, init means, priors) reads the mirror instead of syncing
+        the device: a CV sweep calls _fit once per fold, and per-fold
+        fetches dominate leave-one-out CV."""
+        w = frame.valid_weights()
+        wc_name = self.params.get("weights_column")
+        if wc_name:
+            wc = frame.col(wc_name).numeric_view()
+            w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
+        w = self._cv_masked_weights(w, frame)
+        wh_host = self._host_weights(frame, y)
+        resp_na_host = np.isnan(frame.col(y).to_numpy())  # cached host view
+        if resp_na_host.any():
+            w = w * jnp.asarray(np.pad(
+                (~resp_na_host).astype(np.float32),
+                (0, frame.nrows_padded - frame.nrows)))
+        return w, wh_host
+
     def _host_weights(self, frame: Frame, y: Optional[str]) -> np.ndarray:
         """HOST mirror of the effective training weights: user weight
         column × CV fold mask × response-NA exclusion, [frame.nrows]

@@ -74,6 +74,28 @@ def bucket_depth(d: int) -> int:
     return d
 
 
+def trees_per_chunk(tp: "TreeParams", n_rows: int, capped: bool) -> int:
+    """Trees a compiled chunk of a boosting or bagging loop — the ONE
+    rule for every loop (models/gbm.py, models/drf.py), so that the
+    global-tree-index PRNG keys and the stop points of a batched and a
+    sequential GBM fit line up exactly.
+
+    Row scale bounds single-program runtime: a 25-tree fused scan at
+    50M rows runs minutes inside ONE XLA program, between which no
+    cancel point, checkpoint or progress update can fire — chunks
+    shrink past ~5M padded rows so each program stays ~tens of seconds;
+    <=5M rows keep 25 (the pyunit shapes are untouched).
+    A fit under max_runtime_secs (``capped``) can only stop at a chunk
+    boundary, so its chunk also shrinks as per-tree cost grows
+    (complete-tree layout: ~2^depth * nbins per tree) — a 25-deep-tree
+    chunk at depth bucket 10 runs ~20-80s, far past a ~30s AutoML
+    slice. Uncapped fits keep 25 (no extra program shapes)."""
+    cost = max(1.0, n_rows / 5_242_880.0)
+    if capped:
+        cost *= (2.0 ** tp.max_depth / 64.0) * (tp.nbins_total / 65.0)
+    return max(1, min(25, int(round(25.0 / max(cost, 1.0)))))
+
+
 class Tree(NamedTuple):
     """One complete tree; arrays padded to Lmax = 2^(D-1) internal slots."""
     feat: jax.Array       # [D, Lmax] int32 split feature

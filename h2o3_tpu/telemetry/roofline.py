@@ -151,8 +151,8 @@ def _use_cost() -> bool:
 
 # algo → family of analytic estimator + the observed jit entry points
 # whose calls carry the fit's device work (compile_observer names)
-_TREE_KERNELS = ("gbm.boost_scan", "gbm.boost_scan_scored",
-                 "gbm.boost_scan_multi", "gbm.boost_scan_batched")
+_TREE_KERNELS = ("gbm.boost_scan", "gbm.boost_scan_multi",
+                 "gbm.boost_scan_batched")
 ALGO_KERNELS: Dict[str, Tuple[str, ...]] = {
     "gbm": _TREE_KERNELS, "drf": _TREE_KERNELS, "xgboost": _TREE_KERNELS,
     "glm": ("glm.irls_solve", "glm.irls_solve_batched"),

@@ -52,7 +52,9 @@ with open(os.path.join(workdir, f"node{pid}.json"), "w") as f:
               f)
 
 STOP = os.path.join(workdir, "stop")
-DEADLINE = time.time() + 180.0
+# the parent's one deadline bounds this process too (it must outlive
+# every wait of the test, and not outlive a parent that died)
+DEADLINE = time.time() + float(os.environ.get("H2O3TPU_MP_TIMEOUT_S", "300"))
 
 if pid == 0:
     from h2o3_tpu.api.server import start_server

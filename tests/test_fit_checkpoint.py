@@ -105,28 +105,55 @@ def test_corrupt_snapshot_quarantined(tmp_path):
 # -------------------------------------- supervisor resume (fault inject)
 
 
-def test_gbm_infra_fault_resumes_bit_identical(tmp_path):
+def _multiclass_frame(n=2000, seed=0):
+    r = np.random.RandomState(seed)
+    X = r.randn(n, 5)
+    yv = np.digitize(X[:, 0] + 0.3 * r.randn(n), [-0.5, 0.5])
+    cols = {f"x{i}": X[:, i] for i in range(5)}
+    cols["y"] = np.array(["a", "b", "c"], object)[yv]
+    return h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
+
+
+_STOP = dict(stopping_rounds=2, stopping_tolerance=0.0, score_tree_interval=5)
+# one chunk loop and one snapshot format serve every kind of fit
+# (models/gbm.py _run_chunks): what is scored, and so what rides in the
+# snapshot, is all that differs
+_FIT_KINDS = {
+    "plain": (_classif_frame, None, {}),
+    "stop_on_training": (_classif_frame, None, _STOP),
+    "stop_on_validation": (_classif_frame, lambda: _classif_frame(700, 1),
+                           _STOP),
+    "multinomial": (_multiclass_frame, None, {}),
+    "multinomial_stop_on_validation": (
+        _multiclass_frame, lambda: _multiclass_frame(700, 1), _STOP),
+}
+
+
+@pytest.mark.parametrize("kind", list(_FIT_KINDS))
+def test_gbm_infra_fault_resumes_bit_identical(tmp_path, kind):
     """Leg 2+3 acceptance (in-process): an infra-classed failure at the
     chunk boundary after the first snapshot makes the job supervisor
     re-enter the fit from the snapshot; forest, metrics and scoring
     history are bit-identical to an uninterrupted fit, with exactly one
     resume counted — and the counters land in the job's flight-recorder
-    capsule. Then the quarantine leg: a garbage snapshot at the same
-    fit's path costs the resume, not correctness."""
-    fr = _classif_frame()
-    kw = dict(ntrees=50, max_depth=3, seed=5, stopping_rounds=2,
-              stopping_tolerance=0.0, score_tree_interval=5)
-    clean = GBMEstimator(**kw).train(fr, y="y")
+    capsule."""
+    mk_frame, mk_valid, extra = _FIT_KINDS[kind]
+    fr = mk_frame()
+    vf = mk_valid() if mk_valid else None
+    kw = dict(ntrees=50, max_depth=3, seed=5, **extra)
+    clean = GBMEstimator(**kw).train(fr, y="y", validation_frame=vf)
     watchdog.inject_fault("fit_chunk", times=1)
     r0 = telemetry.REGISTRY.total("fit_resumes_total")
     w0 = telemetry.REGISTRY.total("fit_checkpoints_written_total")
     b = GBMEstimator(**kw)
     with recovery.fit_checkpoint_scope(str(tmp_path)):
-        m = b.train(fr, y="y")
+        m = b.train(fr, y="y", validation_frame=vf)
     assert telemetry.REGISTRY.total("fit_resumes_total") == r0 + 1
     assert telemetry.REGISTRY.total("fit_checkpoints_written_total") > w0
     _forests_equal(clean.forest, m.forest)
+    assert np.array_equal(np.asarray(clean.f0), np.asarray(m.f0))
     assert clean.output["scoring_history"] == m.output["scoring_history"]
+    assert clean.output["varimp"] == m.output["varimp"]
     assert float(clean.training_metrics["logloss"]) == \
         float(m.training_metrics["logloss"])
     # the snapshot was cleared on completion (dir may be gone entirely)
@@ -139,20 +166,45 @@ def test_gbm_infra_fault_resumes_bit_identical(tmp_path):
     deltas = cap["metric_deltas"]
     assert any("fit_checkpoints_written_total" in k for k in deltas), deltas
     assert any("fit_resumes_total" in k for k in deltas)
-    # fit-level quarantine: garbage at the fit's own snapshot path →
-    # restart from round 0, same model as the clean run, no resume
-    b2 = GBMEstimator(**kw)
-    probe = recovery._fit_fingerprint("gbm", b2.params, "y",
-                                      clean.output["names"], fr.nrows)
-    path = os.path.join(str(tmp_path), f"gbm_{probe}{recovery.FIT_SUFFIX}")
+
+
+def _write_garbage(path):
     with open(path, "wb") as f:
         f.write(b"\x80\x04 definitely not a fit snapshot")
-    r1 = telemetry.REGISTRY.total("fit_resumes_total")
+
+
+def _write_without_margin(path):
+    """A well-formed snapshot of the right algo and version whose state
+    lacks a field every resuming GBM fit reads (as one written by an
+    older program would)."""
+    recovery.FitCheckpointer(path, "gbm", every=1).save(
+        25, {"done": 25, "trees": None,
+             "gains_total": np.zeros(5, np.float32)})
+
+
+@pytest.mark.parametrize("write", [_write_garbage, _write_without_margin],
+                         ids=["garbage", "lacks_a_field"])
+def test_gbm_unusable_snapshot_quarantined(tmp_path, write):
+    """Fit-level quarantine: garbage at the fit's own snapshot path, or
+    a readable snapshot that lacks what the fit needs → moved aside,
+    counted, restart from round 0 with the same model as a clean run —
+    no resume counted, never a half-resumed fit."""
+    fr = _classif_frame()
+    kw = dict(ntrees=30, max_depth=3, seed=5, **_STOP)
+    clean = GBMEstimator(**kw).train(fr, y="y")
+    b = GBMEstimator(**kw)
+    probe = recovery._fit_fingerprint("gbm", b.params, "y",
+                                      clean.output["names"], fr.nrows)
+    write(os.path.join(str(tmp_path), f"gbm_{probe}{recovery.FIT_SUFFIX}"))
+    r0 = telemetry.REGISTRY.total("fit_resumes_total")
+    c0 = telemetry.REGISTRY.total("snapshot_load_failures_total")
     with recovery.fit_checkpoint_scope(str(tmp_path)):
-        m2 = b2.train(fr, y="y")
-    assert telemetry.REGISTRY.total("fit_resumes_total") == r1
+        m = b.train(fr, y="y")
+    assert telemetry.REGISTRY.total("fit_resumes_total") == r0
+    assert telemetry.REGISTRY.total("snapshot_load_failures_total") == c0 + 1
     assert any(n.endswith(".corrupt") for n in os.listdir(tmp_path))
-    _forests_equal(clean.forest, m2.forest)
+    _forests_equal(clean.forest, m.forest)
+    assert clean.output["scoring_history"] == m.output["scoring_history"]
 
 
 def test_deeplearning_infra_fault_resumes_bit_identical(tmp_path,

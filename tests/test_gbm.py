@@ -55,6 +55,29 @@ def test_gbm_multinomial():
     assert acc > 0.85
 
 
+def test_gbm_multinomial_opens_the_fit_phases():
+    """A K-class job charges its start (class codes to the device, the
+    priors, the [N, K] margins) to ``gbm.init`` like every other fit,
+    not to ``gbm.fit``'s own time."""
+    from h2o3_tpu import telemetry
+    r = np.random.RandomState(3)
+    n = 600
+    X = r.randn(n, 3)
+    y = np.digitize(X[:, 0], [-0.4, 0.4])
+    fr = h2o3_tpu.Frame.from_numpy(
+        {**{f"x{i}": X[:, i] for i in range(3)},
+         "y": np.array(["a", "b", "c"], object)[y]}, categorical=["y"])
+    before = {s["id"] for s in telemetry.spans_snapshot(1 << 20)}
+    GBMEstimator(ntrees=3, max_depth=3, seed=5).train(fr, y="y")
+    spans = {s["name"]: s["meta"] for s in telemetry.spans_snapshot(1 << 20)
+             if s["id"] not in before}
+    assert {"gbm.bin", "gbm.init", "gbm.chunk", "gbm.rescore",
+            "gbm.metrics"} <= set(spans)
+    assert spans["gbm.chunk"]["trees"] == 3
+    assert {"levels_kernel", "levels_xla"} <= set(spans["gbm.chunk"])
+    assert {"levels_select", "levels_gather"} <= set(spans["gbm.rescore"])
+
+
 def test_gbm_with_categorical_features():
     r = np.random.RandomState(11)
     n = 2000

@@ -113,6 +113,34 @@ def test_gbm_batched_early_stop_masks_match_sequential(monkeypatch):
     assert stopped_any, "no model early-stopped; test lost its teeth"
 
 
+def _gbm_spans_of(fit):
+    """Names and meta of the ``gbm.*`` spans that ``fit()`` opens."""
+    before = {s["id"] for s in telemetry.spans_snapshot(1 << 20)}
+    fit()
+    return [(s["name"], s["meta"]) for s in telemetry.spans_snapshot(1 << 20)
+            if s["name"].startswith("gbm.") and s["id"] not in before]
+
+
+def test_gbm_batched_fit_opens_the_sequential_fits_spans():
+    """A batched and a sequential fit of the same combo run the same
+    preamble, init and finish (models/gbm.py), so a trace of a grid
+    search shows the phases a trace of one fit shows."""
+    from h2o3_tpu.models.gbm import fit_gbm_batched
+    fr = _class_frame()
+    combo = dict(ntrees=6, max_depth=3, seed=7, learn_rate=0.2)
+    phases = {"gbm.bin", "gbm.init", "gbm.chunk", "gbm.rescore",
+              "gbm.metrics"}
+    seq = _gbm_spans_of(lambda: GBMEstimator(**combo).train(fr, y="y"))
+    bat = _gbm_spans_of(
+        lambda: fit_gbm_batched(GBMEstimator, [combo], fr, y="y"))
+    assert {n for n, _ in seq} - {"gbm.fit"} == phases
+    assert {n for n, _ in bat} == phases
+    for spans in (seq, bat):
+        meta = {n: m for n, m in spans}
+        assert {"trees", "levels_kernel", "levels_xla"} <= set(meta["gbm.chunk"])
+        assert {"levels_select", "levels_gather"} <= set(meta["gbm.rescore"])
+
+
 def test_gbm_batched_max_models_cap_discards_extras():
     """max_models caps the grid exactly like the sequential walk; pre-
     trained extras are discarded from the DKV, not leaked."""

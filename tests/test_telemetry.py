@@ -324,12 +324,12 @@ def test_gbm_chunking_invariant_sampling():
     key = jax.random.PRNGKey(42)
     kw = dict(tp=tp, dist=dist, sample_rate=0.6)
 
-    tr_full, m_full, _ = _boost_scan(bm.bins, bm.nbins, y, w, margin, key,
-                                     ntrees=4, tree0=0, **kw)
-    tr_a, m_a, _ = _boost_scan(bm.bins, bm.nbins, y, w, margin, key,
-                               ntrees=2, tree0=0, **kw)
-    tr_b, m_b, _ = _boost_scan(bm.bins, bm.nbins, y, w, m_a, key,
-                               ntrees=2, tree0=2, **kw)
+    tr_full, (m_full, _), _, _ = _boost_scan(
+        bm.bins, bm.nbins, y, w, (margin, None), key, ntrees=4, tree0=0, **kw)
+    tr_a, carry_a, _, _ = _boost_scan(
+        bm.bins, bm.nbins, y, w, (margin, None), key, ntrees=2, tree0=0, **kw)
+    tr_b, (m_b, _), _, _ = _boost_scan(
+        bm.bins, bm.nbins, y, w, carry_a, key, ntrees=2, tree0=2, **kw)
     for f in tr_full._fields:
         full = np.asarray(getattr(tr_full, f))
         split = np.concatenate([np.asarray(getattr(tr_a, f)),
