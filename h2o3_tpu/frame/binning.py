@@ -213,40 +213,46 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
               nbins_total_override: Optional[int] = None,
               train_domains: Optional[List[Optional[List[str]]]] = None,
               histogram_type: str = "quantiles",
-              weights: Optional[np.ndarray] = None) -> BinnedMatrix:
+              weights=None, weights_key=None) -> BinnedMatrix:
     """Bin ``features`` of ``frame`` into a device int matrix.
 
     ``edges_override``/``train_domains`` re-bin a scoring frame with
     training-time edges and categorical domains — the adaptTestForTrain
     path (hex/Model.java:1850): unseen test levels map to the NA bin.
-    ``weights`` (host [nrows]) makes the quantile sketch weighted so the
-    row-weight ≡ row-multiplicity contract holds (see _numeric_edges).
+    ``weights`` (host [nrows], or a function that builds it) makes the
+    quantile sketch weighted so the row-weight ≡ row-multiplicity
+    contract holds (see _numeric_edges).
 
     Training-path results are CACHED on the Frame keyed by (features,
-    nbins, nbins_cats, histogram_type, weights digest) and invalidated
+    nbins, nbins_cats, histogram_type, weights slot) and invalidated
     on column mutation like the PR 4 ``Frame.device_matrix`` cache —
     grid/AutoML sweeps bin the same frame once per model-family config
-    instead of once per fit. Scoring rebins (edges/domain overrides)
-    bypass the cache: their key is the training matrix, not the frame.
+    instead of once per fit. The weights' slot is ``weights_key`` where
+    the caller can name what they were made from (ModelBuilder._binned:
+    columns of this frame), and a function is then called on a miss
+    alone; weights that come without a name go by a digest of their
+    content, a pass over every row (1.5 s at 48M rows: PERF.md §5).
+    Scoring rebins (edges/domain overrides) bypass the cache: their key
+    is the training matrix, not the frame.
     """
     F = len(features)
     names = list(features)
     cache_key = cache = None
     if (edges_override is None and nbins_total_override is None
             and train_domains is None):
-        # weights enter the quantile sketch, so equal-CONTENT weights
-        # must share a cache slot (every fit rebuilds the host mirror
-        # array); a content digest is ~10ms at 5M rows vs seconds of
-        # re-binning
-        if weights is None:
-            wdig = None
+        if weights_key is not None:
+            wslot = ("named", weights_key)
+        elif weights is None:
+            wslot = None
         else:
             import hashlib
+            if callable(weights):
+                weights = weights()
             warr = np.ascontiguousarray(np.asarray(weights, np.float64))
-            wdig = hashlib.blake2b(warr.tobytes(),
-                                   digest_size=16).hexdigest()
+            wslot = hashlib.blake2b(warr.tobytes(),
+                                    digest_size=16).hexdigest()
         cache_key = (tuple(names), int(nbins), int(nbins_cats),
-                     str(histogram_type), wdig)
+                     str(histogram_type), wslot)
         cache = getattr(frame, "_bin_cache", None)
         if cache is None:
             cache = {}
@@ -256,6 +262,8 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
                 cache = None
         if cache is not None and cache_key in cache:
             return cache[cache_key]
+    if callable(weights):
+        weights = weights()
     cols = [frame.col(n) for n in names]
     is_cat = np.array([c.is_categorical for c in cols], dtype=bool)
     domains = [c.domain for c in cols]

@@ -31,7 +31,7 @@ import numpy as np
 
 from h2o3_tpu.parallel.mesh import fetch_replicated as _fetch_np
 
-from h2o3_tpu.frame.binning import BinnedMatrix, bin_frame, rebin_for_scoring
+from h2o3_tpu.frame.binning import BinnedMatrix, rebin_for_scoring
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models import metrics as mm
 from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
@@ -307,7 +307,7 @@ class DRFEstimator(ModelBuilder):
         ht = {"auto": "quantiles", "quantilesglobal": "quantiles",
               "uniformadaptive": "uniform"}.get(ht, ht)
         rc = frame.col(y)
-        w, wh_host = self._training_weights(frame, y)
+        w, _, rows = self._training_weights(frame, y)
         # checkpoint restart (SharedTree _checkpoint semantics): reuse
         # the donor's bin edges so its trees stay valid, continue the
         # PRNG key chain, and append trees up to the new ntrees
@@ -340,9 +340,9 @@ class DRFEstimator(ModelBuilder):
         elif shared_bm is not None:
             bm = shared_bm
         else:
-            bm = bin_frame(frame, x, nbins=p["nbins"],
-                           nbins_cats=p["nbins_cats"], histogram_type=ht,
-                           weights=wh_host)
+            bm, _ = self._binned(frame, x, y, nbins=p["nbins"],
+                                 nbins_cats=p["nbins_cats"],
+                                 histogram_type=ht)
 
         depth = int(p["max_depth"])
         # complete-tree layout: a level costs 2^d histogram node slots
@@ -379,7 +379,7 @@ class DRFEstimator(ModelBuilder):
                       else max(1, F // 3))
         elif mtries <= 0:
             mtries = F
-        w, w_scale = self._normalize_uniform_weights(w, wh_host)
+        w_scale = rows.w_scale
 
         tp = TreeParams(
             max_depth=compile_depth,

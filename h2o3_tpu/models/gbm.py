@@ -31,13 +31,13 @@ from h2o3_tpu.parallel.mesh import fetch_replicated as _fetch_np
 
 from h2o3_tpu.core import recovery as _recovery
 from h2o3_tpu.core.watchdog import maybe_fail
-from h2o3_tpu.frame.binning import BinnedMatrix, bin_frame, rebin_for_scoring
+from h2o3_tpu.frame.binning import BinnedMatrix, rebin_for_scoring
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models import metrics as mm
 from h2o3_tpu.models.distribution import Distribution, get_distribution
 from h2o3_tpu.ml.calibration import maybe_calibrate
 from h2o3_tpu.models.model import (EarlyStopper, Model, ModelBuilder,
-                                   ModelCategory, adapt_domain,
+                                   ModelCategory, RowSummary, adapt_domain,
                                    checkpoint_error, infer_category,
                                    resolve_checkpoint_model,
                                    validate_checkpoint_params)
@@ -639,40 +639,33 @@ class GBMModel(Model):
 
 
 def _prepare(builder, frame: Frame, x: Sequence[str], y: str, ckpt=None):
-    """The preamble of a fit: (w, wh_host, w_scale, bm) — device row
-    weights, their host mirror, the scale a uniform weight column was
-    divided by, and the binned matrix."""
+    """The preamble of a fit: (w, y_dev, rows, bm) — the device row
+    weights and response, the host's summary of both (one fetch; no
+    array with a row dimension is made on the host) and the binned
+    matrix."""
     p = builder.params
-    rc = frame.col(y)
-    if p.get("check_constant_response", True) and not rc.is_categorical:
-        yh = rc.to_numpy()
-        vals = yh[~np.isnan(yh)]
-        if vals.size and float(vals.min()) == float(vals.max()):
-            raise ValueError(
-                "Response cannot be constant - check your response "
-                "column, or set check_constant_response=False")
-    w, wh_host = builder._training_weights(frame, y)
+    w, y_dev, rows = builder._training_weights(frame, y)
+    if p.get("check_constant_response", True) \
+            and rows.y_min is not None and rows.y_min == rows.y_max:
+        raise ValueError(
+            "Response cannot be constant - check your response "
+            "column, or set check_constant_response=False")
 
     shared_bm = getattr(builder, "_cv_shared_bm", None)
-    with telemetry.span("gbm.bin"):
+    with telemetry.span("gbm.bin") as sp:
         if ckpt is not None:
-            bm = rebin_for_scoring(ckpt.bm, frame)
+            bm, how = rebin_for_scoring(ckpt.bm, frame), "rebin"
         elif shared_bm is not None:
             # CV fold models reuse the main model's full-data bin edges
             # (deliberate: per-fold edge re-sketches cost more than the
             # sketch approximation is worth; the histogram is adaptive
             # per node anyway)
-            bm = shared_bm
+            bm, how = shared_bm, "shared"
         else:
-            # weighted edges: the row-weight ≡ row-multiplicity contract
-            # (pyunit_weights_gbm) must hold through the bin sketch too
-            bm = bin_frame(frame, x, nbins=p["nbins"],
-                           nbins_cats=p["nbins_cats"], weights=wh_host)
-
-    w, w_scale = builder._normalize_uniform_weights(w, wh_host)
-    if w_scale != 1.0:
-        wh_host = wh_host / np.float32(w_scale)
-    return w, wh_host, w_scale, bm
+            bm, how = builder._binned(frame, x, y, nbins=p["nbins"],
+                                      nbins_cats=p["nbins_cats"])
+        sp.annotate(cache=how)
+    return w, y_dev, rows, bm
 
 
 def _tp_of(p: dict, bm: BinnedMatrix, w_scale: float) -> TreeParams:
@@ -697,23 +690,20 @@ def _prng_key(p: dict):
     return jax.random.PRNGKey(seed if seed >= 0 else 0xDEC0DE)
 
 
-def _init_single(p: dict, frame: Frame, y: str, bm: BinnedMatrix, w,
-                 wh_host: np.ndarray, dist: Distribution, ckpt=None):
-    """Where a single-vector fit starts: (y_dev, off, f0, margin) — the
-    response and the offset column on the device, the initial margin
-    value and the [Npad] start margin."""
+def _init_single(p: dict, frame: Frame, bm: BinnedMatrix, w, y_dev,
+                 rows: RowSummary, dist: Distribution, ckpt=None):
+    """Where a single-vector fit starts: (off, f0, margin) — the offset
+    column on the device, the initial margin value (from the weighted
+    mean of the response, by the row summary's sums) and the [Npad]
+    start margin."""
     mesh = get_mesh()
-    n_pad = bm.bins.shape[0] - frame.nrows
-    with telemetry.span("gbm.init"):
-        yv = np.nan_to_num(frame.col(y).to_numpy()).astype(np.float32)
-        # host weighted mean from the weight mirror — no device
-        # sync (w is numerically equal, host caches are replicated)
-        mean_y = (float(np.sum(yv * wh_host))
-                  / max(float(np.sum(wh_host)), 1e-12))
-        y_dev = put_sharded(np.pad(yv, (0, n_pad)), row_sharding(mesh))
+    with telemetry.span("gbm.init", on_device=True, host_bytes=0,
+                        rows_out=rows.rows_out) as sp:
+        mean_y = rows.sum_wy / max(rows.sum_w, 1e-12)
         # init_f is solved WITH the offset in place
         off = _offset_of(p, frame, bm.bins.shape[0])
         if off is not None:
+            sp.annotate(host_bytes=int(off.nbytes))
             off = put_sharded(off, row_sharding(mesh))
         if ckpt is not None:
             f0 = ckpt.f0
@@ -736,26 +726,18 @@ def _init_single(p: dict, frame: Frame, y: str, bm: BinnedMatrix, w,
                 c = c - gsum / jnp.maximum(hsum, 1e-12)
             f0 = np.float32(c)
             margin = off + f0
-    return y_dev, off, f0, margin
+    return off, f0, margin
 
 
-def _init_multi(frame: Frame, y: str, bm: BinnedMatrix,
-                wh_host: np.ndarray, ckpt=None):
-    """Where a K-class fit starts: (y_dev, f0, margins) — the class
-    codes on the device, the [K] log priors and the [Npad, K] start
-    margins."""
+def _init_multi(bm: BinnedMatrix, rows: RowSummary, ckpt=None):
+    """Where a K-class fit starts: (f0, margins) — the [K] log priors,
+    from the row summary's weighted class counts over the rows that
+    train, and the [Npad, K] start margins."""
     mesh = get_mesh()
-    rc = frame.col(y)
-    K = rc.cardinality
-    with telemetry.span("gbm.init"):
-        yv = np.nan_to_num(rc.to_numpy()).astype(np.int32)  # host cache
-        # weighted class priors over rows that actually train, from
-        # the host weight mirror (no device sync)
-        counts = np.bincount(yv, weights=wh_host,
-                             minlength=K).astype(np.float64)
+    with telemetry.span("gbm.init", on_device=True, host_bytes=0,
+                        rows_out=rows.rows_out):
+        counts = rows.sum_wy
         pri = np.clip(counts / max(counts.sum(), 1e-12), 1e-10, 1.0)
-        y_dev = put_sharded(np.pad(yv, (0, bm.bins.shape[0] - frame.nrows)),
-                            row_sharding(mesh))
         if ckpt is not None:
             f0 = ckpt.f0
             margins = jax.device_put(ckpt._margins(bm).astype(jnp.float32),
@@ -764,7 +746,7 @@ def _init_multi(frame: Frame, y: str, bm: BinnedMatrix,
             f0 = np.log(pri).astype(np.float32)
             margins = put_sharded(_start_margin(f0, bm.bins.shape[0]),
                                   row_sharding(mesh))
-    return y_dev, f0, margins
+    return f0, margins
 
 
 def _start_margin(f0, n_rows: int):
@@ -1015,8 +997,8 @@ class GBMEstimator(ModelBuilder):
             validate_checkpoint_params("gbm", ckpt.params, p,
                                        CHECKPOINT_NON_MODIFIABLE)
 
-        w, wh_host, w_scale, bm = _prepare(self, frame, x, y, ckpt)
-        tp = _tp_of(p, bm, w_scale)
+        w, y_dev, rows, bm = _prepare(self, frame, x, y, ckpt)
+        tp = _tp_of(p, bm, rows.w_scale)
         constraints = _build_constraints(p, x, frame, category)
         interaction_sets = _build_interaction_sets(p, x)
 
@@ -1057,13 +1039,13 @@ class GBMEstimator(ModelBuilder):
 
         if multinomial:
             dist, off = None, None
-            y_dev, f0, margin = _init_multi(frame, y, bm, wh_host, ckpt)
+            f0, margin = _init_multi(bm, rows, ckpt)
         else:
             dist = (get_distribution("bernoulli")
                     if category == ModelCategory.BINOMIAL
                     else get_distribution(dist_name, **p))
-            y_dev, off, f0, margin = _init_single(p, frame, y, bm, w,
-                                                  wh_host, dist, ckpt)
+            off, f0, margin = _init_single(p, frame, bm, w, y_dev, rows,
+                                           dist, ckpt)
             output["init_f"] = float(f0)
 
         # early stopping watches the validation set when given, else
@@ -1174,8 +1156,8 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
         # scan — sequential path handles it; not vmapped (yet)
         raise BatchIneligible("validation-frame early stopping")
 
-    w, wh_host, w_scale, bm = _prepare(b0, frame, x, y)
-    tps = [_tp_of(b.params, bm, w_scale) for b in builders]
+    w, y_dev, rows, bm = _prepare(b0, frame, x, y)
+    tps = [_tp_of(b.params, bm, rows.w_scale) for b in builders]
     rates = [float(b.params["sample_rate"]) for b in builders]
     keys = jnp.stack([_prng_key(b.params) for b in builders])
     constraints = _build_constraints(p0, x, frame, category)
@@ -1190,8 +1172,7 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
     dist = (get_distribution("bernoulli")
             if category == ModelCategory.BINOMIAL
             else get_distribution(dist_name, **p0))
-    y_dev, off, f0, margin1 = _init_single(p0, frame, y, bm, w, wh_host,
-                                           dist)
+    off, f0, margin1 = _init_single(p0, frame, bm, w, y_dev, rows, dist)
     margins = jnp.zeros((M, bm.bins.shape[0]), jnp.float32) + margin1
 
     # The batched chunk loop stays its own: a model that stops is MASKED

@@ -16,17 +16,18 @@ categorical codes are remapped into training domains (unseen level → NA).
 
 from __future__ import annotations
 
+import dataclasses
 import time
 import weakref
 from functools import partial
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.parallel.mesh import fetch_replicated as _fetch_np
-from h2o3_tpu.parallel.mesh import row_sharding
+from h2o3_tpu.parallel.mesh import real_rows, row_sharding
 
 from h2o3_tpu.core.job import Job
 from h2o3_tpu.core.kv import DKV, make_key
@@ -79,8 +80,9 @@ def adapt_domain(test_col, train_domain: List[str]) -> np.ndarray:
     return out
 
 
-@partial(jax.jit, static_argnames=("categorical", "dtype"))
-def _response_program(data, na_mask, w, *, categorical: bool, dtype: str):
+def _response_of(data, na_mask, w, categorical: bool, dtype: str):
+    """Traced body of the response programs: (y as ``dtype``, w with the
+    rows of a missing response weighted out)."""
     if categorical:
         yraw = jnp.where(na_mask, -1, data)
         present = yraw >= 0
@@ -88,10 +90,15 @@ def _response_program(data, na_mask, w, *, categorical: bool, dtype: str):
     else:
         present = ~na_mask
         y = jnp.where(na_mask, 0, data)
+    return y.astype(dtype), w * present.astype(w.dtype)
+
+
+@partial(jax.jit, static_argnames=("categorical", "dtype"))
+def _response_program(data, na_mask, w, *, categorical: bool, dtype: str):
+    y, w = _response_of(data, na_mask, w, categorical, dtype)
     row = row_sharding()
-    return (jax.lax.with_sharding_constraint(y.astype(dtype), row),
-            jax.lax.with_sharding_constraint(w * present.astype(w.dtype),
-                                             row))
+    return (jax.lax.with_sharding_constraint(y, row),
+            jax.lax.with_sharding_constraint(w, row))
 
 
 def response_on_device(col, w, *, categorical: bool, dtype: str = "float32"):
@@ -108,6 +115,109 @@ def response_on_device(col, w, *, categorical: bool, dtype: str = "float32"):
     assert col.data.shape == w.shape, (col.data.shape, w.shape)
     return _response_program(col.data, col.na_mask, w,
                              categorical=categorical, dtype=dtype)
+
+
+# rows a float32 partial sum covers — numpy's own pairwise base case.
+# 0/1 codes and whole weights add up exactly inside a block, a float's
+# rounding stays at the size of a block's sum, and the partials (1.5 MB
+# a 48M-row vector) are finished in float64 on the host
+SUM_BLOCK_ROWS = 128
+
+
+def _block_sums(v):
+    """[N] → [ceil(N / SUM_BLOCK_ROWS)] float32 sums of blocks of rows."""
+    n = v.shape[0]
+    nb = -(-n // SUM_BLOCK_ROWS)
+    if nb * SUM_BLOCK_ROWS != n:
+        v = jnp.pad(v, (0, nb * SUM_BLOCK_ROWS - n))
+    return v.reshape(nb, SUM_BLOCK_ROWS).sum(axis=1)
+
+
+@partial(jax.jit, static_argnames=("categorical", "nclass"))
+def _row_state_program(nrows, data, na_mask, wdata, wna, fold, *,
+                       categorical: bool, nclass: int):
+    """A tree fit's per-row state from the resident columns, and its
+    scalar summary: (w, y, summary). ``wdata`` / ``wna`` (the weights
+    column) and ``fold`` (the CV fold's 1/0 vector) may be None."""
+    real = real_rows(nrows, data.shape[0])
+    w = real.astype(jnp.float32)
+    if wdata is not None:
+        w = w * jnp.where(wna, 0.0, wdata.astype(jnp.float32))
+    if fold is not None:
+        w = w * fold
+    y, w = _response_of(data, na_mask, w, categorical,
+                        "int32" if nclass else "float32")
+    # a constant weight column rescales to exactly 1.0 (a select, not a
+    # division: x / x need not round to 1 on every backend)
+    pos = w > 0
+    w_min = jnp.min(jnp.where(pos, w, jnp.inf))
+    w_max = jnp.max(jnp.where(pos, w, -jnp.inf))
+    uniform = (w_min == w_max) & (w_min != 1.0)
+    w = jnp.where(uniform & pos, 1.0, w)
+    summary = {
+        "w_scale": jnp.where(uniform, w_min, 1.0),
+        "rows_out": jnp.sum(real & ~pos, dtype=jnp.int32),
+        "w": _block_sums(w),
+    }
+    if nclass:
+        # [K, blocks] weighted class counts, a class a pass
+        summary["wy"] = jax.lax.map(
+            lambda k: _block_sums(jnp.where(y == k, w, 0.0)),
+            jnp.arange(nclass, dtype=jnp.int32))
+    else:
+        summary["wy"] = _block_sums(w * y)
+    if not categorical:
+        summary["y_min"] = jnp.min(jnp.where(na_mask, jnp.inf, y))
+        summary["y_max"] = jnp.max(jnp.where(na_mask, -jnp.inf, y))
+    row = row_sharding()
+    return (jax.lax.with_sharding_constraint(w, row),
+            jax.lax.with_sharding_constraint(y, row), summary)
+
+
+@dataclasses.dataclass(frozen=True)
+class RowSummary:
+    """What the host keeps of a tree fit's row state: sums over the rows
+    that train, of the weights as the fit uses them (a constant weight
+    column already rescaled to 1.0, ``w_scale`` saying by what)."""
+    sum_w: float
+    sum_wy: Union[float, np.ndarray]    # sum(w*y); [K] class weights
+    w_scale: float
+    rows_out: int           # real rows weighted out (NA or zero weight)
+    y_min: Optional[float] = None   # numeric response: over its non-NA
+    y_max: Optional[float] = None
+
+
+def row_state_on_device(col, nrows: int, weights_col=None, fold=None):
+    """(w, y_dev, RowSummary) for a tree fit on response ``col``: the
+    device row weights — real rows x weights column (NA → 0) x CV fold
+    vector, rows with a missing response weighted out, a constant weight
+    column rescaled to exactly 1.0 — the response beside them (float32
+    values or 0/1 codes; int32 codes for a K-class response, K != 2) and
+    the scalars the host needs of both, in ONE program and ONE fetch.
+    No array with a row dimension is made on the host.
+
+    Sums: float32 over SUM_BLOCK_ROWS rows on the device, the partials
+    in float64 here — exact for 0/1 codes under whole weights however
+    many rows (a plain float32 sum stalls past 2^24), and within a
+    float32 ulp of the float64 sum for values of one sign."""
+    if col.data is None:
+        raise ValueError(f"response column {col.name!r} is of type "
+                         f"{col.type}; it has to be numeric or categorical")
+    nclass = col.cardinality if (col.is_categorical
+                                 and col.cardinality != 2) else 0
+    w, y_dev, s = _row_state_program(
+        np.int32(nrows), col.data, col.na_mask,
+        None if weights_col is None else weights_col.data,
+        None if weights_col is None else weights_col.na_mask, fold,
+        categorical=col.is_categorical, nclass=nclass)
+    s = _fetch_np(s)
+    wy = np.sum(s["wy"], axis=-1, dtype=np.float64)
+    return w, y_dev, RowSummary(
+        sum_w=float(np.sum(s["w"], dtype=np.float64)),
+        sum_wy=wy if nclass else float(wy),
+        w_scale=float(s["w_scale"]), rows_out=int(s["rows_out"]),
+        y_min=float(s["y_min"]) if "y_min" in s else None,
+        y_max=float(s["y_max"]) if "y_max" in s else None)
 
 
 def checkpoint_error(algo: str, field: str, message: str) -> ValueError:
@@ -376,47 +486,49 @@ class ModelBuilder:
         raise NotImplementedError
 
     # -- shared weight plumbing (one impl; GBM/DRF/GLM all use these) --
+    def _cv_fold_weights(self, frame: Frame):
+        """The CV fold's 1/0 row vector on the device, [nrows_padded]
+        float32, or None outside the CV fast path."""
+        fold_mask = getattr(self, "_cv_fold_mask", None)
+        if fold_mask is None:
+            return None
+        fm = np.zeros(frame.nrows_padded, np.float32)
+        fm[: frame.nrows] = fold_mask.astype(np.float32)
+        return jnp.asarray(fm)
+
     def _cv_masked_weights(self, w, frame: Frame):
         """CV fast path (ml/cv.py): fold models train on the PARENT
         frame with held-out rows weight-masked — no per-fold frame or
         bin rebuild, one compiled program across folds."""
-        fold_mask = getattr(self, "_cv_fold_mask", None)
-        if fold_mask is None:
-            return w
-        fm = np.zeros(frame.nrows_padded, np.float32)
-        fm[: frame.nrows] = fold_mask.astype(np.float32)
-        return w * jnp.asarray(fm)
+        fold = self._cv_fold_weights(frame)
+        return w if fold is None else w * fold
 
     def _training_weights(self, frame: Frame, y: str):
-        """(w, wh_host) for a tree fit: the device row weights — padding
-        mask x user weight column x CV fold mask, with the rows whose
-        response is NA weighted out (the reference's ModelBuilder drops
-        them from training and from the training metrics) — and their
-        equal HOST mirror (_host_weights). Every host-side consumer (bin
-        sketch, init means, priors) reads the mirror instead of syncing
-        the device: a CV sweep calls _fit once per fold, and per-fold
-        fetches dominate leave-one-out CV."""
-        w = frame.valid_weights()
+        """(w, y_dev, rows) for a tree fit, derived on the device from
+        the frame's resident columns (row_state_on_device): the row
+        weights — padding mask x user weight column x CV fold mask, with
+        the rows whose response is NA weighted out (the reference's
+        ModelBuilder drops them from training and from the training
+        metrics) and a constant weight column rescaled to 1.0 — the
+        response, and their RowSummary. The host reads the summary's
+        scalars in one fetch (the one device sync of a fit's preamble);
+        callers divide every ABSOLUTE training threshold (min_rows,
+        min_split_improvement, reg_lambda) by ``rows.w_scale``, which
+        reproduces raw-weight reference semantics exactly in real
+        arithmetic while 'uniform weights ≡ no weights' holds bit for
+        bit (pyunit_weights_gbm)."""
         wc_name = self.params.get("weights_column")
-        if wc_name:
-            wc = frame.col(wc_name).numeric_view()
-            w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
-        w = self._cv_masked_weights(w, frame)
-        wh_host = self._host_weights(frame, y)
-        resp_na_host = np.isnan(frame.col(y).to_numpy())  # cached host view
-        if resp_na_host.any():
-            w = w * jnp.asarray(np.pad(
-                (~resp_na_host).astype(np.float32),
-                (0, frame.nrows_padded - frame.nrows)))
-        return w, wh_host
+        return row_state_on_device(
+            frame.col(y), frame.nrows,
+            weights_col=frame.col(wc_name) if wc_name else None,
+            fold=self._cv_fold_weights(frame))
 
     def _host_weights(self, frame: Frame, y: Optional[str]) -> np.ndarray:
-        """HOST mirror of the effective training weights: user weight
-        column × CV fold mask × response-NA exclusion, [frame.nrows]
-        float32. ONE implementation — GBM/DRF mirror the device vector
-        with this, and uniformity detection classifies it; all reads
-        come from cached host views, so no device sync (a per-fold
-        fetch dominates leave-one-out CV)."""
+        """HOST copy of the training weights before any rescaling: user
+        weight column × CV fold mask × response-NA exclusion,
+        [frame.nrows] float32, equal to _training_weights' device vector
+        row for row. Built for ONE reader: the weighted quantile sketch
+        of a frame's first binning (_binned), which runs on the host."""
         wc_name = self.params.get("weights_column")
         if wc_name and wc_name in frame:
             wh = np.nan_to_num(
@@ -431,20 +543,30 @@ class ModelBuilder:
             wh = wh * (~np.isnan(frame.col(y).to_numpy())).astype(np.float32)
         return wh
 
-    def _normalize_uniform_weights(self, w, wh_host: np.ndarray):
-        """(w', scale): a constant weight column rescales to exactly 1.0
-        so 'uniform weights ≡ no weights' holds bit-for-bit
-        (pyunit_weights_gbm asserts 1e-5-relative metric equality, which
-        f32 rounding of w*k misses). Callers divide every ABSOLUTE
-        training threshold (min_rows, min_split_improvement,
-        reg_lambda) by the returned scale — that reproduces raw-weight
-        reference semantics exactly in real arithmetic. ``wh_host`` is
-        the _host_weights mirror of ``w``."""
-        pos = wh_host[wh_host > 0]
-        if pos.size and pos.min() == pos.max() and float(pos[0]) != 1.0:
-            s = float(pos[0])
-            return w / s, s
-        return w, 1.0
+    def _binned(self, frame: Frame, x: Sequence[str], y: str, **config):
+        """(bm, "hit" | "miss"): the frame's binned training matrix from
+        bin_frame's cache, its slot named by what the training weights
+        are made from — the weights column and the response (columns
+        are immutable and a frame drops its bins when one is replaced)
+        — so a warm fit hashes no rows. The host weight vector is built
+        on a miss alone: the weighted edges of the row-weight ≡
+        row-multiplicity contract (pyunit_weights_gbm) are cut on the
+        host. A CV fold's weights have no such name: they go by content
+        and read "miss" (ml/cv.py hands fold fits the main model's bins,
+        so none comes here)."""
+        from h2o3_tpu.frame.binning import bin_frame
+        built = []
+
+        def host_weights():
+            built.append(True)
+            return self._host_weights(frame, y)
+
+        named = getattr(self, "_cv_fold_mask", None) is None
+        bm = bin_frame(
+            frame, x, weights=host_weights, weights_key=(
+                self.params.get("weights_column"), y) if named else None,
+            **config)
+        return bm, "miss" if built else "hit"
 
     # -- public train --------------------------------------------------
     def resolve_x(self, frame: Frame, x: Optional[Sequence[str]],

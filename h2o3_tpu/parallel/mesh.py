@@ -23,9 +23,11 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import os
+from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -302,8 +304,22 @@ def shard_rows(x, mesh: Optional[Mesh] = None, block: int = 1,
     return put_sharded(x, row_sharding(mesh))
 
 
+def real_rows(n, npad: int):
+    """[npad] bool under a trace: True for the ``n`` real rows. An iota
+    compared with a scalar — nothing row-sized is fed or folded in."""
+    return jax.lax.iota(jnp.int32, npad) < n
+
+
+@partial(jax.jit, static_argnames=("npad", "sharding"))
+def _valid_mask_program(n, *, npad: int, sharding):
+    return jax.lax.with_sharding_constraint(
+        real_rows(n, npad).astype(jnp.float32), sharding)
+
+
 def valid_mask(n: int, npad: int, mesh: Optional[Mesh] = None):
-    """float32 1/0 mask marking real rows among padded."""
-    m = np.zeros((npad,), dtype=np.float32)
-    m[:n] = 1.0
-    return put_sharded(m, row_sharding(mesh))
+    """float32 1/0 mask marking real rows among padded, made on the
+    device (an iota compared with ``n``): no host vector, no upload.
+    ``n`` is a traced scalar, so frames of one padded size share the
+    program."""
+    return _valid_mask_program(np.int32(n), npad=int(npad),
+                               sharding=row_sharding(mesh))

@@ -399,6 +399,72 @@ def test_glm_response_on_device(topo, categorical, chips):
     assert "collective-permute" not in txt and "all-to-all" not in txt
 
 
+def _row_sized_constants(hlo_text, n):
+    """Shapes of the program's literal constants with a dimension of at
+    least ``n // 2`` (a folded row mask would be one)."""
+    return [m for m in re.findall(r"= \w+\[([\d,]+)\]\S* constant\(",
+                                  hlo_text)
+            if max(int(d) for d in m.split(",")) >= n // 2]
+
+
+def _assert_rows_stay_on_the_chip(txt, n):
+    assert "callback" not in txt and "infeed" not in txt
+    assert "outfeed" not in txt
+    assert not _row_sized_constants(txt, n)
+    # scalars may be all-reduced; nothing is gathered or moved
+    assert "all-gather" not in txt and "all-to-all" not in txt
+    assert "collective-permute" not in txt
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("response", ["binomial", "numeric_weighted",
+                                      "seven_classes"])
+def test_gbm_row_state_on_device(topo, response, chips):
+    """models/model.py's row-state program at the rows of the cell
+    gbm-airlines-d6.fit-48m: the row mask is an iota compared with a
+    scalar (nothing row-sized is folded in or fed from the host), the
+    rows never leave their chip — only block partials and scalars do —
+    and the temporaries stay a few row vectors."""
+    from h2o3_tpu.models.model import SUM_BLOCK_ROWS, _row_state_program
+    mesh = _mesh(topo, chips)
+    n = mesh_mod.padded_rows(AIR48_ROWS, mesh)
+    assert n == 48_234_496
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    vec = lambda dt: S((n,), dt, sharding=row)   # noqa: E731
+    weighted = response == "numeric_weighted"
+    with _as_global_mesh(mesh):
+        lowered = _row_state_program.lower(
+            S((), jnp.int32),
+            vec(jnp.float32 if weighted else jnp.int32), vec(jnp.bool_),
+            vec(jnp.float32) if weighted else None,
+            vec(jnp.bool_) if weighted else None,
+            vec(jnp.float32) if weighted else None,
+            categorical=not weighted,
+            nclass=7 if response == "seven_classes" else 0)
+    compiled = lowered.compile()
+    _assert_rows_stay_on_the_chip(compiled.as_text(), n)
+    w, y, summary = compiled.output_shardings
+    assert w.is_equivalent_to(row, 1) and y.is_equivalent_to(row, 1)
+    shapes = jax.tree_util.tree_map(lambda a: a.shape, lowered.out_info[2])
+    assert shapes["w"] == (n // SUM_BLOCK_ROWS,)
+    # w and y (in the outputs), and beside them at most four row vectors
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 4 * n / chips
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_valid_mask_on_device(topo, chips):
+    """parallel/mesh.py valid_mask at the same rows: made where it
+    lies, from no argument but the row count."""
+    mesh = _mesh(topo, chips)
+    n = mesh_mod.padded_rows(AIR48_ROWS, mesh)
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    compiled = mesh_mod._valid_mask_program.lower(
+        S((), jnp.int32), npad=n, sharding=row).compile()
+    _assert_rows_stay_on_the_chip(compiled.as_text(), n)
+    assert compiled.output_shardings.is_equivalent_to(row, 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n / chips
+
+
 def test_dl_train_chunk(topo):
     from h2o3_tpu.models.deeplearning import DeepLearningEstimator
     r = np.random.RandomState(5)
