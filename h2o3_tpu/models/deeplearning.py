@@ -140,10 +140,18 @@ def _train_step_impl(params, opt_state, lr, X, y, w, key, *, act, category,
     the cross-replica model averaging (DeepLearningTask.java:164-176).
     ``mu_now`` overrides the momentum carried in opt_state (the fused
     multi-step path computes the ramp per step on device)."""
-    grads = jax.grad(_loss)(params, X, y, w, key, act=act, category=category,
+    # jax.grad, written out so that a trace can tell the two passes
+    # apart: a scope opened inside a differentiated function is renamed
+    # by the transform (``jvp(dl.forward)``), one opened around it is not
+    with jax.named_scope("dl.forward"):
+        value, back = jax.vjp(
+            lambda q: _loss(q, X, y, w, key, act=act, category=category,
                             input_dropout=input_dropout,
                             hidden_dropout=hidden_dropout, l1=l1, l2=l2,
-                            nclasses=nclasses, bf16=bf16)
+                            nclasses=nclasses, bf16=bf16), params)
+    with jax.named_scope("dl.backward"):
+        (grads,) = back(jnp.ones_like(value))
+
     def upd(p, g, s):
         # ADADELTA (reference adaptive_rate=True, rho/epsilon params)
         eg2 = rho * s["eg2"] + (1 - rho) * g * g
@@ -152,21 +160,24 @@ def _train_step_impl(params, opt_state, lr, X, y, w, key, *, act, category,
         return p + dx, {"eg2": eg2, "ex2": ex2}
 
     new_params, new_state = [], []
-    for p, g, s in zip(params, grads, opt_state):
-        np_, ns_ = {}, {}
-        for k in ("W", "b"):
-            if adaptive:
-                pk, sk = upd(p[k], g[k], s[k])
-            else:
-                # Nesterov momentum SGD (reference momentum_start/stable)
-                mu = s[k]["mu"] if mu_now is None else mu_now
-                v = mu * s[k]["v"] - lr * g[k]
-                pk = (p[k] + mu * v - lr * g[k]) if nesterov else (p[k] + v)
-                sk = {"v": v, "mu": mu}
-            np_[k] = pk
-            ns_[k] = sk
-        new_params.append(np_)
-        new_state.append(ns_)
+    with jax.named_scope("dl.update"):
+        for p, g, s in zip(params, grads, opt_state):
+            np_, ns_ = {}, {}
+            for k in ("W", "b"):
+                if adaptive:
+                    pk, sk = upd(p[k], g[k], s[k])
+                else:
+                    # Nesterov momentum SGD (reference
+                    # momentum_start/stable)
+                    mu = s[k]["mu"] if mu_now is None else mu_now
+                    v = mu * s[k]["v"] - lr * g[k]
+                    pk = (p[k] + mu * v - lr * g[k]) if nesterov \
+                        else (p[k] + v)
+                    sk = {"v": v, "mu": mu}
+                np_[k] = pk
+                ns_[k] = sk
+            new_params.append(np_)
+            new_state.append(ns_)
     return new_params, new_state
 
 
@@ -176,9 +187,12 @@ _STEP_STATICS = ("act", "category", "input_dropout", "hidden_dropout",
 
 # jitted full-dataset loss for the early-stopping boundary — the eager
 # _loss layer loop would re-dispatch per op
-_loss_eval = partial(jax.jit, static_argnames=(
+@partial(jax.jit, static_argnames=(
     "act", "category", "input_dropout", "hidden_dropout", "l1", "l2",
-    "nclasses"))(_loss)
+    "nclasses"))
+def _loss_eval(params, X, y, w, key, **kwargs):
+    with jax.named_scope("dl.score"):
+        return _loss(params, X, y, w, key, **kwargs)
 
 
 @observed_jit("dl.train_chunk")
@@ -218,16 +232,17 @@ def _train_steps_fused(params, opt_state, X, y, w, key, step0, start0,
         # start0 is host-computed (exact int; step0*batch would overflow
         # int32 on long fits); modulo n, with dynamic_slice clamping the
         # epoch-boundary start so tail rows still train.
-        start = (start0 + i.astype(jnp.int32) * batch) % max(n, 1)
-        Xb = jax.lax.dynamic_slice_in_dim(X, start, batch, axis=0)
-        yb = jax.lax.dynamic_slice_in_dim(y, start, batch, axis=0)
-        wb = jax.lax.dynamic_slice_in_dim(w, start, batch, axis=0)
-        # the sliced batch must stay row-sharded: without the constraint
-        # GSPMD may replicate it and the gradient psum over the 'data'
-        # axis would average a replicated batch
-        Xb = jax.lax.with_sharding_constraint(Xb, row_sharding())
-        yb = jax.lax.with_sharding_constraint(yb, row_sharding())
-        wb = jax.lax.with_sharding_constraint(wb, row_sharding())
+        with jax.named_scope("dl.slice"):
+            start = (start0 + i.astype(jnp.int32) * batch) % max(n, 1)
+            Xb = jax.lax.dynamic_slice_in_dim(X, start, batch, axis=0)
+            yb = jax.lax.dynamic_slice_in_dim(y, start, batch, axis=0)
+            wb = jax.lax.dynamic_slice_in_dim(w, start, batch, axis=0)
+            # the sliced batch must stay row-sharded: without the
+            # constraint GSPMD may replicate it and the gradient psum
+            # over the 'data' axis would average a replicated batch
+            Xb = jax.lax.with_sharding_constraint(Xb, row_sharding())
+            yb = jax.lax.with_sharding_constraint(yb, row_sharding())
+            wb = jax.lax.with_sharding_constraint(wb, row_sharding())
         lr = jnp.float32(rate) / (1.0 + rate_annealing * step * batch)
         ramp = jnp.minimum(1.0, step * batch / max(momentum_ramp, 1.0))
         mu_now = jnp.float32(momentum_start
@@ -235,11 +250,12 @@ def _train_steps_fused(params, opt_state, X, y, w, key, step0, start0,
         new_p, new_s = _train_step_impl(
             params, opt_state, lr, Xb, yb, wb, kstep,
             mu_now=mu_now, **step_kwargs)
-        eff = i < limit
-        params = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(eff, a, b), new_p, params)
-        opt_state = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(eff, a, b), new_s, opt_state)
+        with jax.named_scope("dl.mask"):
+            eff = i < limit
+            params = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(eff, a, b), new_p, params)
+            opt_state = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(eff, a, b), new_s, opt_state)
         return (params, opt_state, key), None
 
     (params, opt_state, key), _ = jax.lax.scan(
@@ -411,55 +427,10 @@ class DeepLearningEstimator(ModelBuilder):
         merged.update(params)
         super().__init__(**merged)
 
-    def _fit(self, frame: Frame, x: Sequence[str], y: Optional[str],
-             job, validation_frame: Optional[Frame] = None) -> Model:
+    def _init_net(self, sizes: List[int], act: str, key, kinit):
+        """Weights, optimizer state, PRNG key and the steps already
+        trained: fresh from ``kinit``, or a checkpoint donor's."""
         p = self.params
-        mesh = get_mesh()
-        auto_enc = bool(p["autoencoder"])
-        category = (None if auto_enc else infer_category(frame, y))
-        act, act_dropout = _parse_activation(str(p["activation"]))
-        di = build_datainfo(frame, x, standardize=bool(p["standardize"]),
-                            use_all_factor_levels=bool(p["use_all_factor_levels"]))
-        w = frame.valid_weights()
-        if p.get("weights_column"):
-            wc = frame.col(p["weights_column"]).numeric_view()
-            w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
-
-        N = di.X.shape[0]
-        n = frame.nrows
-        resp_stats = None
-        if auto_enc:
-            y_dev = di.X
-            out_dim = di.P
-            cat_mode = "mse"
-        elif category == ModelCategory.REGRESSION:
-            yv = frame.col(y).numeric_view()
-            w = w * jnp.where(jnp.isnan(yv), 0.0, 1.0)
-            yhost = np.nan_to_num(np.asarray(yv))
-            wn = np.asarray(w)
-            mu = float((yhost * wn).sum() / max(wn.sum(), 1e-12))
-            sd = float(np.sqrt(np.maximum(
-                ((yhost - mu) ** 2 * wn).sum() / max(wn.sum(), 1e-12), 1e-12)))
-            resp_stats = (mu, sd)
-            y_dev = jnp.asarray((yhost - mu) / sd)[:, None]
-            out_dim = 1
-            cat_mode = "mse"
-        else:
-            rc = frame.col(y)
-            codes = _fetch_np(rc.data)[:n].astype(np.int32)
-            na = _fetch_np(rc.na_mask)[:n]
-            w = w * jnp.asarray(np.pad((~na).astype(np.float32), (0, N - n)))
-            codes[na] = 0
-            y_dev = jax.device_put(np.pad(codes, (0, N - n)),
-                                   row_sharding(mesh))
-            out_dim = rc.cardinality
-            cat_mode = "softmax"
-
-        hidden = [int(h) for h in p["hidden"]]
-        sizes = [di.P] + hidden + [out_dim]
-        seed = int(p["seed"]) if int(p["seed"]) >= 0 else 0xD1
-        key = jax.random.PRNGKey(seed)
-        key, kinit = jax.random.split(key)
         done0 = 0
         prior_opt = prior_key = None
         if p.get("checkpoint") is not None:
@@ -499,13 +470,6 @@ class DeepLearningEstimator(ModelBuilder):
         else:
             params_net = _init_params(kinit, sizes, act == "maxout")
 
-        hd = p["hidden_dropout_ratios"]
-        if hd is None:
-            hd = tuple([0.5] * len(hidden)) if act_dropout else tuple([0.0] * len(hidden))
-        else:
-            hd = tuple(float(v) for v in hd)
-        in_drop = float(p["input_dropout_ratio"])
-
         adaptive = bool(p["adaptive_rate"])
         if adaptive:
             opt_state = [{k: {"eg2": jnp.zeros_like(l[k]),
@@ -522,6 +486,84 @@ class DeepLearningEstimator(ModelBuilder):
             opt_state = jax.tree_util.tree_map(jnp.asarray, prior_opt)
         if prior_key is not None:
             key = jnp.asarray(prior_key)
+        return params_net, opt_state, key, done0
+
+    def _fit(self, frame: Frame, x: Sequence[str], y: Optional[str],
+             job, validation_frame: Optional[Frame] = None) -> Model:
+        from h2o3_tpu import telemetry
+        from h2o3_tpu.telemetry import stepprof
+        p = self.params
+        mesh = get_mesh()
+        auto_enc = bool(p["autoencoder"])
+        category = (None if auto_enc else infer_category(frame, y))
+        act, act_dropout = _parse_activation(str(p["activation"]))
+        with telemetry.span("deeplearning.design"):
+            di = build_datainfo(
+                frame, x, standardize=bool(p["standardize"]),
+                use_all_factor_levels=bool(p["use_all_factor_levels"]))
+            w = frame.valid_weights()
+        if p.get("weights_column"):
+            wc = frame.col(p["weights_column"]).numeric_view()
+            w = w * jnp.where(jnp.isnan(wc), 0.0, wc)
+
+        N = di.X.shape[0]
+        n = frame.nrows
+        resp_stats = None
+        # the response still goes through the host: ``host_bytes`` is
+        # what a job fetches from and sends to the device for it
+        with telemetry.span("deeplearning.response", on_device=False,
+                            host_bytes=0) as resp_span:
+            if auto_enc:
+                y_dev = di.X
+                out_dim = di.P
+                cat_mode = "mse"
+            elif category == ModelCategory.REGRESSION:
+                yv = frame.col(y).numeric_view()
+                w = w * jnp.where(jnp.isnan(yv), 0.0, 1.0)
+                yhost = np.nan_to_num(np.asarray(yv))
+                wn = np.asarray(w)
+                mu = float((yhost * wn).sum() / max(wn.sum(), 1e-12))
+                sd = float(np.sqrt(np.maximum(
+                    ((yhost - mu) ** 2 * wn).sum() / max(wn.sum(), 1e-12),
+                    1e-12)))
+                resp_stats = (mu, sd)
+                y_std = (yhost - mu) / sd
+                y_dev = jnp.asarray(y_std)[:, None]
+                resp_span.annotate(host_bytes=int(
+                    yhost.nbytes + wn.nbytes + y_std.nbytes))
+                out_dim = 1
+                cat_mode = "mse"
+            else:
+                rc = frame.col(y)
+                codes = _fetch_np(rc.data)[:n].astype(np.int32)
+                na = _fetch_np(rc.na_mask)[:n]
+                keep = np.pad((~na).astype(np.float32), (0, N - n))
+                w = w * jnp.asarray(keep)
+                codes[na] = 0
+                codes = np.pad(codes, (0, N - n))
+                y_dev = jax.device_put(codes, row_sharding(mesh))
+                resp_span.annotate(host_bytes=int(
+                    N * (rc.data.dtype.itemsize + rc.na_mask.dtype.itemsize)
+                    + keep.nbytes + codes.nbytes))
+                out_dim = rc.cardinality
+                cat_mode = "softmax"
+
+        hidden = [int(h) for h in p["hidden"]]
+        sizes = [di.P] + hidden + [out_dim]
+        seed = int(p["seed"]) if int(p["seed"]) >= 0 else 0xD1
+        key = jax.random.PRNGKey(seed)
+        key, kinit = jax.random.split(key)
+        with telemetry.span("deeplearning.init"):
+            params_net, opt_state, key, done0 = self._init_net(
+                sizes, act, key, kinit)
+        adaptive = bool(p["adaptive_rate"])
+
+        hd = p["hidden_dropout_ratios"]
+        if hd is None:
+            hd = tuple([0.5] * len(hidden)) if act_dropout else tuple([0.0] * len(hidden))
+        else:
+            hd = tuple(float(v) for v in hd)
+        in_drop = float(p["input_dropout_ratio"])
 
         batch = int(p["mini_batch_size"])
         if batch <= 1:
@@ -606,12 +648,14 @@ class DeepLearningEstimator(ModelBuilder):
                     next_score = _st["next_score"]
                     stopper.history = list(_st["stop_hist"])
                     scoring_history = list(_st["scoring_history"])
-        from h2o3_tpu import telemetry
-        from h2o3_tpu.telemetry import stepprof
         while done < total_steps:
             k = min(chunk, total_steps - done)
             stepprof.chunk_begin()
-            with telemetry.span("deeplearning.chunk", steps=k):
+            # ``steps`` take effect; ``steps_run`` is the static chunk the
+            # program computes whatever ``steps`` is
+            with telemetry.span("deeplearning.chunk", steps=k,
+                                steps_run=chunk, batch=batch,
+                                bf16=step_kwargs["bf16"]):
                 params_net, opt_state, key = _train_steps_fused(
                     params_net, opt_state, Xh, y_dev, w, key,
                     jnp.float32(done),
@@ -627,11 +671,12 @@ class DeepLearningEstimator(ModelBuilder):
                                     or done >= total_steps):
                 next_score += score_stride
                 key, sub = jax.random.split(key)
-                lv = float(_loss_eval(
-                    params_net, Xh, y_dev, w, sub, act=act,
-                    category=cat_mode, input_dropout=0.0,
-                    hidden_dropout=tuple([0.0] * len(hidden)),
-                    l1=0.0, l2=0.0, nclasses=out_dim))
+                with telemetry.span("deeplearning.score", step=done):
+                    lv = float(_loss_eval(
+                        params_net, Xh, y_dev, w, sub, act=act,
+                        category=cat_mode, input_dropout=0.0,
+                        hidden_dropout=tuple([0.0] * len(hidden)),
+                        l1=0.0, l2=0.0, nclasses=out_dim))
                 scoring_history.append({"step": done, "loss": lv})
                 if stopper.should_stop(lv):
                     break
@@ -688,6 +733,15 @@ class DeepLearningEstimator(ModelBuilder):
                 bkeys.append(bf.key)
             model.output["weights_keys"] = wkeys
             model.output["biases_keys"] = bkeys
+        with telemetry.span("deeplearning.metrics"):
+            self._score_metrics(model, frame, category, validation_frame)
+        return model
+
+    def _score_metrics(self, model, frame: Frame, category,
+                       validation_frame: Optional[Frame]) -> None:
+        """Training metrics (on the reference's 10K-row sample by
+        default) and validation metrics of the finished model."""
+        p = self.params
         nscore = int(p.get("score_training_samples") or 0)
         score_mask = None
         if nscore and frame.nrows > nscore:
@@ -717,4 +771,3 @@ class DeepLearningEstimator(ModelBuilder):
                 vmask = vm
             model.validation_metrics = model.model_performance(
                 validation_frame, mask_weights=vmask)
-        return model

@@ -43,6 +43,7 @@ AIR_CATS = (False,) * 6 + (True,) * 3 + (False,)
 GLM_ROWS, DL_ROWS, SCORE_ROWS = 2_000_000, 200_000, 100_000
 HIGGS_ROWS = 11_000_000                  # benchmark cell glm-higgs.fit-11m
 AIR48_ROWS = 48_000_000                  # cell gbm-airlines-d6.fit-48m (F, B)
+MNIST1M_ROWS = 1_048_576                 # cell dl-mnist8m-200x200.fit-1m
 TINY_ROWS = 3000
 LEVELS = (0, 3, 5)                       # of depth-bucket 6
 
@@ -465,15 +466,48 @@ def test_valid_mask_on_device(topo, chips):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * n / chips
 
 
-def test_dl_train_chunk(topo):
+@pytest.fixture(scope="module")
+def tiny_dl():
+    """A DeepLearning fit at the MNIST widths, small, on the CPU test
+    mesh: leaves ``dl.train_chunk``'s call with the compile observer."""
     from h2o3_tpu.models.deeplearning import DeepLearningEstimator
     r = np.random.RandomState(5)
     X = (r.rand(TINY_ROWS, 784) > 0.8).astype(np.float32)
     cols = {f"p{i}": X[:, i] for i in range(784)}
     cols["label"] = r.randint(0, 10, TINY_ROWS).astype(str)
     fr = h2o3_tpu.Frame.from_numpy(cols, categorical=["label"])
-    DeepLearningEstimator(hidden=[200, 200], activation="rectifier",
-                          epochs=0.1, seed=1).train(fr, y="label")
+    model = DeepLearningEstimator(
+        hidden=[200, 200], activation="rectifier", epochs=0.1,
+        seed=1).train(fr, y="label")
+    yield fr.nrows_padded
+    h2o3_tpu.DKV.remove(model.key)
+    h2o3_tpu.DKV.remove(fr.key)
+
+
+@pytest.mark.allow_key_leak          # the module-scoped fit above
+def test_dl_train_chunk(topo, tiny_dl):
     # the smoke's fit: 200k rows give a 2048-row batch, 200-step chunks
-    _lower_recorded("dl.train_chunk", _mesh(topo, 1), fr.nrows_padded,
+    _lower_recorded("dl.train_chunk", _mesh(topo, 1), tiny_dl,
                     DL_ROWS, n=DL_ROWS, batch=2048, nsteps=200).compile()
+
+
+@pytest.mark.allow_key_leak
+def test_dl_train_chunk_at_the_benchmark_cell(topo, tiny_dl):
+    """The chunk of ``dl-mnist8m-200x200.fit-1m`` as the fit asks for
+    it: the 1,048,576 x 784 float32 design matrix on one chip, batches
+    of 16,384 rows, bfloat16 operands, 200 static steps. It lowers under
+    the name the benchmark's readers look for, and what it asks of the
+    chip beside the resident matrix stays a small part of it."""
+    lowered = _lower_recorded(
+        "dl.train_chunk", _mesh(topo, 1), tiny_dl, MNIST1M_ROWS,
+        n=MNIST1M_ROWS, batch=16384, nsteps=200, bf16=True)
+    assert "module @jit__train_steps_fused" in lowered.as_text()[:400]
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    matrix = MNIST1M_ROWS * 784 * 4
+    assert matrix <= mem.argument_size_in_bytes < matrix + 64e6
+    # the compiler hoists the operands' rounding out of the scan: ONE
+    # bfloat16 copy of the matrix (1.64 GB) a chunk, then a batch, its
+    # activations and their gradients — never a second float32 matrix
+    assert matrix // 2 <= mem.temp_size_in_bytes < matrix // 2 + 256e6
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
