@@ -23,13 +23,15 @@ import numpy as np
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.frame.rollups import rollups
 from h2o3_tpu.parallel.mesh import row_sharding
+from h2o3_tpu.telemetry import annotate
 
 
 @partial(jax.jit, static_argnames=("spec", "standardize"))
 def _design_device(datas, nas, stats, *, spec: tuple, standardize: bool):
     """All columns → the dense [Npad, P] design matrix in ONE compiled
     program. ``spec`` per column: ("cat", first_level, cardinality) or
-    ("num",); ``stats`` per column: (mu, sd) scalars (unused for cats).
+    ("num",); ``stats``: ONE ``[cols, 2]`` float32 array, row ``i`` the
+    (mu, sd) of column ``i`` ((0, 1) and unread for cats).
     """
     blocks = []
     for i, sp in enumerate(spec):
@@ -41,7 +43,7 @@ def _design_device(datas, nas, stats, *, spec: tuple, standardize: bool):
             oh = (code[:, None] == levels[None, :]).astype(jnp.float32)
             blocks.append(jnp.where(na[:, None], 0.0, oh))
         else:
-            mu, sd = stats[i]
+            mu, sd = stats[i, 0], stats[i, 1]
             x = datas[i].astype(jnp.float32)
             x = jnp.where(na | jnp.isnan(x), mu, x)   # mean imputation
             if standardize:
@@ -87,11 +89,15 @@ def build_datainfo(frame: Frame, features: Sequence[str],
     domains: List[Optional[List[str]]] = []
     shard = row_sharding()
 
-    # host pass: names/domains/stats + per-column device inputs; the
-    # expansion itself runs as ONE jitted program (_design_device) —
-    # per-column eager ops re-dispatch through the runtime and dominate
-    # wall time on a remote-attached chip
-    datas, nas, stats, spec = [], [], [], []
+    # host pass: names/domains, the columns' resident device arrays and
+    # every column's (mu, sd) in ONE host array, which reaches the chip
+    # as one argument of the ONE jitted program (_design_device). No
+    # device operation per column: an eager op, or a scalar converted on
+    # its own, is a transfer of its own — 0.4 ms each on a
+    # remote-attached chip, 0.61 s of a 0.64 s build at 784 columns
+    datas, nas, spec = [], [], []
+    stats = np.tile(np.float32([0.0, 1.0]), (len(cols), 1))
+    host_arrays, host_bytes = (1, stats.nbytes) if cols else (0, 0)
     for i, c in enumerate(cols):
         if is_cat[i]:
             if stats_override is not None:
@@ -100,9 +106,11 @@ def build_datainfo(frame: Frame, features: Sequence[str],
                 codes = adapt_domain(c, dom)
                 codes = np.pad(codes, (0, frame.nrows_padded - frame.nrows),
                                constant_values=-1)
-                datas.append(jax.device_put(
-                    np.maximum(codes, 0).astype(np.int32), shard))
-                nas.append(jax.device_put(codes < 0, shard))
+                code, na = np.maximum(codes, 0).astype(np.int32), codes < 0
+                datas.append(jax.device_put(code, shard))
+                nas.append(jax.device_put(na, shard))
+                host_arrays += 2
+                host_bytes += code.nbytes + na.nbytes
             else:
                 dom = c.domain or []
                 datas.append(c.data)
@@ -116,7 +124,6 @@ def build_datainfo(frame: Frame, features: Sequence[str],
             # for indicators is the level frequency — zero is the simple,
             # consistent choice and is masked by skip rows when requested)
             spec.append(("cat", first, card))
-            stats.append((0.0, 1.0))
             coef_names += [f"{c.name}.{dom[l]}" for l in range(first, card)]
         else:
             domains.append(None)
@@ -126,22 +133,23 @@ def build_datainfo(frame: Frame, features: Sequence[str],
             else:
                 r = rollups(c)
                 mu, sd = r["mean"], (r["sigma"] or 1.0)
+            sd = sd if sd > 0 else 1.0
             num_means.append(mu)
-            num_sigmas.append(sd if sd > 0 else 1.0)
+            num_sigmas.append(sd)
             spec.append(("num",))
-            stats.append((float(mu), float(sd if sd > 0 else 1.0)))
+            stats[i] = (float(mu), float(sd))
             datas.append(c.data)
             nas.append(c.na_mask)
             coef_names.append(c.name)
 
     if cols:
-        X = _design_device(tuple(datas), tuple(nas),
-                           tuple((jnp.float32(m), jnp.float32(s))
-                                 for m, s in stats),
+        X = _design_device(tuple(datas), tuple(nas), stats,
                            spec=tuple(spec), standardize=bool(standardize))
     else:
         X = jnp.zeros((frame.nrows_padded, 0), jnp.float32)
     X = jax.device_put(X, shard)
+    annotate(columns=len(cols), host_arrays=host_arrays,
+             host_bytes=host_bytes)
     return DataInfo(
         names=list(features), coef_names=coef_names, X=X, is_cat=is_cat,
         cat_offsets=np.asarray(cat_offsets, np.int64),

@@ -179,6 +179,11 @@ def test_a_fit_leaves_its_phase_spans(fits):
     assert set(PHASES) | {"fit"} <= set(by)
     for once in ("design", "response", "init", "metrics", "fit"):
         assert len(by[once]) == 1, once
+    # every column's statistics went up as ONE [INPUTS, 2] float32
+    # array, and nothing else did
+    design = by["design"][0]
+    assert (design["columns"], design["host_arrays"],
+            design["host_bytes"]) == (INPUTS, 1, INPUTS * 2 * 4)
     resp = by["response"][0]
     # the label's codes and NA mask fetched, the NA weight and the
     # padded int32 codes sent: int32 + bool + float32 + int32 a row

@@ -156,6 +156,12 @@ def test_train_builds_the_response_on_device(kind):
           if s["name"] == "glm.response"][-1]
     assert sp["meta"]["on_device"] is True
     assert sp["meta"]["host_bytes"] == 0
+    # the design matrix's build sent one host array: three columns'
+    # (mean, sigma) as float32
+    design = [s for s in telemetry.spans_snapshot(200)
+              if s["name"] == "glm.design"][-1]["meta"]
+    assert (design["columns"], design["host_arrays"],
+            design["host_bytes"]) == (3, 1, 3 * 2 * 4)
     coef = m.coef_multinomial if kind == "multinomial" else m.coef
     got = [float(v).hex() for v in np.asarray(coef).ravel()]
     assert got == _PARENT_COEF[kind]
