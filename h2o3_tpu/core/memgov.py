@@ -142,6 +142,10 @@ def _frame_cache_nbytes(fr) -> int:
     return total
 
 
+# level passes whose streamed bytes stand for a tree fit's working set
+TREE_WORKING_LEVELS = 6.0
+
+
 def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
                        validation_frame=None) -> int:
     """Projected device footprint of one fit: the resident input frames,
@@ -171,6 +175,14 @@ def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
             samples = float(d.get("samples", 0.0) or 0.0)
             rows = float(getattr(frame, "nrows", 0) or 1)
             units = samples / rows if samples else 1.0
+        depth = float(d.get("depth") or 0.0)
+        if depth > TREE_WORKING_LEVELS:
+            # a tree streams its rows once a level, but what is alive
+            # between chunk boundaries is the row state, whatever the
+            # depth: count the passes the flagship depth stands for (the
+            # depth-20 default forest read 25 GB a tree here and was
+            # refused a chip it fits, PERF.md §6, PR 35)
+            units *= depth / TREE_WORKING_LEVELS
         est += int(float(cost.get("bytes", 0.0)) / max(units, 1.0))
     return int(est)
 

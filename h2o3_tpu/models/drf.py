@@ -20,6 +20,7 @@ throughout, and one model costs one dispatch.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 
 from functools import partial
@@ -38,16 +39,24 @@ from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
                                    adapt_domain, checkpoint_error,
                                    infer_category, resolve_checkpoint_model,
                                    validate_checkpoint_params)
+from h2o3_tpu import telemetry
+from h2o3_tpu.models import frontier
 from h2o3_tpu.models.tree import (Tree, TreeParams, bucket_depth,
-                                  grow_tree, predict_forest, scalars_of,
-                                  stack_trees, trees_per_chunk)
+                                  concat_forests, frontier_start, grow_tree,
+                                  kernel_levels, leaf_values, predict_forest,
+                                  scalars_of, stack_trees, trees_per_chunk)
 from h2o3_tpu.ops import pallas as pallas_ops
-from h2o3_tpu.parallel.mesh import get_mesh, row_sharding
+from h2o3_tpu.parallel.mesh import get_mesh
 from h2o3_tpu.utils.log import get_logger
 
 log = get_logger("h2o3_tpu.drf")
 
-MAX_COMPLETE_DEPTH = 14  # complete-tree layout: histograms are 2^d·F·B·3
+# what the readers of the complete ``Tree`` layout (TreeSHAP, leaf
+# assignment, path counts, MOJO/POJO export) are handed: a forest whose
+# REALIZED depth is at most this is converted for them after the fit
+# (DRFModel.forest); a deeper one raises frontier.DeepForestError there.
+# Growth itself is not capped by it.
+MAX_COMPLETE_DEPTH = 14
 
 
 @partial(jax.jit,
@@ -83,7 +92,7 @@ def _bag_scan(bins, nb, ys, w, key, depth_limit, *, tp: TreeParams,
     (oob_sum, oob_cnt), (trees, gains) = jax.lax.scan(
         step, (oob_sum, oob_cnt), subs)
     # [T, K, ...] per-scan-step stacked class trees → flat [T*K, ...]
-    forest = Tree(*(a.reshape((-1,) + a.shape[2:]) for a in trees))
+    forest = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), trees)
     # key_out: the evolved key chain — the chunked capped path threads
     # it so chunked and single-scan forests are bit-identical for the
     # same seed (a NON-binding max_runtime_secs must not change results)
@@ -112,26 +121,72 @@ def _bag_body(bins, nb, ys, w, oob_sum, oob_cnt, key, depth_limit, *,
         kt, sub = jax.random.split(kt)
         yk = ys[:, k]
         # g=-y, h=1 ⇒ leaf value = Σ w·y / (Σ w + λ): the bagged leaf mean
-        tree, nid, gains = grow_tree(bins, nb, wbag, -yk, jnp.ones_like(yk),
+        tree, nid, gains = grow_tree(bins, nb, wbag, -yk, None,
                                      col_mask, params=tp, mesh=mesh,
                                      mtries=mtries, key=sub, scalars=sc)
         trees.append(tree)
         gains_tot = gains_tot + gains
-        pred = tree.leaf[nid]          # routing nid is bag-independent
+        pred = leaf_values(tree)[nid]  # routing nid is bag-independent
         oob_sum = oob_sum.at[:, k].add(jnp.where(oob, pred, 0.0))
     oob_cnt = oob_cnt + oob.astype(jnp.float32)
     return stack_trees(trees), oob_sum, oob_cnt, gains_tot
 
 
+@partial(jax.jit, static_argnames=("B",))
+def _predict_deep_forest(stacked, bins, B: int):
+    """``predict_forest`` for a stacked ``DeepTree`` forest."""
+    def step(acc, tree):
+        return acc + frontier.predict_deep(tree, bins, B), None
+
+    total, _ = jax.lax.scan(
+        step, jnp.zeros((bins.shape[0],), jnp.float32), stacked)
+    return total
+
+
+def _n_trees(forest) -> int:
+    return (forest if isinstance(forest, Tree)
+            else forest.top).feat.shape[0]
+
+
 class DRFModel(Model):
     algo = "drf"
 
-    def __init__(self, params, output, forest: Tree, bm: BinnedMatrix,
+    def __init__(self, params, output, forest, bm: BinnedMatrix,
                  ntrees: int):
         super().__init__(params, output)
-        self.forest = forest           # [T*K, D, Lmax]
+        # as grown: a complete ``Tree`` [T*K, D, Lmax], or a
+        # ``frontier.DeepTree`` where the depth passed the complete
+        # layout (checkpoint restarts append to this form)
+        self.grown = forest
+        self._complete = forest if isinstance(forest, Tree) else None
         self.bm = bm
         self.ntrees = ntrees
+
+    @property
+    def forest(self) -> Tree:
+        """The forest as the complete ``Tree`` its readers know. One
+        grown past that layout is converted if its realized depth fits
+        MAX_COMPLETE_DEPTH, and is a named error otherwise."""
+        if self._complete is None:
+            depth = int(self.output.get("depth_reached")
+                        or frontier.realized_depth(self.grown))
+            if depth > MAX_COMPLETE_DEPTH:
+                raise frontier.DeepForestError(
+                    f"this forest has splits down to level {depth - 1}; "
+                    f"the complete tree layout holds {MAX_COMPLETE_DEPTH} "
+                    "levels (predict, metrics, varimp and checkpoint "
+                    "restart work on it; TreeSHAP, leaf assignment, "
+                    "feature frequencies and MOJO/POJO export do not yet)")
+            self._complete = frontier.to_complete(
+                self.grown, bucket_depth(max(depth, 1)),
+                self.bm.nbins_total)
+        return self._complete
+
+    def _scoring_forest(self):
+        try:
+            return self.forest
+        except frontier.DeepForestError:
+            return self.grown
 
     def _mean_votes(self, bm: BinnedMatrix):
         """Per-class average tree output [N, K]."""
@@ -140,7 +195,10 @@ class DRFModel(Model):
                 if self.output["category"] != ModelCategory.REGRESSION else 1)
         if self.output["category"] == ModelCategory.BINOMIAL:
             K = 1
-        T = self.forest.feat.shape[0] // K
+        forest = self._scoring_forest()
+        T = _n_trees(forest) // K
+        score = predict_forest if isinstance(forest, Tree) \
+            else _predict_deep_forest
         # explicit reciprocal multiply, NOT division: XLA rewrites
         # x / <constant> into x * reciprocal inside a jitted program
         # but keeps true division in eager mode, a 1-ULP drift that
@@ -149,9 +207,9 @@ class DRFModel(Model):
         inv_t = jnp.float32(1.0 / T)
         outs = []
         for k in range(K):
-            f = Tree(*(a.reshape((T, K) + a.shape[1:])[:, k]
-                       for a in self.forest))
-            outs.append(predict_forest(f, bm.bins, B) * inv_t)
+            f = jax.tree.map(
+                lambda a: a.reshape((T, K) + a.shape[1:])[:, k], forest)
+            outs.append(score(f, bm.bins, B) * inv_t)
         return jnp.stack(outs, axis=1)
 
     def _probs(self, bm: BinnedMatrix):
@@ -301,13 +359,12 @@ class DRFEstimator(ModelBuilder):
     def _fit(self, frame: Frame, x: Sequence[str], y: Optional[str],
              job, validation_frame: Optional[Frame] = None) -> Model:
         p = self.params
-        mesh = get_mesh()
         category = infer_category(frame, y)
         ht = str(p.get("histogram_type", "auto")).lower()
         ht = {"auto": "quantiles", "quantilesglobal": "quantiles",
               "uniformadaptive": "uniform"}.get(ht, ht)
         rc = frame.col(y)
-        w, _, rows = self._training_weights(frame, y)
+        w, y_dev, rows = self._training_weights(frame, y)
         # checkpoint restart (SharedTree _checkpoint semantics): reuse
         # the donor's bin edges so its trees stay valid, continue the
         # PRNG key chain, and append trees up to the new ntrees
@@ -335,42 +392,24 @@ class DRFEstimator(ModelBuilder):
                                        self.CHECKPOINT_NON_MODIFIABLE)
 
         shared_bm = getattr(self, "_cv_shared_bm", None)
-        if ckpt is not None:
-            bm = rebin_for_scoring(ckpt.bm, frame)
-        elif shared_bm is not None:
-            bm = shared_bm
-        else:
-            bm, _ = self._binned(frame, x, y, nbins=p["nbins"],
-                                 nbins_cats=p["nbins_cats"],
-                                 histogram_type=ht)
+        with telemetry.span("drf.bin") as sp:
+            if ckpt is not None:
+                bm, how = rebin_for_scoring(ckpt.bm, frame), "rebin"
+            elif shared_bm is not None:
+                bm, how = shared_bm, "shared"
+            else:
+                bm, how = self._binned(frame, x, y, nbins=p["nbins"],
+                                       nbins_cats=p["nbins_cats"],
+                                       histogram_type=ht)
+            sp.annotate(cache=how)
 
+        # trees grow to the depth asked for: the complete layout and its
+        # kernels for the shallow levels, the frontier regime
+        # (models/frontier.py) below them. The program compiles at the
+        # depth BUCKET and masks splits beyond the actual depth —
+        # candidates of nearby depths share one compiled forest program
+        # (tree.py DEPTH_BUCKETS)
         depth = int(p["max_depth"])
-        # complete-tree layout: a level costs 2^d histogram node slots
-        # whether or not rows reach them, so cap depth by the DATA size
-        # too — the reference's depth-20 default on a 400-row pyunit
-        # frame would otherwise build 8K-node histograms of emptiness.
-        # log2(n)+3 leaves room for moderately unbalanced trees (a
-        # min_rows=1 spine deeper than that is approximated, as it
-        # already was by MAX_COMPLETE_DEPTH). Padded count keeps CV
-        # folds on one compiled shape.
-        # log2(n)+3 leaves room for moderately unbalanced trees; light
-        # CV fold fits (near-LOO sweeps, models discarded after their
-        # holdout scoring) drop to +1 — a complete tree of that depth
-        # already has a slot per row, and the slack quadruples forest
-        # HBM on pyunit-sized frames
-        slack = 1 if getattr(self, "_cv_light", False) else 3
-        data_cap = int(np.ceil(np.log2(max(frame.nrows_padded, 4)))) \
-            + slack
-        eff = min(depth, MAX_COMPLETE_DEPTH, data_cap)
-        if eff < depth:
-            log.warning("DRF max_depth=%d capped to %d (complete-tree TPU "
-                        "layout, %d rows)", depth, eff, frame.nrows)
-            depth = eff
-        # compile at the depth BUCKET (never past the caps) and mask
-        # splits beyond the actual depth — candidates of nearby depths
-        # share one compiled forest program (tree.py DEPTH_BUCKETS)
-        compile_depth = min(bucket_depth(depth), MAX_COMPLETE_DEPTH,
-                            data_cap)
         F = len(x)
         mtries = int(p["mtries"])
         if mtries == -1:
@@ -382,7 +421,7 @@ class DRFEstimator(ModelBuilder):
         w_scale = rows.w_scale
 
         tp = TreeParams(
-            max_depth=compile_depth,
+            max_depth=bucket_depth(depth),
             min_rows=float(p["min_rows"]) / w_scale,
             learn_rate=1.0, reg_lambda=0.0,
             min_split_improvement=float(p["min_split_improvement"])
@@ -391,97 +430,104 @@ class DRFEstimator(ModelBuilder):
             nbins_total=bm.nbins_total,
             cat_feats=tuple(bool(v) for v in bm.is_cat),
             pallas=pallas_ops.resolve_tree_mode())
-
-        # target matrix ys [Npad, K]: indicators for classification
+        tp = dataclasses.replace(tp, frontier_from=frontier_start(tp, F))
         N = bm.bins.shape[0]
-        if category == ModelCategory.REGRESSION:
-            K = 1
-            yv = np.nan_to_num(rc.to_numpy()).astype(np.float32)
-            ys = np.pad(yv, (0, N - frame.nrows))[:, None]
-            y_int = None
-        else:
-            codes = np.nan_to_num(rc.to_numpy()).astype(np.int32)  # host
-            codes = np.pad(codes, (0, N - frame.nrows))
-            K = 1 if category == ModelCategory.BINOMIAL else rc.cardinality
-            if K == 1:
-                ys = (codes == 1).astype(np.float32)[:, None]
-            else:
-                ys = (codes[:, None] == np.arange(K)[None, :]).astype(np.float32)
-            y_int = jax.device_put(codes, row_sharding(mesh))
-        ys = jax.device_put(ys, row_sharding(mesh))
+        n_complete = frontier.complete_levels(N, tp.max_depth,
+                                              tp.frontier_from)
+        kl = kernel_levels(tp, F)[:n_complete]
+        paths = {"levels_kernel": sum(kl),
+                 "levels_xla": n_complete - sum(kl),
+                 "levels_frontier": tp.max_depth - n_complete}
 
-        seed = int(p["seed"]) if int(p["seed"]) >= 0 else 0xD2F
-        key = jax.random.PRNGKey(seed)
-        ntrees = int(p["ntrees"])
-        prior_T = 0
-        if ckpt is not None:
-            prior_T = ckpt.forest.feat.shape[0] // max(K, 1)
-            if ntrees <= prior_T:
-                raise checkpoint_error(
-                    "drf", "ntrees",
-                    f"If checkpoint is provided, ntrees ({ntrees}) must "
-                    f"exceed the checkpoint model's tree count "
-                    f"({prior_T})")
-            # _bag_scan's key carry is split once per tree, so prior_T
-            # host-side splits reproduce the evolved chain exactly: the
-            # appended trees are bit-equal to trees prior_T.. of a
-            # single longer run with the same seed
-            for _ in range(prior_T):
-                key, _sub = jax.random.split(key)
-            ntrees = ntrees - prior_T
+        # target matrix ys [Npad, K] from the device response (weighted-
+        # out rows read 0): the values, or indicators for classification
+        with telemetry.span("drf.init", on_device=True, host_bytes=0,
+                            rows_out=rows.rows_out):
+            if category == ModelCategory.REGRESSION \
+                    or category == ModelCategory.BINOMIAL:
+                K = 1
+                ys = y_dev.astype(jnp.float32)[:, None]
+            else:
+                K = rc.cardinality
+                ys = (y_dev[:, None] == jnp.arange(K, dtype=y_dev.dtype)
+                      [None, :]).astype(jnp.float32)
+
+            seed = int(p["seed"]) if int(p["seed"]) >= 0 else 0xD2F
+            key = jax.random.PRNGKey(seed)
+            ntrees = int(p["ntrees"])
+            prior_T = 0
+            if ckpt is not None:
+                prior_T = _n_trees(ckpt.grown) // max(K, 1)
+                if ntrees <= prior_T:
+                    raise checkpoint_error(
+                        "drf", "ntrees",
+                        f"If checkpoint is provided, ntrees ({ntrees}) "
+                        f"must exceed the checkpoint model's tree count "
+                        f"({prior_T})")
+                # _bag_scan's key carry is split once per tree, so
+                # prior_T host-side splits reproduce the evolved chain
+                # exactly: the appended trees are bit-equal to trees
+                # prior_T.. of a single longer run with the same seed
+                for _ in range(prior_T):
+                    key, _sub = jax.random.split(key)
+                ntrees = ntrees - prior_T
         output = {"category": category, "response": y, "names": list(x),
                   "nclasses": rc.cardinality if rc.is_categorical else 1,
                   "domain": rc.domain}
-        # max_runtime_secs (Model.Parameters): graceful stop at a
-        # 25-tree chunk boundary, keeping the forest built so far —
-        # without a cap the forest trains as ONE fused scan (the LOO-CV
-        # fast path needs exactly one dispatch per fold model)
+        # max_runtime_secs (Model.Parameters): graceful stop at a chunk
+        # boundary, keeping the forest built so far — without a cap the
+        # forest trains as ONE fused scan (the LOO-CV fast path needs
+        # exactly one dispatch per fold model)
         _cap = float(p.get("max_runtime_secs") or 0.0)
-        if _cap > 0:
-            _deadline = time.time() + _cap
-            # chunk shrinks with per-tree cost so the deadline can bind
-            _chunk = trees_per_chunk(tp, bm.bins.shape[0], capped=True)
-            chunks, osum_acc, ocnt_acc, gains_acc = [], None, None, None
-            done = 0
-            while done < ntrees:
-                kk = min(_chunk, ntrees - done)
+        _deadline = time.time() + _cap
+        # the chunk shrinks with per-tree cost so the deadline can bind
+        _chunk = trees_per_chunk(tp, N, capped=True) if _cap > 0 else ntrees
+        light = getattr(self, "_cv_light", False)
+        chunks, oob_sum, oob_cnt, gains_dev = [], 0.0, 0.0, 0.0
+        depth_reached = capped = 0
+        done = 0
+        while done < ntrees:
+            kk = min(_chunk, ntrees - done)
+            with telemetry.span("drf.chunk", trees=kk, **paths) as sp:
                 tr_c, osum, ocnt, g_c, key = _bag_scan(
                     bm.bins, bm.nbins, ys, w, key, jnp.int32(depth),
                     tp=tp, sample_rate=float(p["sample_rate"]),
                     mtries=mtries, n_class=K, ntrees=kk)
-                chunks.append(tr_c)
-                osum_acc = osum if osum_acc is None else osum_acc + osum
-                ocnt_acc = ocnt if ocnt_acc is None else ocnt_acc + ocnt
-                gains_acc = g_c if gains_acc is None else gains_acc + g_c
-                done += kk
-                job.update(kk / ntrees, f"tree {done}/{ntrees}")
-                if time.time() > _deadline and done < ntrees:
-                    log.info("max_runtime_secs: DRF stopping at %d/%d "
-                             "trees", done, ntrees)
-                    break
-            forest = (chunks[0] if len(chunks) == 1 else
-                      Tree(*(jnp.concatenate([getattr(c, f)
-                                              for c in chunks])
-                             for f in Tree._fields)))
-            oob_sum, oob_cnt, gains_dev = osum_acc, ocnt_acc, gains_acc
-            ntrees = done
-        else:
-            forest, oob_sum, oob_cnt, gains_dev, _ = _bag_scan(
-                bm.bins, bm.nbins, ys, w, key, jnp.int32(depth), tp=tp,
-                sample_rate=float(p["sample_rate"]), mtries=mtries,
-                n_class=K, ntrees=ntrees)
-            job.update(1.0, f"{ntrees} trees")
+                if not light:
+                    # one fetch a chunk: what the trees came to
+                    got = _fetch_np(frontier.forest_facts(tr_c))
+                    depth_reached = max(depth_reached, int(got["depth"]))
+                    capped += int(got["capped"])
+                    sp.annotate(depth_reached=int(got["depth"]),
+                                leaves=int(got["leaves"]),
+                                frontier_nodes_max=int(got["nodes_max"]))
+            telemetry.counter("train_iterations_total", algo="drf").inc(kk)
+            chunks.append(tr_c)
+            oob_sum, oob_cnt = oob_sum + osum, oob_cnt + ocnt
+            gains_dev = gains_dev + g_c
+            done += kk
+            job.update(kk / ntrees, f"tree {done}/{ntrees}")
+            if _cap > 0 and time.time() > _deadline and done < ntrees:
+                log.info("max_runtime_secs: DRF stopping at %d/%d trees",
+                         done, ntrees)
+                break
+        forest = concat_forests(chunks)
+        ntrees = done
+        # stays 0: the frontier's capacity comes from the rows, so no
+        # tree is stopped by the layout (frontier.frontier_capacity)
+        telemetry.counter("drf_depth_capped_total").inc(capped)
         if ckpt is not None:
-            if ckpt.forest.feat.shape[1:] != forest.feat.shape[1:]:
+            shapes = [tuple(a.shape[1:]) for a in jax.tree.leaves(forest)]
+            donor = [tuple(a.shape[1:])
+                     for a in jax.tree.leaves(ckpt.grown)]
+            if type(ckpt.grown) is not type(forest) or donor != shapes:
                 raise checkpoint_error(
                     "drf", "training_frame",
                     "checkpoint restart requires a compatible training "
-                    "frame (donor tree layout "
-                    f"{tuple(ckpt.forest.feat.shape[1:])} vs "
-                    f"{tuple(forest.feat.shape[1:])})")
-            forest = Tree(*(jnp.concatenate([getattr(ckpt.forest, f),
-                                             getattr(forest, f)])
-                            for f in Tree._fields))
+                    f"frame (donor tree layout {donor[0]} vs {shapes[0]})")
+            forest = concat_forests([ckpt.grown, forest])
+            depth_reached = max(depth_reached,
+                                int(ckpt.output.get("depth_reached") or 0))
             prior_oob = getattr(ckpt, "_oob", None)
             if prior_oob is not None and \
                     tuple(prior_oob[0].shape) == tuple(oob_sum.shape):
@@ -495,7 +541,7 @@ class DRFEstimator(ModelBuilder):
                             "reflect only the appended trees")
             ntrees = ntrees + prior_T
         model = DRFModel(p, output, forest, bm, ntrees)
-        if getattr(self, "_cv_light", False):
+        if light:
             # near-LOO CV fold fit (ml/cv.py): skip OOB metrics / varimp
             # / calibration — hundreds of folds of those frills (several
             # blocking device syncs each) were the pyunit_cv_carsRF
@@ -506,30 +552,32 @@ class DRFEstimator(ModelBuilder):
             model.output["default_threshold"] = 0.5
             model.output["varimp"] = []
             return model
+        model.output["depth_reached"] = depth_reached
         gains_total = np.asarray(gains_dev)
-        # host-lowered OOB accumulators ride the model so a checkpoint=
-        # restart can CONTINUE them (pickled device-independent)
-        model._oob = (np.asarray(oob_sum), np.asarray(oob_cnt))
+        with telemetry.span("drf.oob"):
+            # host-lowered OOB accumulators ride the model so a
+            # checkpoint= restart can CONTINUE them (pickled
+            # device-independent)
+            model._oob = (np.asarray(oob_sum), np.asarray(oob_cnt))
+            # rows never out-of-bag drop out via weight
+            w_oob = w * (oob_cnt > 0).astype(jnp.float32)
+            mean_oob = oob_sum / jnp.maximum(oob_cnt[:, None], 1.0)
 
-        # OOB training metrics (rows never out-of-bag drop out via weight)
-        w_oob = w * (oob_cnt > 0).astype(jnp.float32)
-        mean_oob = oob_sum / jnp.maximum(oob_cnt[:, None], 1.0)
-        if category == ModelCategory.REGRESSION:
-            yv = jnp.asarray(np.pad(np.nan_to_num(rc.to_numpy()).astype(np.float32),
-                                    (0, N - frame.nrows)))
-            model.training_metrics = mm.regression_metrics(
-                mean_oob[:, 0], yv, w_oob)
-        elif category == ModelCategory.BINOMIAL:
-            p1 = jnp.clip(mean_oob[:, 0], 0.0, 1.0)
-            model.training_metrics = mm.binomial_metrics(
-                p1, (y_int == 1).astype(jnp.float32), w_oob)
-            model.output["default_threshold"] = \
-                model.training_metrics["max_f1_threshold"]
-        else:
-            s = jnp.sum(mean_oob, axis=1, keepdims=True)
-            probs = jnp.clip(mean_oob, 0.0, 1.0) / jnp.maximum(s, 1e-12)
-            model.training_metrics = mm.multinomial_metrics(
-                probs, y_int, w_oob, domain=rc.domain)
+        with telemetry.span("drf.metrics"):
+            if category == ModelCategory.REGRESSION:
+                model.training_metrics = mm.regression_metrics(
+                    mean_oob[:, 0], ys[:, 0], w_oob)
+            elif category == ModelCategory.BINOMIAL:
+                p1 = jnp.clip(mean_oob[:, 0], 0.0, 1.0)
+                model.training_metrics = mm.binomial_metrics(
+                    p1, ys[:, 0], w_oob)
+                model.output["default_threshold"] = \
+                    model.training_metrics["max_f1_threshold"]
+            else:
+                s = jnp.sum(mean_oob, axis=1, keepdims=True)
+                probs = jnp.clip(mean_oob, 0.0, 1.0) / jnp.maximum(s, 1e-12)
+                model.training_metrics = mm.multinomial_metrics(
+                    probs, y_dev, w_oob, domain=rc.domain)
 
         vi = gains_total
         order = np.argsort(-vi)

@@ -55,10 +55,10 @@ def _grow_ext_tree(X, lo, hi, w, key, *, depth: int, ext: int):
     for d in range(depth):
         L = 2 ** d
         key, kn, km, kb = jax.random.split(key, 4)
-        from h2o3_tpu.models.tree import _mtries_mask
+        from h2o3_tpu.models.tree import _level_mtries_mask
         Wn = jax.random.normal(kn, (L, F))
         # keep exactly ext+1 random components per node
-        Wn = jnp.where(_mtries_mask(km, L, F, k), Wn, 0.0)
+        Wn = jnp.where(_level_mtries_mask(km, L, F, k), Wn, 0.0)
         # offset b = w·p for a random point p in the value box
         pu = jax.random.uniform(kb, (L, F))
         pnt = lo[None, :] + pu * (hi - lo)[None, :]

@@ -142,6 +142,11 @@ class TreeParams:
                                      # mid-process flip recompiles
                                      # instead of reusing a stale
                                      # program (ops/pallas.resolve_tree_mode)
+    frontier_from: int = 0           # first level grown in the frontier
+                                     # regime (models/frontier.py) where
+                                     # the depth passes it; 0 = complete
+                                     # layout all the way (GBM: its depths
+                                     # fit the layout, ROADMAP R4)
 
     @property
     def has_cats(self) -> bool:
@@ -161,6 +166,26 @@ def kernel_levels(params: TreeParams, n_features: int) -> tuple:
     from h2o3_tpu.ops.pallas import tile_rows
     return tuple(tile_rows(n_features, params.nbins_total, 2 ** d) > 0
                  for d in range(params.max_depth))
+
+
+# Where no kernel says otherwise, the first level grown in the frontier
+# regime by the fits that ask for it (DRF): below it a complete level's
+# XLA histogram is [2^d, F·B, 3] one-hot blocks that stop loading at
+# scale (PERF.md §4), and from level 9 on the airlines widths fit no
+# kernel tile either.
+FRONTIER_FROM = 9
+
+
+def frontier_start(params: TreeParams, n_features: int) -> int:
+    """The level from which a fit that grows past the complete layout
+    leaves it: the first level the kernels do not take, FRONTIER_FROM at
+    most (and where no kernel runs)."""
+    fits = kernel_levels(dataclasses.replace(params,
+                                             max_depth=FRONTIER_FROM),
+                         n_features)
+    if not any(fits):
+        return FRONTIER_FROM
+    return next((d for d, ok in enumerate(fits) if not ok), FRONTIER_FROM)
 
 
 def row_feature_values(bins, f_r):
@@ -183,12 +208,13 @@ def _best_splits(hist, nb, col_mask, params: TreeParams,
     stay bit-exact by construction. See ops.split_scan.best_splits for
     the full contract."""
     sc = scalars if scalars is not None else scalars_of(params)
+    cats = params.has_cats and is_cat is not None
     return best_splits(
         hist, nb, col_mask, min_rows=sc.min_rows,
-        reg_lambda=sc.reg_lambda,
-        is_cat=is_cat if (params.has_cats and is_cat is not None)
-        else None,
-        constraints=constraints, lo=lo, hi=hi)
+        reg_lambda=sc.reg_lambda, is_cat=is_cat if cats else None,
+        constraints=constraints, lo=lo, hi=hi,
+        cat_idx=tuple(i for i, c in enumerate(params.cat_feats) if c)
+        if cats else None)
 
 
 def _pack_leftmask(leftmask, W: int):
@@ -263,11 +289,26 @@ def _level_goleft(feat_d, thresh_d, nal_d, isp_d, cat_d, lw_d, nid, bins,
     return 2 * nid + jnp.where(goleft, 0, 1)
 
 
-def _mtries_mask(key, L: int, F: int, mtries: int):
+def _level_mtries_mask(key, L: int, F: int, mtries: int):
+    """Exactly-mtries-per-node column mask [L, F] from ONE draw a level
+    of L nodes — the uplift forest's and the extended isolation forest's
+    (models/uplift.py, models/extisofor.py): their trees keep the
+    complete layout and nothing replays them node by node."""
+    u = jax.random.uniform(key, (L, F))
+    rank = jnp.argsort(jnp.argsort(u, axis=1), axis=1)
+    return rank < mtries
+
+
+def _mtries_mask(key, heap_ids, F: int, mtries: int):
     """Exactly-mtries-per-node column mask [L, F] — the reference DRF
     per-split column subsample (hex/tree/DTree.java UndecidedNode scoreCols,
-    mtries semantics of hex/tree/drf/DRF.java:30)."""
-    u = jax.random.uniform(key, (L, F))
+    mtries semantics of hex/tree/drf/DRF.java:30). A node's draw is a
+    function of (tree key, its heap id 2^level + path) alone: the mtries
+    columns that F uniforms from ``fold_in(key, heap id)`` rank lowest —
+    whatever layout holds the node, and replayable node by node
+    (benchmark/references/drf.py)."""
+    u = jax.vmap(lambda h: jax.random.uniform(
+        jax.random.fold_in(key, h), (F,)))(heap_ids)
     rank = jnp.argsort(jnp.argsort(u, axis=1), axis=1)
     return rank < mtries
 
@@ -277,7 +318,8 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
               interaction_sets=None, scalars=None):
     """Grow one tree; returns (Tree, final_leaf_id_per_row).
 
-    bins [Npad, F] int32 row-sharded; w zero on padding rows; col_mask [F]
+    bins [Npad, F] int32 row-sharded; w zero on padding rows (``h`` None
+    is a hessian of 1, as a forest of mean-valued leaves has it); col_mask [F]
     bool (per-tree column sampling, reference col_sample_rate_per_tree).
     mtries > 0 additionally samples exactly-mtries columns per NODE per
     level (DRF semantics) using `key`. ``constraints`` [F] in {-1,0,+1}
@@ -289,13 +331,25 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
     once a node splits on feature f, its subtree may only use features
     sharing an interaction set with every feature on the path — tracked
     as a per-node allowed mask.
+
+    Where ``params.frontier_from`` is set and the depth passes it, the
+    levels from there on are grown in the frontier regime
+    (models/frontier.py) and the result is ``(DeepTree, ref, gains)``:
+    ``ref`` indexes the node tables' flat values as ``nid`` indexes
+    ``Tree.leaf`` (``leaf_values``).
     """
-    D = params.max_depth
+    from h2o3_tpu.models import frontier
+    unit_h = h is None
+    if unit_h:
+        h = jnp.ones_like(g)
     sc = scalars if scalars is not None else scalars_of(params)
     B = params.nbins_total
     F = bins.shape[1]
-    Lmax = 2 ** (D - 1) if D > 0 else 1
     N = bins.shape[0]
+    # the complete layout holds levels 0..D-1 of the whole tree, or the
+    # levels above the frontier
+    D = frontier.complete_levels(N, params.max_depth, params.frontier_from)
+    Lmax = 2 ** (D - 1) if D > 0 else 1
     nid = jnp.zeros((N,), jnp.int32)
 
     feats = jnp.zeros((D, Lmax), jnp.int32)
@@ -329,12 +383,17 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
         from h2o3_tpu.ops.pallas.treekernel import fused_level
         stats3 = jnp.stack([w, w * g, w * h]).astype(jnp.float32)  # [3, N]
     prev_hist = None
+    # a node whose parent did not split is a leaf, not a second chance
+    # with a fresh column draw: only mtries can tell the difference (a
+    # node that found no split finds none in the same columns below)
+    alive = jnp.ones((1,), bool)
     for d in range(D):
         L = 2 ** d
         cm = col_mask
         if mtries > 0 and mtries < F:
-            key, sub = jax.random.split(key)
-            cm = _mtries_mask(sub, L, F, mtries) & col_mask[None, :]
+            heap = 2 ** d + jnp.arange(L, dtype=jnp.int32)
+            cm = _mtries_mask(key, heap, F, mtries) & col_mask[None, :] \
+                & alive[:, None]
         if interaction_sets is not None:
             cm = (cm if cm.ndim == 2 else cm[None, :]) & allowed
         use_fused = fused[d]
@@ -386,6 +445,7 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
                     split = split & (jnp.int32(d) < sc.depth_limit)
             nid_next = None
         prev_hist = hist
+        alive = jnp.repeat(split, 2)
         feats = feats.at[d, :L].set(jnp.where(split, bf, 0))
         threshs = threshs.at[d, :L].set(jnp.where(split, bt, B))
         na_lefts = na_lefts.at[d, :L].set(jnp.where(split, bnal, False))
@@ -440,6 +500,21 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
                                     is_splits[d], cat_splits[d],
                                     left_words[d], nid, bins, B, d)
 
+    if D < params.max_depth:
+        if constraints is not None or interaction_sets is not None:
+            raise NotImplementedError(
+                "monotone and interaction constraints stop at the "
+                "complete layout (TreeParams.frontier_from)")
+        empty = jnp.zeros((2 ** D,), jnp.float32)
+        top = Tree(feats, threshs, na_lefts, is_splits, empty, empty,
+                   cat_splits, left_words)
+        deep, ref, gains, capped = frontier.grow_frontier(
+            bins, nb, nid, (w, w * g) if unit_h else (w, w * g, w * h),
+            alive, key, col_mask, params=params, K=D, sc=sc, mtries=mtries,
+            is_cat=is_cat)
+        return (frontier.DeepTree(top, deep, capped), ref,
+                gain_by_feat + gains)
+
     # leaf Newton values from final assignment (GammaPass analogue)
     nleaf = 2 ** D
     with jax.named_scope("tree.leaf_sums"):
@@ -461,21 +536,28 @@ def predict_tree(tree: Tree, bins, B: int):
     return tree.leaf[_route(tree, bins, B)]
 
 
-def stack_trees(trees) -> Tree:
-    """Stack per-iteration Trees into [T, ...] arrays for scan-predict."""
-    return Tree(*(jnp.stack([getattr(t, f) for t in trees])
-                  for f in Tree._fields))
+def leaf_values(tree):
+    """The flat per-node values that grow_tree's second result indexes:
+    a complete tree's leaves, or a deep tree's node tables."""
+    return tree.leaf if isinstance(tree, Tree) \
+        else tree.deep.value.reshape(-1)
 
 
-def concat_forests(chunks) -> Tree:
-    """Concatenate [T_i, ...] forest chunks along the tree axis — the
-    chunked-scan and model-batched training paths both assemble their
-    final forest through this."""
+def stack_trees(trees):
+    """Stack per-iteration trees (all ``Tree`` or all ``DeepTree``)
+    into [T, ...] arrays for scan-predict."""
+    return jax.tree.map(lambda *a: jnp.stack(a), *trees)
+
+
+def concat_forests(chunks):
+    """Concatenate [T_i, ...] forest chunks (all ``Tree`` or all
+    ``DeepTree``) along the tree axis — the chunked-scan and
+    model-batched training paths both assemble their final forest
+    through this."""
     chunks = list(chunks)
     if len(chunks) == 1:
         return chunks[0]
-    return Tree(*(jnp.concatenate([getattr(c, f) for c in chunks])
-                  for f in Tree._fields))
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *chunks)
 
 
 def unstack_model_trees(batched: Tree, m: int, keep=None) -> Tree:

@@ -28,7 +28,7 @@ from h2o3_tpu.frame.binning import BinnedMatrix, bin_frame, rebin_for_scoring
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models import metrics as mm
 from h2o3_tpu.models.model import Model, ModelBuilder, ModelCategory, adapt_domain
-from h2o3_tpu.models.tree import (Tree, _mtries_mask, predict_forest,
+from h2o3_tpu.models.tree import (Tree, _level_mtries_mask, predict_forest,
                                   zero_catsplit,
                                   row_feature_values, stack_trees)
 from h2o3_tpu.ops.histogram import histogram
@@ -134,7 +134,7 @@ def _grow_uplift_tree(bins, nb, w, y, treat, key, *, depth: int, B: int,
         ht = histogram(bins, nid, wt, y, ones, n_nodes=L, n_bins=B, mesh=mesh)
         hc = histogram(bins, nid, wc, y, ones, n_nodes=L, n_bins=B, mesh=mesh)
         key, sub = jax.random.split(key)
-        cm = (_mtries_mask(sub, L, F, mtries) if 0 < mtries < F
+        cm = (_level_mtries_mask(sub, L, F, mtries) if 0 < mtries < F
               else jnp.ones((1, F), bool))
         bg, bf, bt, bnal = _best_uplift_splits(ht, hc, nb, cm, min_rows,
                                                metric)

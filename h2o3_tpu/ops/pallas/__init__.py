@@ -129,7 +129,7 @@ def _up(n: int, m: int) -> int:
 def tile_rows(n_features: int, n_bins: int, n_nodes: int,
               budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
     """Rows per tile at which BOTH level kernels (histogram, partition)
-    fit ``budget_bytes`` of scoped VMEM, as a multiple of 128 up to
+    fit ``budget_bytes`` of scoped VMEM, as a power of two from 128 to
     2048 — or 0 when the level cannot run in the kernels at all (bin
     ids the bf16 expansion cannot hold exactly, or a histogram
     accumulator that outgrows VMEM before a single tile is added: deep
@@ -159,4 +159,9 @@ def tile_rows(n_features: int, n_bins: int, n_nodes: int,
     part_fixed = 2 * _up(n_bins - 1, 8) * 4 * _up(n_nodes, 128)
     rows = min((int(budget_bytes) - hist_fixed) // hist_row,
                (int(budget_bytes) - part_fixed) // part_row, 2048)
-    return max(0, (rows // 128) * 128)
+    # a power of two divides a frame's padded row count (a multiple of a
+    # large power of two), so the kernels' operands need no padded copy:
+    # at 48M rows a level with a 1,536- or 384-row tile made its own
+    # copies of the bins (twice), the node ids and the statistics, 2 GB
+    # a level (PERF.md §6, PR 35)
+    return 1 << (rows.bit_length() - 1) if rows >= 128 else 0

@@ -14,11 +14,13 @@ tests/test_tree_kernels.py) and can never drift apart.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
 def best_splits(hist, nb, col_mask, *, min_rows, reg_lambda,
-                is_cat=None, constraints=None, lo=None, hi=None):
+                is_cat=None, constraints=None, lo=None, hi=None,
+                cat_idx=None):
     """Vectorized DTree.findBestSplitPoint over all nodes of a level.
 
     hist: [L, F, B, 3] of {w, g, h}; col_mask [F] (per-tree sampling) or
@@ -30,7 +32,9 @@ def best_splits(hist, nb, col_mask, *, min_rows, reg_lambda,
     hex/tree/Constraints).
 
     Categorical features (``is_cat`` [F] bool; pass None for an
-    all-numeric scan): bins are re-ordered PER NODE by their Newton value
+    all-numeric scan; ``cat_idx``, where the caller knows the schema
+    statically, is the tuple of their indices and confines the
+    re-ordering work to them): bins are re-ordered PER NODE by their Newton value
     -g/(h+λ) and the threshold scan runs over that order, so the best
     "prefix" is the best category SUBSET — the static-shape formulation
     of the reference's bitset splits (hex/tree/DTree.java:619-697
@@ -44,22 +48,59 @@ def best_splits(hist, nb, col_mask, *, min_rows, reg_lambda,
     wv = w[:, :, : B - 1]
     gv = g[:, :, : B - 1]
     hv = h[:, :, : B - 1]
-    order = None
+    rank = None
     if is_cat is not None:
         # per-(node, feature) bin order: Newton value ascending for cats,
         # natural bin order for numerics (identity keeps the exact
-        # numeric semantics). Empty bins key to 0 and sort mid-sequence;
-        # their left/right membership carries no weight either way.
-        # empty bins key to +inf so they sort AFTER every populated bin:
-        # the t <= nb-2 threshold-validity mask then stays correct in
-        # sorted space (populated bins occupy a prefix of it)
-        val = jnp.where(wv > 0, -gv / (hv + lam + 1e-10), jnp.inf)
-        pos = jnp.arange(B - 1, dtype=jnp.float32)
-        key = jnp.where(is_cat[None, :, None], val, pos[None, None, :])
-        order = jnp.argsort(key, axis=2, stable=True)
-        wv = jnp.take_along_axis(wv, order, axis=2)
-        gv = jnp.take_along_axis(gv, order, axis=2)
-        hv = jnp.take_along_axis(hv, order, axis=2)
+        # numeric semantics). Empty bins key to +inf so they sort AFTER
+        # every populated bin: the t <= nb-2 threshold-validity mask then
+        # stays correct in sorted space (populated bins occupy a prefix
+        # of it).
+        # The order is a STABLE ascending sort of the keys, computed
+        # without a sort: bin i's position is the number of bins that
+        # precede it (smaller key, or equal key and smaller id), and the
+        # re-ordered statistics are one-hot sums over those positions —
+        # compares and adds the VPU fuses, where a sort along a 125-wide
+        # axis and the gathers after it cost 58 us a node on a v5e
+        # (PERF.md §6, PR 35). Keys compare in the sort's total order
+        # (-0.0 before +0.0), as integers.
+        pos = jnp.arange(B - 1, dtype=jnp.int32)
+
+        def position(wc, gc, hc):
+            """[L, C, B-1] statistics of categorical columns → each
+            bin's position in its node's order."""
+            val = jnp.where(wc > 0, -gc / (hc + lam + 1e-10), jnp.inf)
+            bits = jax.lax.bitcast_convert_type(val, jnp.int32)
+            ordered = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+            ki, kj = ordered[:, :, :, None], ordered[:, :, None, :]
+            before = (kj < ki) | ((kj == ki) & (pos[None, :] < pos[:, None]))
+            return jnp.sum(before, axis=3, dtype=jnp.int32)
+
+        def reorder(x, r):
+            at = r[:, :, :, None] == pos[None, None, None, :]
+            return jnp.sum(jnp.where(at, x[:, :, :, None], 0.0), axis=2)
+
+        if cat_idx is None:
+            rank = jnp.where(is_cat[None, :, None], position(wv, gv, hv),
+                             pos[None, None, :])             # [L, F, B-1]
+            wv, gv, hv = (reorder(x, rank) for x in (wv, gv, hv))
+        elif cat_idx:
+            F = hist.shape[1]
+
+            def take(x):
+                return jnp.stack([x[:, i] for i in cat_idx], axis=1)
+
+            def put(x, xc):
+                cols = [x[:, f] for f in range(F)]
+                for j, i in enumerate(cat_idx):
+                    cols[i] = xc[:, j]
+                return jnp.stack(cols, axis=1)
+
+            rank_c = position(take(wv), take(gv), take(hv))
+            rank = put(jnp.broadcast_to(pos[None, None, :], wv.shape),
+                       rank_c)
+            wv, gv, hv = (put(x, reorder(take(x), rank_c))
+                          for x in (wv, gv, hv))
     # cumulative over (possibly re-ordered) value bins; NA bin is B-1
     cw = jnp.cumsum(wv, axis=2)
     cg = jnp.cumsum(gv, axis=2)
@@ -116,12 +157,12 @@ def best_splits(hist, nb, col_mask, *, min_rows, reg_lambda,
     rvals = jnp.stack([rv_nar, rv_nal], axis=-1).reshape(L, -1)
     best_lv = jnp.take_along_axis(lvals, best[:, None], axis=1)[:, 0]
     best_rv = jnp.take_along_axis(rvals, best[:, None], axis=1)[:, 0]
-    if order is not None:
+    if rank is not None:
         # original-bin-id membership of the winning prefix: position of
         # bin b within the winning feature's order <= t  ⇔  b goes left
-        order_win = jnp.take_along_axis(
-            order, best_f[:, None, None], axis=1)[:, 0]     # [L, B-1]
-        ranks = jnp.argsort(order_win, axis=1)              # inverse perm
+        F = hist.shape[1]
+        win = best_f[:, None] == jnp.arange(F, dtype=jnp.int32)[None, :]
+        ranks = jnp.sum(jnp.where(win[:, :, None], rank, 0), axis=1)
         leftmask = ranks <= best_t[:, None]
     else:
         leftmask = (jnp.arange(B - 1, dtype=jnp.int32)[None, :]
