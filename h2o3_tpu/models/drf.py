@@ -429,15 +429,23 @@ class DRFEstimator(ModelBuilder):
             col_sample_rate=float(p["col_sample_rate_per_tree"]),
             nbins_total=bm.nbins_total,
             cat_feats=tuple(bool(v) for v in bm.is_cat),
-            pallas=pallas_ops.resolve_tree_mode())
+            pallas=pallas_ops.resolve_tree_mode(),
+            # a class indicator under weights of 0 or 1: a row's (w, w·y)
+            # are 0 or ±1, whatever the bag keeps of it
+            whole_stats=rows.w_whole
+            and category != ModelCategory.REGRESSION)
         tp = dataclasses.replace(tp, frontier_from=frontier_start(tp, F))
         N = bm.bins.shape[0]
         n_complete = frontier.complete_levels(N, tp.max_depth,
                                               tp.frontier_from)
         kl = kernel_levels(tp, F)[:n_complete]
+        # a forest's trees have a hessian of 1: two statistics a row
+        tile, pieces = frontier.hist_plan(tp, N, F, 2)
         paths = {"levels_kernel": sum(kl),
                  "levels_xla": n_complete - sum(kl),
-                 "levels_frontier": tp.max_depth - n_complete}
+                 "levels_frontier": tp.max_depth - n_complete,
+                 "frontier_hist": "kernel" if tile else "xla",
+                 "hist_operand_rows": 2 * pieces}
 
         # target matrix ys [Npad, K] from the device response (weighted-
         # out rows read 0): the values, or indicators for classification

@@ -103,11 +103,34 @@ def _geometry(n_rows: int, lcap: int):
     return min(CHUNK_ROWS, n_rows), lb, sb
 
 
+def hist_plan(params, n_rows: int, n_features: int, n_stats: int):
+    """(tile, pieces) of a frontier level's histogram pass: the row tile
+    of the Pallas kernel ``tree_frontier_hist`` — 0 where the fit asked
+    for no kernels or no tile fits, and the XLA chunk product runs — and
+    the bfloat16 pieces a statistic enters the product in (1 where
+    ``params.whole_stats``, else 3). ``n_stats * pieces`` is the operand's
+    rows a node. The one place that decides it: ``grow_frontier`` follows
+    it and the forest fit reports it on ``drf.chunk``."""
+    pieces = 1 if params.whole_stats else 3
+    if params.pallas not in ("native", "interpret"):
+        return 0, pieces
+    from h2o3_tpu.ops.pallas import frontier_tile_rows
+    B = params.nbins_total
+    lb = _geometry(n_rows, frontier_capacity(n_rows, params.max_depth))[1]
+    n_words = -(-n_features // (32 // _bin_bits(B)))
+    return frontier_tile_rows(n_features, B, piece_rows(lb, n_stats, pieces),
+                              1 + n_words + n_stats), pieces
+
+
+def _bin_bits(n_bins: int) -> int:
+    return 8 if n_bins <= 256 else 16
+
+
 def pack_bins(bins, n_bins: int):
     """``bins`` [N, F] → a tuple of [N] uint32 words holding the row's
     bin ids, 8 bits each (16 where a bin id passes 255): what a row
     carries through the level sorts."""
-    bits = 8 if n_bins <= 256 else 16
+    bits = _bin_bits(n_bins)
     per = 32 // bits
     F = bins.shape[1]
     words = []
@@ -127,46 +150,21 @@ def _unpack(words, f: int, bits: int):
             & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
 
 
-def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
-                  K: int, sc, mtries: int, is_cat):
-    """Levels K..D-1 of one tree from the complete phase's level-K state
-    (``nid`` in [0, 2^K); ``alive`` [2^K]: the nodes whose parent split;
-    ``stats``: the rows' (w, w·g, w·h), or (w, w·g) where h is 1 and the
-    third is the first).
-
-    Returns ``(DeepLevels, ref, gains, capped)``: ``ref`` [N] the flat
-    index (level - K) * Lcap + slot of every row's final node; ``capped``
-    whether a split was refused for want of node slots (never, by
-    ``frontier_capacity``; counted all the same)."""
-    from h2o3_tpu.models import tree as T
-    D, B = params.max_depth, params.nbins_total
-    N, F = bins.shape
+def chunk_product_hist(blk_start, s, fid, words, stats, *, lb: int, sb: int,
+                       chunk: int, n_features: int, n_bins: int, bits: int,
+                       n_pieces: int):
+    """Super-batch ``s`` of a frontier level as float32 sums
+    [sb·lb, F, B, S], in XLA: what ``treekernel.frontier_hist`` computes
+    where no kernel runs, and the oracle it is held to. Block k's rows
+    [blk_start[k], blk_start[k+1]) of the node-sorted ``fid`` / ``words``
+    / ``stats`` are cut into chunks of ``chunk`` rows from the block's
+    first (the arrays end in ``chunk`` rows that belong to no node, so a
+    chunk cut at any row stays inside); a chunk's histogram is the
+    one-hot product of ops/histogram.py over the block's LOCAL node ids."""
+    F, B, S = n_features, n_bins, len(stats)
     FB = F * B
-    lcap = frontier_capacity(N, D)
-    C, LB, SB = _geometry(N, lcap)
-    SBN = LB * SB
-    nblk = lcap // LB
-    nlev = D - K
-    PR = piece_rows(LB)
-    W = max(1, (B - 1 + 31) // 32) if params.has_cats else 1
-    lam = sc.reg_lambda
-
-    words, bits = pack_bins(bins, B)
-    nw = len(words)
-
-    def tail(v, fill):
-        return jnp.concatenate([v, jnp.full((C,), fill, v.dtype)])
-
-    # the sort key: a live row's slot in its level (< lcap), a final
-    # row's lcap + flat table index of its node, the tail's rows last
-    fid = tail(nid.astype(jnp.int32), jnp.iinfo(jnp.int32).max)
-    rid = tail(jnp.arange(N, dtype=jnp.int32), N)
-    words = tuple(tail(w, 0) for w in words)
-    stats = tuple(tail(s.astype(jnp.float32), 0.0) for s in stats)
-    ns = len(stats)
-    slots = jnp.arange(lcap, dtype=jnp.int32)
-    path0 = jnp.where(slots < 2 ** K, slots, -1)
-    may0 = jnp.zeros((lcap,), bool).at[: 2 ** K].set(alive)
+    PR = piece_rows(lb, S, n_pieces)
+    k0 = s * sb
     iota_b = jnp.arange(B, dtype=jnp.int32)
     lane_f = np.arange(FB) // B
     sel = jnp.asarray(lane_f[None, :] == np.arange(F)[:, None],
@@ -175,7 +173,7 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
 
     def indicator(wd):
         """The chunk's 0/1 (feature, bin) indicator [C, FB], bfloat16.
-        As the histogram kernel builds it (treekernel._hist_block): the
+        As the level kernel builds it (treekernel._hist_block): the
         row's bins expanded across the F·B lanes by a 0/1 selection
         product (exact in bfloat16 for 8-bit bin ids) and ONE compare —
         0.81 s a pass over 48M rows where a compare a feature and a
@@ -191,16 +189,85 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
             preferred_element_type=jnp.float32)               # [C, FB]
         return (row_bin == lane_b).astype(jnp.bfloat16)
 
-    def chunk_hist(at, k, fid, words, stats):
+    def chunk_hist(at, k):
         def cut(v):
-            return jax.lax.dynamic_slice(v, (at,), (C,))
-        lid = (cut(fid) - k * LB)[None, :]
-        st = [cut(s) for s in stats]
-        st = jnp.stack(st if ns == 3 else st + st[:1])
+            return jax.lax.dynamic_slice(v, (at,), (chunk,))
+        lid = (cut(fid) - k * lb)[None, :]
         return jax.lax.dot_general(
-            stat_rows(lid, st, LB), indicator([cut(w) for w in words]),
+            stat_rows(lid, jnp.stack([cut(v) for v in stats]), lb, S,
+                      n_pieces),
+            indicator([cut(w) for w in words]),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
+    def block(kk, hist):
+        r0 = blk_start[k0 + kk]
+        r1 = blk_start[k0 + kk + 1]
+        a = jax.lax.fori_loop(
+            0, (r1 - r0 + chunk - 1) // chunk,
+            lambda c, a: a + chunk_hist(r0 + c * chunk, k0 + kk),
+            jnp.zeros((PR, FB), jnp.float32))
+        return jax.lax.dynamic_update_slice(
+            hist, sum_pieces(a, lb, S, n_pieces)[None], (kk, 0, 0))
+    hist = jax.lax.fori_loop(0, sb, block,
+                             jnp.zeros((sb, S * lb, FB), jnp.float32))
+    # [sb, S·lb, FB] rows node·S + stat → [nodes, F, B, S]
+    return hist.reshape(sb * lb, S, F, B).transpose(0, 2, 3, 1)
+
+
+def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
+                  K: int, sc, mtries: int, is_cat):
+    """Levels K..D-1 of one tree from the complete phase's level-K state
+    (``nid`` in [0, 2^K); ``alive`` [2^K]: the nodes whose parent split;
+    ``stats``: the rows' (w, w·g, w·h), or (w, w·g) where h is 1 and the
+    third is the first: the histogram's third column is then a copy of
+    its first, not a third of the product. ``params.whole_stats`` says
+    every statistic is 0 or ±1, and the operand then carries one
+    bfloat16 piece a statistic, not three (ops/histogram.stat_rows).
+
+    A level's histogram pass is the Pallas kernel ``tree_frontier_hist``
+    (ops/pallas/treekernel.frontier_hist) where ``params.pallas`` asks
+    for kernels and a tile fits, and the XLA chunk product elsewhere.
+
+    Returns ``(DeepLevels, ref, gains, capped)``: ``ref`` [N] the flat
+    index (level - K) * Lcap + slot of every row's final node; ``capped``
+    whether a split was refused for want of node slots (never, by
+    ``frontier_capacity``; counted all the same)."""
+    from h2o3_tpu.models import tree as T
+    D, B = params.max_depth, params.nbins_total
+    N, F = bins.shape
+    lcap = frontier_capacity(N, D)
+    C, LB, SB = _geometry(N, lcap)
+    SBN = LB * SB
+    nblk = lcap // LB
+    nlev = D - K
+    ns = len(stats)
+    tile, pieces = hist_plan(params, N, F, ns)
+    W = max(1, (B - 1 + 31) // 32) if params.has_cats else 1
+    lam = sc.reg_lambda
+
+    words, bits = pack_bins(bins, B)
+    nw = len(words)
+    if tile:
+        from h2o3_tpu.ops.pallas import treekernel
+    elif params.pallas in ("native", "interpret"):
+        from h2o3_tpu.ops.pallas import record_fallback
+        record_fallback("frontier_fits_no_tile")
+    # rows behind the frame's: a chunk cut at any row stays inside, and
+    # the kernel's tiles divide the whole
+    n_tail = C + (-(N + C)) % max(tile, 1)
+
+    def tail(v, fill):
+        return jnp.concatenate([v, jnp.full((n_tail,), fill, v.dtype)])
+
+    # the sort key: a live row's slot in its level (< lcap), a final
+    # row's lcap + flat table index of its node, the tail's rows last
+    fid = tail(nid.astype(jnp.int32), jnp.iinfo(jnp.int32).max)
+    rid = tail(jnp.arange(N, dtype=jnp.int32), N)
+    words = tuple(tail(w, 0) for w in words)
+    stats = tuple(tail(s.astype(jnp.float32), 0.0) for s in stats)
+    slots = jnp.arange(lcap, dtype=jnp.int32)
+    path0 = jnp.where(slots < 2 ** K, slots, -1)
+    may0 = jnp.zeros((lcap,), bool).at[: 2 ** K].set(alive)
     iota_lb = jnp.arange(LB, dtype=jnp.int32)[:, None]
 
     def byte_rows(v, n):
@@ -234,6 +301,9 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
             blk_start = jnp.searchsorted(
                 fid, jnp.arange(nblk + 1, dtype=jnp.int32) * LB,
                 side="left").astype(jnp.int32)
+            if tile:
+                sched = treekernel.frontier_schedule(
+                    blk_start, tile, fid.shape[0] // tile)
         n_sb = (n_live + SBN - 1) // SBN
         heap0 = jnp.left_shift(jnp.int32(1), d)
 
@@ -243,22 +313,18 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
             n0 = k0 * LB
 
             with jax.named_scope("tree.frontier.hist"):
-                def block(kk, hist):
-                    r0 = blk_start[k0 + kk]
-                    r1 = blk_start[k0 + kk + 1]
-
-                    def chunk(c, a):
-                        return a + chunk_hist(r0 + c * C, k0 + kk, fid,
-                                              words, stats)
-                    a = jax.lax.fori_loop(
-                        0, (r1 - r0 + C - 1) // C, chunk,
-                        jnp.zeros((PR, FB), jnp.float32))
-                    return jax.lax.dynamic_update_slice(
-                        hist, sum_pieces(a, LB)[None], (kk, 0, 0))
-                hist = jax.lax.fori_loop(
-                    0, SB, block, jnp.zeros((SB, 3 * LB, FB), jnp.float32))
-                # [SB, 3·LB, FB] rows node·3 + stat → [nodes, F, B, 3]
-                hist = hist.reshape(SBN, 3, F, B).transpose(0, 2, 3, 1)
+                if tile:
+                    hist = treekernel.frontier_hist(
+                        sched, blk_start, s, fid, words, stats, lb=LB, sb=SB,
+                        n_features=F, n_bins=B, bits=bits, n_pieces=pieces,
+                        tile=tile, interpret=params.pallas == "interpret")
+                else:
+                    hist = chunk_product_hist(
+                        blk_start, s, fid, words, stats, lb=LB, sb=SB,
+                        chunk=C, n_features=F, n_bins=B, bits=bits,
+                        n_pieces=pieces)
+                if ns == 2:
+                    hist = jnp.concatenate([hist, hist[..., :1]], axis=-1)
 
             with jax.named_scope("tree.frontier.split"):
                 p_sb = jax.lax.dynamic_slice(path, (n0,), (SBN,))

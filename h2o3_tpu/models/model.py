@@ -156,6 +156,8 @@ def _row_state_program(nrows, data, na_mask, wdata, wna, fold, *,
     w = jnp.where(uniform & pos, 1.0, w)
     summary = {
         "w_scale": jnp.where(uniform, w_min, 1.0),
+        # every weight that trains is 1.0 now: w is 0 or 1 on every row
+        "w_whole": w_min == w_max,
         "rows_out": jnp.sum(real & ~pos, dtype=jnp.int32),
         "w": _block_sums(w),
     }
@@ -183,6 +185,7 @@ class RowSummary:
     sum_wy: Union[float, np.ndarray]    # sum(w*y); [K] class weights
     w_scale: float
     rows_out: int           # real rows weighted out (NA or zero weight)
+    w_whole: bool = False   # every row's weight is 0 or (rescaled) 1
     y_min: Optional[float] = None   # numeric response: over its non-NA
     y_max: Optional[float] = None
 
@@ -216,6 +219,7 @@ def row_state_on_device(col, nrows: int, weights_col=None, fold=None):
         sum_w=float(np.sum(s["w"], dtype=np.float64)),
         sum_wy=wy if nclass else float(wy),
         w_scale=float(s["w_scale"]), rows_out=int(s["rows_out"]),
+        w_whole=bool(s["w_whole"]),
         y_min=float(s["y_min"]) if "y_min" in s else None,
         y_max=float(s["y_max"]) if "y_max" in s else None)
 

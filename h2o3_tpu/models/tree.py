@@ -147,6 +147,11 @@ class TreeParams:
                                      # the depth passes it; 0 = complete
                                      # layout all the way (GBM: its depths
                                      # fit the layout, ROADMAP R4)
+    whole_stats: bool = False        # the fit KNOWS every statistic of a
+                                     # row is 0 or ±1 (whole weights on a
+                                     # class indicator): the frontier's
+                                     # histogram operand then carries one
+                                     # bfloat16 piece a statistic
 
     @property
     def has_cats(self) -> bool:

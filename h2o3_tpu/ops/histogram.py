@@ -73,46 +73,60 @@ def split3(v):
     return hi, mid, rest - mid
 
 
-def piece_rows(n_nodes: int) -> int:
-    """Rows of ``stat_rows``' block: 3 pieces x ``n_nodes`` x 3 stats,
-    up to the 16 sublanes of a bfloat16 tile."""
-    return -(-9 * n_nodes // 16) * 16
+def piece_rows(n_nodes: int, n_stats: int = 3, n_pieces: int = 3) -> int:
+    """Rows of ``stat_rows``' block: ``n_pieces`` pieces x ``n_nodes`` x
+    ``n_stats`` stats, up to the 16 sublanes of a bfloat16 tile."""
+    return -(-n_pieces * n_stats * n_nodes // 16) * 16
 
 
-def stat_rows(nid, stats, n_nodes: int):
+def stat_rows(nid, stats, n_nodes: int, n_stats: int = 3, n_pieces: int = 3):
     """The statistics operand of the histogram product, rows on the
-    lanes: ``nid`` [1, C] int32 and ``stats`` [3, C] float32 ({w, w·g,
-    w·h}) → bfloat16 [piece_rows(n_nodes), C]. Row ``p·3L + 3·node + s``
-    holds piece ``p`` (``split3``) of stat ``s`` where the row's node is
-    ``node``, else 0. One function for the XLA path and for the body of
-    the Pallas kernels (only what Mosaic lowers: iota, compare, select,
-    bit masks), so both feed the MXU the same operand.
+    lanes: ``nid`` [1, C] int32 and ``stats`` [n_stats, C] float32 ({w,
+    w·g, w·h}) → bfloat16 [piece_rows(n_nodes, ..), C]. With S stats and
+    L nodes, row ``p·S·L + S·node + s`` holds piece ``p`` (``split3``)
+    of stat ``s`` where the row's node is ``node``, else 0. One function
+    for the XLA path and for the body of the Pallas kernels (only what
+    Mosaic lowers: iota, compare, select, bit masks), so both feed the
+    MXU the same operand.
+
+    ``n_stats`` 2 is for a caller whose third statistic IS its first (a
+    hessian of 1) and who copies the finished column; ``n_pieces`` 1 for
+    one who KNOWS every statistic is a bfloat16 value already (0, ±1:
+    whole weights on a class indicator), so that the pieces left out are
+    identically zero. Either way no sum changes.
 
     The stat is SELECTED into its rows (never a masked add): a NaN stat
     must not bleed into its siblings' rows the way 0*NaN would."""
-    L3 = 3 * n_nodes
-    k = jax.lax.broadcasted_iota(jnp.int32, (piece_rows(n_nodes), 1), 0)
-    piece = k // L3
-    rem = k - piece * L3
-    node = rem // 3
-    stat = rem - 3 * node
+    LS = n_stats * n_nodes
+    k = jax.lax.broadcasted_iota(
+        jnp.int32, (piece_rows(n_nodes, n_stats, n_pieces), 1), 0)
+    piece = k // LS
+    rem = k - piece * LS
+    node = rem // n_stats
+    stat = rem - n_stats * node
 
-    def of_stat(x):                                          # [3, C] -> [M, C]
-        return jnp.where(stat == 0, x[0:1],
-                         jnp.where(stat == 1, x[1:2], x[2:3]))
+    def pick(which, rows):           # rows[j] where which == j, last else
+        out = rows[-1]
+        for j in range(len(rows) - 2, -1, -1):
+            out = jnp.where(which == j, rows[j], out)
+        return out
 
-    hi, mid, lo = split3(stats)
-    val = jnp.where(piece == 0, of_stat(hi),
-                    jnp.where(piece == 1, of_stat(mid), of_stat(lo)))
-    hit = (nid == node) & (piece < 3)
+    def of_stat(x):                                          # [S, C] -> [M, C]
+        return pick(stat, [x[s:s + 1] for s in range(n_stats)])
+
+    val = pick(piece, [of_stat(p) for p in split3(stats)[:n_pieces]])
+    hit = (nid == node) & (piece < n_pieces)
     return jnp.where(hit, val, 0.0).astype(jnp.bfloat16)
 
 
-def sum_pieces(acc, n_nodes: int):
-    """[piece_rows(n_nodes), FB] products of ``stat_rows`` → the
-    float32 sums [3L, FB]."""
-    L3 = 3 * n_nodes
-    return acc[:L3] + acc[L3:2 * L3] + acc[2 * L3:3 * L3]
+def sum_pieces(acc, n_nodes: int, n_stats: int = 3, n_pieces: int = 3):
+    """[piece_rows(n_nodes, ..), FB] products of ``stat_rows`` → the
+    float32 sums [n_stats · L, FB]."""
+    LS = n_stats * n_nodes
+    out = acc[:LS]
+    for p in range(1, n_pieces):
+        out = out + acc[p * LS:(p + 1) * LS]
+    return out
 
 
 def _block_hist(bins_blk, nid_blk, stats_blk, n_nodes: int, n_bins: int):

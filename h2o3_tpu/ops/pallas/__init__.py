@@ -126,6 +126,16 @@ def _up(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
 
+def _tile_of(rows: int) -> int:
+    """The largest power of two up to ``rows``, or 0 under 128: a power
+    of two divides a frame's padded row count (a multiple of a large
+    power of two), so a kernel's operands need no padded copy — at 48M
+    rows a level with a 1,536- or 384-row tile made its own copies of
+    the bins (twice), the node ids and the statistics, 2 GB a level
+    (PERF.md §6, PR 35)."""
+    return 1 << (rows.bit_length() - 1) if rows >= 128 else 0
+
+
 def tile_rows(n_features: int, n_bins: int, n_nodes: int,
               budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
     """Rows per tile at which BOTH level kernels (histogram, partition)
@@ -159,9 +169,40 @@ def tile_rows(n_features: int, n_bins: int, n_nodes: int,
     part_fixed = 2 * _up(n_bins - 1, 8) * 4 * _up(n_nodes, 128)
     rows = min((int(budget_bytes) - hist_fixed) // hist_row,
                (int(budget_bytes) - part_fixed) // part_row, 2048)
-    # a power of two divides a frame's padded row count (a multiple of a
-    # large power of two), so the kernels' operands need no padded copy:
-    # at 48M rows a level with a 1,536- or 384-row tile made its own
-    # copies of the bins (twice), the node ids and the statistics, 2 GB
-    # a level (PERF.md §6, PR 35)
-    return 1 << (rows.bit_length() - 1) if rows >= 128 else 0
+    return _tile_of(rows)
+
+
+def frontier_bin_rows(n_bins: int) -> int:
+    """Sublanes a feature's bins take in the indicator of the frontier's
+    histogram kernel: up to the 16 of a bfloat16 tile, so that the
+    features' blocks stack without a shuffle."""
+    return _up(n_bins, 16)
+
+
+def frontier_tile_bytes(n_features: int, n_bins: int, operand_rows: int,
+                        n_inputs: int) -> Tuple[int, int]:
+    """(fixed, per tile row) bytes of scoped VMEM the frontier's
+    histogram kernel (treekernel.frontier_hist) is counted at: the
+    float32 accumulator [operand_rows, F·Bp] — the output block, so two
+    buffers of it — and per row of the tile its bfloat16 indicator
+    column [F·Bp], its column of the statistics operand (bfloat16, and
+    the float32 it is selected from) and the ``n_inputs`` [1, tile] rows
+    it is read from (8 sublanes each, two buffers)."""
+    fbp = _up(n_features * frontier_bin_rows(n_bins), 128)
+    return 2 * 4 * operand_rows * fbp, 2 * fbp + 6 * operand_rows \
+        + 2 * 32 * n_inputs
+
+
+def frontier_tile_rows(n_features: int, n_bins: int, operand_rows: int,
+                       n_inputs: int,
+                       budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
+    """Rows per tile of the frontier's histogram kernel, a power of two
+    from 128 to 2048 as ``tile_rows`` gives them, or 0 where even 128
+    rows do not fit beside the accumulator (a frontier level then takes
+    the XLA chunk product). Pure math, no backend. On a v5e (PR 36) a
+    pass is bound by the MXU rows of the operand, not by the tile: 1,024
+    to 4,096 rows read within 10% of each other, and a level of many
+    small blocks pays by the step, so the tile stops at 2,048."""
+    fixed, row = frontier_tile_bytes(n_features, n_bins, operand_rows,
+                                     n_inputs)
+    return _tile_of(min((int(budget_bytes) - fixed) // row, 2048))

@@ -281,6 +281,127 @@ def _partition_call(bins, nid, bf, bt, bnal, isp, cs, lmask, *, n_bins,
     return newnid[:, :N]
 
 
+# ------------------------------------------- the frontier's histogram pass
+#
+# models/frontier.py orders a level's rows by node, so a block of
+# ``lb`` nodes owns one contiguous run of rows, wherever it starts. The
+# kernel streams ALIGNED row tiles and a schedule (frontier_schedule)
+# says, for each grid step, which tile it reads and which block it adds
+# to — the grouped-matmul pattern (jax.experimental.pallas.ops.tpu.
+# megablox.gmm): a tile that straddles blocks is visited once a block,
+# and the rows outside the block's run drop out of the step (their
+# local node id is set to none: a row of an earlier super-batch has been
+# routed since the sort, and its new key may fall in this block's range).
+
+
+def frontier_schedule(blk_start, tile: int, n_tiles: int):
+    """The level's steps from ``blk_start`` [nblk + 1] (block k owns the
+    sorted rows [blk_start[k], blk_start[k+1])): ``(step0, blk, tid)`` —
+    block k takes the steps [step0[k], step0[k+1]), one for each tile
+    its rows touch, and ONE where it has no row (its histogram still has
+    to be zeroed); step i adds tile ``tid[i]`` to block ``blk[i]``. The
+    step arrays have the static length ``n_tiles + nblk`` that no level
+    can pass; the steps that exist are the first ``step0[nblk]``."""
+    nblk = blk_start.shape[0] - 1
+    r0, r1 = blk_start[:-1], blk_start[1:]
+    first = jnp.minimum(r0 // tile, n_tiles - 1)
+    count = jnp.where(r1 > r0, (r1 - 1) // tile - first + 1, 1)
+    step0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             jnp.cumsum(count, dtype=jnp.int32)])
+    i = jnp.arange(n_tiles + nblk, dtype=jnp.int32)
+    blk = jnp.clip(jnp.searchsorted(step0, i, side="right").astype(jnp.int32)
+                   - 1, 0, nblk - 1)
+    tid = jnp.minimum(first[blk] + i - step0[blk], n_tiles - 1)
+    return step0, blk, tid
+
+
+def _frontier_hist_kernel(meta_ref, blk_ref, tid_ref, start_ref, fid_ref,
+                          *refs, lb: int, n_features: int, n_bins: int,
+                          bits: int, n_words: int, n_stats: int,
+                          n_pieces: int):
+    word_refs = refs[:n_words]
+    stat_refs = refs[n_words:n_words + n_stats]
+    out_ref = refs[n_words + n_stats]
+    at = meta_ref[0] + pl.program_id(0)
+    blk = blk_ref[at]
+
+    @pl.when((pl.program_id(0) == 0)
+             | (blk_ref[jnp.maximum(at - 1, 0)] != blk))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    T = fid_ref.shape[1]
+    at_row = tid_ref[at] * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    mine = (at_row >= start_ref[blk]) & (at_row < start_ref[blk + 1])
+    lid = jnp.where(mine, fid_ref[...] - blk * lb, -1)           # [1, T]
+    # the (feature, bin) indicator with the rows on the lanes: feature
+    # f's bins are the sublanes [f·Bp, f·Bp + B) — one compare of the
+    # row's bin against a sublane iota a feature, no expansion product
+    Bp = pallas_policy.frontier_bin_rows(n_bins)
+    per = 32 // bits
+    bin_id = jax.lax.broadcasted_iota(jnp.int32, (Bp, T), 0)
+    words = [jax.lax.bitcast_convert_type(r[...], jnp.int32)
+             for r in word_refs]                                 # [1, T]
+    ind = jnp.concatenate(
+        [(((words[f // per] >> (bits * (f % per))) & ((1 << bits) - 1))
+          == bin_id).astype(jnp.float32).astype(jnp.bfloat16)
+         for f in range(n_features)], axis=0)
+    s_id = jax.lax.broadcasted_iota(jnp.int32, (n_stats, T), 0)
+    stats = stat_refs[-1][...]
+    for j in range(n_stats - 2, -1, -1):
+        stats = jnp.where(s_id == j, stat_refs[j][...], stats)   # [S, T]
+    # [M, T] x [F·Bp, T] over the rows, the lanes of both ("NT")
+    out_ref[...] += jax.lax.dot_general(
+        stat_rows(lid, stats, lb, n_stats, n_pieces), ind,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def frontier_hist(sched, blk_start, s, fid, words, stats, *, lb: int, sb: int,
+                  n_features: int, n_bins: int, bits: int, n_pieces: int,
+                  tile: int, interpret: bool):
+    """Super-batch ``s`` of a frontier level — the blocks s·sb ..
+    (s+1)·sb - 1 of ``lb`` nodes — as float32 sums [sb·lb, F, B, S]
+    over the node-sorted rows: ``fid`` [N] the rows' node slots (the
+    sort key), ``words`` their packed bin ids ([N] uint32 each, ``bits``
+    a bin), ``stats`` their S statistics ([N] float32 each); N a
+    multiple of ``tile``. ``sched`` is the level's ``frontier_schedule``
+    of ``blk_start``; the grid is the super-batch's own steps, a traced
+    count. ``fid`` need hold the sort's keys only in the super-batch's
+    own rows."""
+    step0, blk, tid = sched
+    N = fid.shape[0]
+    assert N % tile == 0, (N, tile)
+    S, F, B = len(stats), n_features, n_bins
+    Bp = pallas_policy.frontier_bin_rows(B)
+    M = piece_rows(lb, S, n_pieces)
+    k0 = s * sb
+    off = step0[k0]
+    meta = jnp.stack([off, k0]).astype(jnp.int32)
+    row = pl.BlockSpec((1, tile), lambda i, meta, blk, tid, start:
+                       (0, tid[meta[0] + i]))
+    rows = [fid, *words, *stats]
+    pallas_policy.record_launch("tree_frontier_hist")
+    acc = pl.pallas_call(
+        functools.partial(
+            _frontier_hist_kernel, lb=lb, n_features=F, n_bins=B,
+            bits=bits, n_words=len(words), n_stats=S, n_pieces=n_pieces),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(step0[k0 + sb] - off,),
+            in_specs=[row] * len(rows),
+            out_specs=pl.BlockSpec(
+                (None, M, F * Bp), lambda i, meta, blk, tid, start:
+                (blk[meta[0] + i] - meta[1], 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((sb, M, F * Bp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="tree_frontier_hist",
+    )(meta, blk, tid, blk_start, *(r[None, :] for r in rows))
+    # [sb, M, F·Bp] rows piece·S·lb + S·node + stat → [nodes, F, B, S]
+    sums = jax.vmap(lambda a: sum_pieces(a, lb, S, n_pieces))(acc)
+    return sums.reshape(sb * lb, S, F, Bp)[..., :B].transpose(0, 2, 3, 1)
+
+
 # ----------------------------------------------------------- entry points
 
 

@@ -196,6 +196,42 @@ def test_tree_partition_kernel_native(topo, d):
         vec(jnp.bool_), S((L, B - 1), jnp.bool_, sharding=one)).compile()
 
 
+@pytest.mark.parametrize("n_stats,n_pieces", [(2, 1), (2, 3), (3, 3)])
+def test_tree_frontier_hist_kernel_native(topo, n_stats, n_pieces):
+    """The frontier levels' histogram kernel alone (no level sort: that
+    compiles for 100 s) at the widths of drf-airlines-d20.fit-48m — 64
+    nodes a block, 32 blocks a super-batch, 2^19 node slots — and at the
+    operand heights its callers can ask for: 128 rows (the cell: two
+    whole statistics), 384 (two real ones), 576 (three)."""
+    from h2o3_tpu.models import frontier
+    from h2o3_tpu.ops.histogram import piece_rows
+    from h2o3_tpu.ops.pallas import treekernel as tk
+    one = _one_chip(topo)
+    lb, sb = frontier.NODE_BLOCK, frontier.SUPER_BLOCKS
+    nblk = frontier.frontier_capacity(AIR48_ROWS, 20) // lb
+    tile = plx.frontier_tile_rows(F, B, piece_rows(lb, n_stats, n_pieces),
+                                  1 + 3 + n_stats)
+    assert tile >= 1024
+    n = AIR48_ROWS + frontier.CHUNK_ROWS
+    n += -n % tile
+    vec = lambda k, dt=jnp.int32: S((k,), dt, sharding=one)   # noqa: E731
+
+    def call(step0, blk, tid, blk_start, s, fid, w0, w1, w2, *stats):
+        return tk.frontier_hist(
+            (step0, blk, tid), blk_start, s, fid, (w0, w1, w2), stats,
+            lb=lb, sb=sb, n_features=F, n_bins=B, bits=8,
+            n_pieces=n_pieces, tile=tile, interpret=False)
+    steps = n // tile + nblk
+    out = jax.jit(call).lower(
+        vec(nblk + 1), vec(steps), vec(steps), vec(nblk + 1),
+        S((), jnp.int32, sharding=one), vec(n),
+        *[vec(n, jnp.uint32)] * 3, *[vec(n, jnp.float32)] * n_stats
+    ).compile()
+    # no operand of 48M rows is copied on its way into the kernel (the
+    # temporaries are the kernel's [32, operand rows, 1280] products)
+    assert out.memory_analysis().temp_size_in_bytes < 4 * n
+
+
 def test_opt_in_histogram_kernel_native(topo):
     """ops/pallas_histogram.py, reachable only through its own switch,
     at the block size ops/histogram.py gives it."""

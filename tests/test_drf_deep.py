@@ -6,6 +6,8 @@ the complete ``Tree`` that were not carried over raise a named error on a
 forest that is really deeper than that layout holds. Small frames only:
 no test here grows a tree on more than a few thousand rows."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -110,19 +112,29 @@ def test_what_a_deep_forest_still_does(spine):
     assert perf["MSE"] < 1e-3 * float(np.var(fr.col("y").to_numpy()))
 
 
-@pytest.fixture(scope="module")
-def two_regimes(mixed_frame):
+@pytest.fixture(scope="module", params=["off", "interpret"])
+def two_regimes(mixed_frame, request):
     """The same depth-8 forest grown in the complete layout all the way
-    and with the frontier regime forced on from level 3."""
+    (in XLA) and with the frontier regime forced on from level 3 — in
+    XLA too, or with the Pallas kernels in interpret mode: the level
+    kernels above level 3, ``tree_frontier_hist`` from there."""
     fits = {}
     was = tree_mod.FRONTIER_FROM
+    mp = pytest.MonkeyPatch()
     try:
         for start in (0, 3):
             tree_mod.FRONTIER_FROM = start
+            mp.setenv("H2O3TPU_PALLAS", request.param if start else "off")
             fits[start] = DRFEstimator(ntrees=3, seed=1, max_depth=8).train(
                 mixed_frame, y="y")
     finally:
         tree_mod.FRONTIER_FROM = was
+        mp.undo()
+    chunk = [s for s in telemetry.spans_snapshot(last=1 << 16)
+             if s["name"] == "drf.chunk"][-1]["meta"]
+    assert chunk["frontier_hist"] == (
+        "kernel" if request.param == "interpret" else "xla")
+    assert chunk["levels_frontier"] == 7 and chunk["hist_operand_rows"] == 2
     return fits[0], fits[3]
 
 
@@ -171,6 +183,61 @@ def test_a_default_forest_grows_past_the_old_cap(default_forest):
     assert isinstance(default_forest.grown, frontier.DeepTree)
     assert default_forest.output["depth_reached"] > MAX_COMPLETE_DEPTH
     assert default_forest.training_metrics["AUC"] > 0.7
+
+
+def _operand_rows(**fit):
+    frame, x, y = fit.pop("frame"), fit.pop("x"), fit.pop("y", "y")
+    model = DRFEstimator(ntrees=2, seed=7, max_depth=12, **fit).train(
+        frame, y=y, x=x)
+    chunk = [s for s in telemetry.spans_snapshot(last=1 << 16)
+             if s["name"] == "drf.chunk"][-1]["meta"]
+    assert chunk["frontier_hist"] == "xla" and chunk["levels_frontier"] == 5
+    return model, chunk["hist_operand_rows"]
+
+
+@pytest.fixture(scope="module")
+def weighted_frame(mixed_frame):
+    """``mixed_frame`` with a weights column of 0 and 3 (whole once
+    rescaled), one of 0.5 … 2 (fractional) and a numeric response."""
+    r = np.random.default_rng(5)
+    cols = {n: mixed_frame.col(n).to_numpy() for n in ("x1", "x2")}
+    cols["c"] = mixed_frame.col("c").to_numpy().astype(np.int32)
+    cols["y"] = mixed_frame.col("y").to_numpy().astype(np.int32)
+    n = len(cols["y"])
+    cols["w03"] = 3.0 * (r.random(n) < 0.8)
+    cols["wfrac"] = r.choice([0.5, 1.0, 2.0], n)
+    cols["z"] = cols["x2"] + 0.1 * r.normal(size=n)
+    return h2o3_tpu.Frame.from_numpy(
+        cols, domains={"c": [f"l{i}" for i in range(12)], "y": ["n", "y"]})
+
+
+def test_the_histogram_operand_is_as_short_as_the_statistics(
+        weighted_frame, monkeypatch):
+    """Two statistics a forest (a hessian of 1), and one bfloat16 piece
+    each where they are 0 or ±1: a class response under whole weights."""
+    x = ["x1", "x2", "c"]
+    plain, rows = _operand_rows(frame=weighted_frame, x=x)
+    assert rows == 2
+    whole, rows = _operand_rows(frame=weighted_frame, x=x,
+                                weights_column="w03")
+    assert rows == 2
+    assert _operand_rows(frame=weighted_frame, x=x,
+                         weights_column="wfrac")[1] == 6
+    assert _operand_rows(frame=weighted_frame, x=x, y="z")[1] == 6
+    # forced to three pieces, the same forests: the two pieces left out
+    # are identically zero
+    real = DRFEstimator._training_weights
+
+    def three_pieces(self, frame, y):
+        w, y_dev, rows = real(self, frame, y)
+        return w, y_dev, dataclasses.replace(rows, w_whole=False)
+    monkeypatch.setattr(DRFEstimator, "_training_weights", three_pieces)
+    for model, fit in ((plain, {}), (whole, {"weights_column": "w03"})):
+        forced, rows = _operand_rows(frame=weighted_frame, x=x, **fit)
+        assert rows == 6
+        assert _same(model.grown, forced.grown)
+        assert model.training_metrics["logloss"] == \
+            forced.training_metrics["logloss"]
 
 
 def test_chunked_is_single_scan(default_forest, mixed_frame):

@@ -114,6 +114,26 @@ def test_row_state_equals_the_host_path(kind, weights):
         assert rows.y_min is None and rows.y_max is None
 
 
+@pytest.mark.parametrize("weights,whole", [
+    ("none", True), ("na_response", True), ("constant_2", True),
+    ("zeros_and_threes", True), ("column_na_zero", False)])
+def test_the_summary_says_whether_the_weights_are_whole(weights, whole):
+    """``w_whole``: every row's weight is 0 or 1 once a constant column
+    is rescaled — what lets a forest's histogram operand carry one
+    bfloat16 piece a statistic (models/drf.py)."""
+    fr, wc = _frame("binomial", "none" if weights == "zeros_and_threes"
+                    else weights, n=3000)
+    wcol = fr.col(wc) if wc else None
+    if weights == "zeros_and_threes":
+        wt = 3.0 * (np.arange(3000) % 4 > 0)
+        wcol = h2o3_tpu.Frame.from_numpy({"wt": wt}).col("wt")
+    w, _, rows = row_state_on_device(fr.col("y"), fr.nrows, weights_col=wcol)
+    assert rows.w_whole is whole
+    assert set(np.unique(np.asarray(w))) <= {0.0, 1.0} or not whole
+    if weights == "zeros_and_threes":
+        assert rows.w_scale == 3.0 and rows.sum_w == 2250.0
+
+
 def test_counts_stay_exact_past_the_float32_integers():
     """A plain float32 sum of 0/1 codes stalls at 2^24; block partials
     finished in float64 do not. 17M rows is past it and still small
