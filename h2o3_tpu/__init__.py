@@ -18,6 +18,10 @@ Public API mirrors the h2o-py module surface (h2o-py/h2o/h2o.py):
 ``init``, ``import_file``, ``H2OFrame``-like ``Frame``, estimator classes.
 """
 
+import time as _time
+
+_T_IMPORT = _time.time()
+
 try:
     # pandas >= 3.0 backs str columns with pyarrow; libarrow segfaults
     # under this image's threading profile (observed: handler threads in
@@ -42,6 +46,11 @@ from h2o3_tpu.core.memgov import MemoryBudgetExceeded
 from h2o3_tpu.core.scope import Scope
 from h2o3_tpu.core.udf import (upload_custom_distribution,
                                upload_custom_metric)
+
+# what importing the package cost this process (jax and pandas among
+# it where nobody imported them before): set-up no span can hold
+from h2o3_tpu.telemetry import gauge as _gauge
+_gauge("process_import_seconds").set(_time.time() - _T_IMPORT)
 
 __all__ = [
     "__version__",

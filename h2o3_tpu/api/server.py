@@ -1667,7 +1667,9 @@ def _metrics(params, body):
     """Runtime telemetry snapshot (h2o3_tpu/telemetry): registry
     counters/gauges/histograms + recent spans. ``?format=prometheus``
     returns text exposition 0.0.4 for a scraping agent; the JSON shape
-    additionally carries the span ring and per-span-name aggregate.
+    additionally carries the span ring, the per-span-name aggregate and
+    the compile observer's ledger by program (``programs``: traces,
+    lowerings, compiles and persistent-cache loads with their seconds).
     ``?cluster=1`` on a multi-process cloud merges every peer's fan-in
     snapshot (telemetry/cluster.py): counters summed across nodes,
     gauges/histograms per-node with a ``node=`` label, peers past their
@@ -1693,6 +1695,7 @@ def _metrics(params, body):
         return {"metrics": cluster.merged_metrics(col),
                 "spans": telemetry.spans_snapshot(50),
                 "span_aggregate": telemetry.spans_aggregate(),
+                "programs": telemetry.programs_snapshot(),
                 "cluster": {
                     "process_count": col["process_count"],
                     "stale_nodes": col["stale_nodes"],
@@ -1707,7 +1710,8 @@ def _metrics(params, body):
         nspans = 50
     return {"metrics": telemetry.snapshot(),
             "spans": telemetry.spans_snapshot(nspans),
-            "span_aggregate": telemetry.spans_aggregate()}
+            "span_aggregate": telemetry.spans_aggregate(),
+            "programs": telemetry.programs_snapshot()}
 
 
 @route("GET", "/3/Alerts")

@@ -27,6 +27,7 @@ from typing import Optional
 
 import jax
 
+from h2o3_tpu import telemetry
 from h2o3_tpu.core import config as _config
 from h2o3_tpu.core import heartbeat as heartbeat_mod
 from h2o3_tpu.core import watchdog
@@ -154,7 +155,6 @@ def init(backend: Optional[str] = None,
     (digest-verified) and models re-register (core/durability.py, the
     rolling-restart / disaster-recovery path).
     """
-    global _STARTED, _CLOUD_START_MS
     if (_STARTED and backend is None and coordinator_address is None
             and data_axis is None and model_axis is None
             and num_processes is None and process_id is None
@@ -164,6 +164,19 @@ def init(backend: Optional[str] = None,
         # running cluster; silently re-detecting devices here could
         # swap the session's mesh to a different backend mid-flight)
         return cluster_info()
+    with telemetry.span("cloud.init") as sp:
+        info = _form_cloud(backend, data_axis, model_axis,
+                           coordinator_address, num_processes, process_id,
+                           restore_dir, **kwargs)
+        sp.annotate(platform=info["platform"], devices=info["cloud_size"])
+    return info
+
+
+def _form_cloud(backend, data_axis, model_axis, coordinator_address,
+                num_processes, process_id, restore_dir, **kwargs) -> dict:
+    """``init``'s forming path: configuration, compile cache, the
+    distributed client where asked for, the backend and the mesh."""
+    global _STARTED, _CLOUD_START_MS
     cfg = _config.Config.from_env(backend=backend, data_axis=data_axis,
                                   model_axis=model_axis, **kwargs)
     _config.ARGS = cfg
@@ -219,8 +232,11 @@ def init(backend: Optional[str] = None,
         # jax.process_index() inside the logging hot path
         _log.set_node(int(process_id))
 
-    devices = jax.devices(cfg.backend) if cfg.backend else jax.devices()
-    m = mesh_mod.make_mesh(devices, cfg.data_axis, cfg.model_axis)
+    # the first jax.devices() of a process brings the backend up
+    with telemetry.span("cloud.backend"):
+        devices = jax.devices(cfg.backend) if cfg.backend \
+            else jax.devices()
+        m = mesh_mod.make_mesh(devices, cfg.data_axis, cfg.model_axis)
     mesh_mod.set_global_mesh(m)
     _STARTED = True
     _CLOUD_START_MS = int(time.time() * 1000)

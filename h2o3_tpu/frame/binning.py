@@ -15,6 +15,7 @@ and never win a split because their counts are zero.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import List, Optional, Sequence
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from h2o3_tpu.frame.frame import Frame
+from h2o3_tpu import telemetry
 from h2o3_tpu.telemetry import observed_jit
 
 
@@ -207,6 +209,47 @@ def _numeric_edges(x: np.ndarray, nbins: int,
     return np.unique(mids.astype(np.float32))
 
 
+def _bin_codes(frame, cols, is_cat, train_domains, edges, nb, div, B,
+               sharding):
+    """The device half of ``bin_frame``: edges and bin counts up, the
+    binning program over every column, the codes row-sharded."""
+    F = len(cols)
+    edges_dev = jax.device_put(edges)
+    nb_dev = jax.device_put(nb)
+
+    # one jitted pass over all columns (retraces per frame schema only)
+    datas, nas, remaps = [], [], []
+    has_remap = []
+    for i, c in enumerate(cols):
+        datas.append(c.data)
+        nas.append(c.na_mask)
+        if is_cat[i] and train_domains is not None \
+                and train_domains[i] is not None \
+                and c.domain != train_domains[i]:
+            lut = {lvl: j for j, lvl in enumerate(train_domains[i])}
+            mapping = np.array([lut.get(lvl, -1) for lvl in (c.domain or [])],
+                               dtype=np.int32)
+            if len(mapping) == 0:
+                mapping = np.array([-1], dtype=np.int32)
+            remaps.append(jnp.asarray(mapping))
+            has_remap.append(True)
+        else:
+            remaps.append(jnp.zeros((1,), jnp.int32))
+            has_remap.append(False)
+    if F:
+        bins = _bin_device(tuple(datas), tuple(nas), tuple(remaps),
+                           edges_dev, B=B, is_cat_t=tuple(bool(v) for v in is_cat),
+                           has_remap_t=tuple(has_remap),
+                           div_t=tuple(int(v) for v in div))
+    else:
+        bins = jnp.zeros((frame.nrows_padded, 0), jnp.int32)
+    if sharding is not None:
+        from h2o3_tpu.parallel.mesh import row_sharding
+        from h2o3_tpu.parallel.mesh import put_sharded
+        bins = put_sharded(bins, row_sharding())
+    return edges_dev, nb_dev, bins
+
+
 def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
               nbins_cats: int = 64,
               edges_override: Optional[List[np.ndarray]] = None,
@@ -262,8 +305,12 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
                 cache = None
         if cache is not None and cache_key in cache:
             return cache[cache_key]
-    if callable(weights):
-        weights = weights()
+    # the lookup has missed: a frame's first binning, in three phases
+    # (a scoring rebin has no slot, and stays under its caller's spans)
+    def phase(name, **meta):
+        return telemetry.span(name, **meta) if cache_key is not None \
+            else contextlib.nullcontext()
+
     cols = [frame.col(n) for n in names]
     is_cat = np.array([c.is_categorical for c in cols], dtype=bool)
     domains = [c.domain for c in cols]
@@ -277,9 +324,19 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
         from concurrent.futures import ThreadPoolExecutor
         from h2o3_tpu.frame.column import prefetch_host
         numeric = [i for i in range(F) if not is_cat[i]]
-        prefetch_host([cols[i] for i in numeric])
-        host = [cols[i].to_numpy() for i in numeric]
-        with ThreadPoolExecutor(4) as pool:
+        with phase("bin.fetch", columns=len(numeric),
+                   rows=frame.nrows) as sp:
+            if callable(weights):
+                weights = weights()
+            prefetch_host([cols[i] for i in numeric])
+            host = [cols[i].to_numpy() for i in numeric]
+            if sp is not None:
+                sp.annotate(host_bytes=int(
+                    sum(x.nbytes for x in host)
+                    + (np.asarray(weights).nbytes
+                       if weights is not None else 0)))
+        with phase("bin.edges", columns=len(numeric), rows=frame.nrows), \
+                ThreadPoolExecutor(4) as pool:
             numeric_edges = dict(zip(numeric, pool.map(
                 lambda x: _numeric_edges(x, nbins, histogram_type,
                                          w=weights), host)))
@@ -321,39 +378,9 @@ def bin_frame(frame: Frame, features: Sequence[str], nbins: int = 64,
         edges[i, : len(e)] = e
 
     sharding = cols[0].data.sharding if cols else None
-    edges_dev = jax.device_put(edges)
-    nb_dev = jax.device_put(nb)
-
-    # one jitted pass over all columns (retraces per frame schema only)
-    datas, nas, remaps = [], [], []
-    has_remap = []
-    for i, c in enumerate(cols):
-        datas.append(c.data)
-        nas.append(c.na_mask)
-        if is_cat[i] and train_domains is not None \
-                and train_domains[i] is not None \
-                and c.domain != train_domains[i]:
-            lut = {lvl: j for j, lvl in enumerate(train_domains[i])}
-            mapping = np.array([lut.get(lvl, -1) for lvl in (c.domain or [])],
-                               dtype=np.int32)
-            if len(mapping) == 0:
-                mapping = np.array([-1], dtype=np.int32)
-            remaps.append(jnp.asarray(mapping))
-            has_remap.append(True)
-        else:
-            remaps.append(jnp.zeros((1,), jnp.int32))
-            has_remap.append(False)
-    if F:
-        bins = _bin_device(tuple(datas), tuple(nas), tuple(remaps),
-                           edges_dev, B=B, is_cat_t=tuple(bool(v) for v in is_cat),
-                           has_remap_t=tuple(has_remap),
-                           div_t=tuple(int(v) for v in div))
-    else:
-        bins = jnp.zeros((frame.nrows_padded, 0), jnp.int32)
-    if sharding is not None:
-        from h2o3_tpu.parallel.mesh import row_sharding
-        from h2o3_tpu.parallel.mesh import put_sharded
-        bins = put_sharded(bins, row_sharding())
+    with phase("bin.codes", columns=F, rows=frame.nrows, nbins_total=B):
+        edges_dev, nb_dev, bins = _bin_codes(
+            frame, cols, is_cat, train_domains, edges, nb, div, B, sharding)
 
     import weakref
     try:

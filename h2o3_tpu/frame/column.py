@@ -145,48 +145,51 @@ def column_from_numpy(name: str, values: np.ndarray, nrows_padded: int,
     (NewChunk.compress); here one dtype per column: int8/int16/int32 for
     integral ranges, float32 otherwise, int32 codes for categoricals.
     """
+    from h2o3_tpu.telemetry.spans import span
     values = np.asarray(values)
     n = values.shape[0]
     pad = nrows_padded - n
 
-    if values.dtype == object or values.dtype.kind in "US":
-        if domain is None:
-            # categorical via interning, domain sorted lexicographically
-            # like the reference parser (water/parser/Categorical.java)
-            import pandas as pd
-            codes, uniques = pd.factorize(values, sort=True)
-            domain = [str(u) for u in uniques]
-            values = codes.astype(np.int32)
-        else:
-            # explicit domain: map labels to codes, unseen/None → NA
-            lut = {lvl: i for i, lvl in enumerate(domain)}
-            values = np.asarray([lut.get(v, -1) if v is not None else -1
-                                 for v in values], np.int32)
-        na = values < 0
-        data = np.where(na, 0, values).astype(np.int32)
-        ctype = T_CAT
-    elif domain is not None:
-        na = (values < 0) | ~np.isfinite(values.astype(np.float64))
-        data = np.where(na, 0, values).astype(np.int32)
-        ctype = T_CAT
-    else:
-        vals64 = values.astype(np.float64)
-        na = ~np.isfinite(vals64)
-        clean = np.where(na, 0.0, vals64)
-        if np.all(clean == np.round(clean)) and np.all(np.abs(clean) < 2**31):
-            lo, hi = clean.min() if n else 0, clean.max() if n else 0
-            if -128 <= lo and hi <= 127:
-                data = clean.astype(np.int8)
-            elif -32768 <= lo and hi <= 32767:
-                data = clean.astype(np.int16)
+    # the host passes that pick the codec and pad: one span a column
+    with span("frame.encode", columns=1, host_bytes=int(values.nbytes)):
+        if values.dtype == object or values.dtype.kind in "US":
+            if domain is None:
+                # categorical via interning, domain sorted lexicographically
+                # like the reference parser (water/parser/Categorical.java)
+                import pandas as pd
+                codes, uniques = pd.factorize(values, sort=True)
+                domain = [str(u) for u in uniques]
+                values = codes.astype(np.int32)
             else:
-                data = clean.astype(np.int32)
+                # explicit domain: map labels to codes, unseen/None → NA
+                lut = {lvl: i for i, lvl in enumerate(domain)}
+                values = np.asarray([lut.get(v, -1) if v is not None else -1
+                                     for v in values], np.int32)
+            na = values < 0
+            data = np.where(na, 0, values).astype(np.int32)
+            ctype = T_CAT
+        elif domain is not None:
+            na = (values < 0) | ~np.isfinite(values.astype(np.float64))
+            data = np.where(na, 0, values).astype(np.int32)
+            ctype = T_CAT
         else:
-            data = clean.astype(np.float32)
-        ctype = T_NUM
+            vals64 = values.astype(np.float64)
+            na = ~np.isfinite(vals64)
+            clean = np.where(na, 0.0, vals64)
+            if np.all(clean == np.round(clean)) and np.all(np.abs(clean) < 2**31):
+                lo, hi = clean.min() if n else 0, clean.max() if n else 0
+                if -128 <= lo and hi <= 127:
+                    data = clean.astype(np.int8)
+                elif -32768 <= lo and hi <= 32767:
+                    data = clean.astype(np.int16)
+                else:
+                    data = clean.astype(np.int32)
+            else:
+                data = clean.astype(np.float32)
+            ctype = T_NUM
 
-    data = np.pad(data, (0, pad))
-    na = np.pad(na, (0, pad), constant_values=True)  # padding rows are NA
+        data = np.pad(data, (0, pad))
+        na = np.pad(na, (0, pad), constant_values=True)  # padding rows are NA
     from h2o3_tpu.parallel.mesh import put_sharded
     if time and ctype == T_NUM:
         # Vec.T_TIME: epoch millis. Device storage remains f32 (x64 is
@@ -194,11 +197,13 @@ def column_from_numpy(name: str, values: np.ndarray, nrows_padded: int,
         # device math on times is ~65-131s-granular; all host paths
         # (rapids time ops, downloads) read the exact f64 cache below.
         ctype = T_TIME
-    col = Column(
-        name=name, type=ctype,
-        data=put_sharded(data, sharding),
-        na_mask=put_sharded(na, sharding),
-        nrows=n, domain=domain)
+    with span("frame.put", columns=1,
+              host_bytes=int(data.nbytes + na.nbytes)):
+        col = Column(
+            name=name, type=ctype,
+            data=put_sharded(data, sharding),
+            na_mask=put_sharded(na, sharding),
+            nrows=n, domain=domain)
     if ctype in (T_NUM, T_TIME) and data.dtype == np.float32:
         # seed the host cache with the ORIGINAL float64 values: the
         # munging/metadata path (rapids reducers, quantiles, mmult)

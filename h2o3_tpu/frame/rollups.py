@@ -49,8 +49,12 @@ def prefetch_rollups(cols) -> None:
             if c._rollups is None and c.type != T_STR and c.data is not None]
     if not todo:
         return
-    fetched = jax.device_get([_rollup_kernel(c.data, c.na_mask)
-                              for c in todo])
+    # opened for the fetch that is really made: a column's rollups are
+    # kept, so a frame's later summaries and design builds open none
+    from h2o3_tpu.telemetry.spans import span
+    with span("frame.rollups", columns=len(todo), fetches=1):
+        fetched = jax.device_get([_rollup_kernel(c.data, c.na_mask)
+                                  for c in todo])
     for c, stats in zip(todo, fetched):
         out = {k: float(v) for k, v in stats.items()}
         out["rows"] = int(out["rows"])

@@ -7,6 +7,17 @@ TPU runtime's equivalent for its OWN failure modes: XLA compile storms,
 shape-bucket misses, and device-memory pressure. Always on, cheap
 (registry op ≈ 1µs; see test_telemetry.py overhead bound).
 
+Where a first fit's time goes is answered by two series that add up:
+``span_own_seconds_total{name=}`` (a span's duration less its children,
+telemetry/spans.py) and ``xla_stage_seconds_total{stage=}`` (tracing,
+lowering, compiling, loading from the persistent cache; by program in
+``programs_snapshot()``, telemetry/compile_observer.py). The set-up
+path opens its own spans where the work is done and never on a cached
+path: ``cloud.init`` / ``cloud.backend``, ``frame.encode`` /
+``frame.put``, ``frame.rollups``, ``bin.fetch`` / ``bin.edges`` /
+``bin.codes``; the gauge ``process_import_seconds`` is the package's
+own import.
+
 Request hardening (api/server.py + core/request_ctx.py) reports
 through the same registry: ``rest_inflight_requests`` (gauge),
 ``rest_rejected_total{reason=}``, ``request_deadline_exceeded_total``,
@@ -38,7 +49,8 @@ from h2o3_tpu.telemetry.spans import (add_collective_bytes, annotate,
 from h2o3_tpu.telemetry.spans import snapshot as spans_snapshot
 from h2o3_tpu.telemetry.spans import aggregate as spans_aggregate
 from h2o3_tpu.telemetry.compile_observer import (compiles_snapshot, install,
-                                                 observed_jit)
+                                                 observed_jit,
+                                                 programs_snapshot)
 from h2o3_tpu.telemetry import trace_export
 from h2o3_tpu.telemetry import trace_context
 from h2o3_tpu.telemetry import slo
@@ -61,7 +73,7 @@ __all__ = [
     "span", "annotate", "current_span", "current_span_id",
     "add_collective_bytes", "spans_snapshot", "spans_aggregate",
     "install", "observed_jit", "snapshot", "to_prometheus",
-    "compiles_snapshot", "flight_recorder", "trace_export",
+    "compiles_snapshot", "programs_snapshot", "flight_recorder", "trace_export",
     "trace_context", "slo", "cluster", "roofline", "stepprof",
     "perfbase",
 ]

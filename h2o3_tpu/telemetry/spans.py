@@ -15,8 +15,14 @@ backend reports none), and any collective-byte estimates charged
 to it by the dispatch layer (parallel/map_reduce.py). Nesting is
 contextvar-based, so worker threads (background jobs) get their own
 root spans for free. Finished spans land in a fixed ring (the TimeLine
-capacity discipline) and feed ``span_seconds{name=}`` histograms in the
-registry; ``GET /3/Metrics`` serves both views.
+capacity discipline) and feed two durable series by name in the
+registry: the histogram ``span_seconds{name=}`` — wall time and the
+count of spans, every child counted again in its parent — and the
+counter ``span_own_seconds_total{name=}`` — the span's duration less
+its child spans and less the XLA stage seconds reported while it was
+the active span (telemetry/compile_observer.py). Own seconds add up:
+over all names they are the wall time a thread spent under spans, ring
+or no ring. ``GET /3/Metrics`` serves all of it.
 
 Timeline events recorded while a span is active carry its id
 (utils/timeline.py), tying the flat event ring to the span tree.
@@ -38,6 +44,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from jax._src import xla_bridge
 from jax.profiler import TraceAnnotation
 
 from h2o3_tpu.telemetry.registry import counter, histogram
@@ -58,7 +65,7 @@ TRACE_PREFIX = "h2o3."
 class Span:
     __slots__ = ("id", "name", "parent_id", "trace_id", "start", "end",
                  "meta", "device_peak_bytes", "collective_bytes",
-                 "_token", "_peak_base")
+                 "child_s", "_token", "_peak_base")
 
     def __init__(self, name: str, parent_id: Optional[str],
                  trace_id: Optional[str] = None, **meta):
@@ -71,6 +78,9 @@ class Span:
         self.meta = meta
         self.device_peak_bytes = 0
         self.collective_bytes = 0.0
+        # seconds of this span that a child span or an XLA stage event
+        # has to its own name
+        self.child_s = 0.0
         self._token = None
         self._peak_base = 0
 
@@ -87,6 +97,8 @@ class Span:
                 "name": self.name,
                 "start_ms": int(self.start * 1000),
                 "duration_ms": round(self.duration * 1000, 3),
+                "own_ms": round(max(self.duration - self.child_s, 0.0)
+                                * 1000, 3),
                 "device_peak_bytes": self.device_peak_bytes,
                 "collective_bytes": self.collective_bytes,
                 "meta": {k: v for k, v in self.meta.items()}}
@@ -95,9 +107,13 @@ class Span:
 def _device_peak() -> int:
     """Device HBM high-water, 0 when the backend reports no stats (the
     CPU backend — job.py documents that pressure then shows up as
-    RESOURCE_EXHAUSTED, not as this gauge)."""
+    RESOURCE_EXHAUSTED, not as this gauge) and 0 while no backend is up:
+    a span never brings one up by asking (seconds on a TPU host; that
+    is ``init()``'s to do, under its ``cloud.backend`` span)."""
     try:
         import jax
+        if not xla_bridge.backends_are_initialized():
+            return 0
         s = jax.devices()[0].memory_stats() or {}
         return int(s.get("peak_bytes_in_use", 0) or 0)
     except Exception:   # noqa: BLE001 - stats are strictly best-effort
@@ -138,14 +154,18 @@ def span(name: str, **meta):
         _current.reset(sp._token)
         sp.end = time.time()
         sp.device_peak_bytes = max(0, _device_peak() - sp._peak_base)
+        seconds = sp.end - sp.start
         if parent is not None:
             # charge child collective traffic up the tree so a root job
-            # span totals its whole subtree
+            # span totals its whole subtree; the seconds go up so that
+            # the parent's own time leaves them out
             parent.collective_bytes += sp.collective_bytes
+            parent.child_s += seconds
         with _finished_lock:
             _finished.append(sp)
-        counter("spans_total", name=name).inc()
-        histogram("span_seconds", name=name).observe(sp.end - sp.start)
+        counter("span_own_seconds_total", name=name).inc(
+            max(seconds - sp.child_s, 0.0))
+        histogram("span_seconds", name=name).observe(seconds)
         # per-job flight recorder capture (one contextvar read when no
         # recorder is attached — telemetry/flight_recorder.py)
         try:
@@ -180,15 +200,17 @@ def record_finished(name: str, start: float, end: float, *,
     coalesced dispatch, then attributed back to each member request's
     own trace. Skips the device-peak baseline (the interval is already
     closed) but otherwise lands in the same ring/metrics/flight
-    recorder as a live span."""
+    recorder as a live span. It has no children, so all of it is its
+    own time; it is charged to no parent (the phases of a coalesced
+    dispatch overlap their requests' spans many times over)."""
     sp = Span(name, parent_id, trace_id=trace_id, **meta)
     sp.start = float(start)
     sp.end = float(end)
     with _finished_lock:
         _finished.append(sp)
-    counter("spans_total", name=name).inc()
-    histogram("span_seconds", name=name).observe(max(sp.end - sp.start,
-                                                     0.0))
+    seconds = max(sp.end - sp.start, 0.0)
+    counter("span_own_seconds_total", name=name).inc(seconds)
+    histogram("span_seconds", name=name).observe(seconds)
     try:
         from h2o3_tpu.telemetry import flight_recorder
         flight_recorder.record_span(sp)

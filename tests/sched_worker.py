@@ -118,14 +118,18 @@ if mode == "trace":
             echoed = r.headers.get("X-H2O-Trace-Id")
             jk = json.loads(r.read())["job"]["key"]["name"]
         status = "?"
-        for _ in range(1200):
+        # every poll is an (untraced) ``rest`` span of its own, and a
+        # node publishes the last cluster.MAX_SPANS (192) spans of its
+        # ring: polled ten times a second, a job of a few seconds
+        # pushed the traced ``rest`` span out of what is stitched
+        for _ in range(240):
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{port}/3/Jobs/{jk}") as r:
                 jd = json.loads(r.read())["jobs"][0]
             status = jd["status"]
             if status not in ("CREATED", "RUNNING"):
                 break
-            time.sleep(0.1)
+            time.sleep(0.5)
         # the stitched trace needs BOTH hosts' span rings: poll until
         # process 1's published snapshot carries its leased items
         trace = {}
@@ -135,8 +139,13 @@ if mode == "trace":
                     f"http://127.0.0.1:{port}/3/Trace"
                     f"?trace_id={TRACE_ID}") as r:
                 trace = json.loads(r.read())
-            if sorted(trace.get("otherData", {})
-                      .get("nodes", [])) == [0, 1]:
+            # ... its ITEMS: a heartbeat-cadence publish from the middle
+            # of process 1's first item already carries traced spans
+            # (fit.admit, the frame's build), so "both nodes are in the
+            # trace" alone raced the items' own spans
+            if any(e.get("name") == "sched.item"
+                   and e.get("args", {}).get("node") == 1
+                   for e in trace.get("traceEvents", [])):
                 break
             time.sleep(0.2)
         result = {"pid": pid, "status": status, "echoed": echoed,

@@ -114,7 +114,7 @@ def test_span_nesting_and_ring():
     assert by_id[si.id]["parent_id"] == so.id
     assert by_id[so.id]["parent_id"] is None
     assert by_id[si.id]["meta"].get("phase") == 1
-    assert telemetry.REGISTRY.value("spans_total", name="t.outer") >= 1
+    assert telemetry.REGISTRY.value("span_seconds", name="t.outer") >= 1
 
 
 def test_span_roots_are_per_thread():
@@ -263,8 +263,8 @@ def test_metrics_endpoint_after_gbm_fit(port):
         with telemetry.span("t.overhead"):
             pass
     per_span = (time.time() - t0) / 500
-    n_spans = telemetry.REGISTRY.value("spans_total", name="gbm.chunk") \
-        + telemetry.REGISTRY.value("spans_total", name="gbm.fit")
+    n_spans = telemetry.REGISTRY.value("span_seconds", name="gbm.chunk") \
+        + telemetry.REGISTRY.value("span_seconds", name="gbm.fit")
     est = ops_fit * per_op + n_spans * per_span
     assert est < 0.02 * fit_wall, (est, fit_wall, ops_fit)
 
