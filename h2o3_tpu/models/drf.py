@@ -434,7 +434,9 @@ class DRFEstimator(ModelBuilder):
             # are 0 or ±1, whatever the bag keeps of it
             whole_stats=rows.w_whole
             and category != ModelCategory.REGRESSION)
-        tp = dataclasses.replace(tp, frontier_from=frontier_start(tp, F))
+        tp = dataclasses.replace(
+            tp, frontier_from=frontier_start(tp, F),
+            frontier_sort_every=frontier.SORT_PERIOD)
         N = bm.bins.shape[0]
         n_complete = frontier.complete_levels(N, tp.max_depth,
                                               tp.frontier_from)
@@ -444,6 +446,8 @@ class DRFEstimator(ModelBuilder):
         paths = {"levels_kernel": sum(kl),
                  "levels_xla": n_complete - sum(kl),
                  "levels_frontier": tp.max_depth - n_complete,
+                 "levels_sorted": frontier.sort_levels(
+                     tp.max_depth - n_complete, tp.frontier_sort_every),
                  "frontier_hist": "kernel" if tile else "xla",
                  "hist_operand_rows": 2 * pieces}
 
@@ -508,7 +512,9 @@ class DRFEstimator(ModelBuilder):
                     capped += int(got["capped"])
                     sp.annotate(depth_reached=int(got["depth"]),
                                 leaves=int(got["leaves"]),
-                                frontier_nodes_max=int(got["nodes_max"]))
+                                frontier_nodes_max=int(got["nodes_max"]),
+                                frontier_rescan_pct=float(
+                                    got["rescan_pct"]))
             telemetry.counter("train_iterations_total", algo="drf").inc(kk)
             chunks.append(tr_c)
             oob_sum, oob_cnt = oob_sum + osum, oob_cnt + ocnt

@@ -12,17 +12,32 @@ layout and its kernels stay for the shallow levels they fit. Below them
   holds its LIVE nodes, compact, in the order their parents split; its
   static capacity comes from the padded row count and the depth
   (``frontier_capacity``: a live node holds at least one row).
-- **growth**: a level pass orders the rows by node (one multi-operand
-  sort: the node id is the key, the packed bin ids, the three
-  statistics and the row id ride along), so a block of ``NODE_BLOCK``
-  consecutive nodes owns one contiguous run of rows. The run is cut into
+- **growth**: the rows are ordered by node on the first frontier level
+  and on every ``TreeParams.frontier_sort_every``-th after it (one
+  multi-operand sort: the node id is the key, the packed bin ids, the
+  statistics and the row id ride along), not at every level. What a
+  level's passes need of the order is that the rows of a block of
+  ``NODE_BLOCK`` consecutive nodes lie in one row range that holds few
+  rows of other blocks, and the slots are handed out so that this
+  outlives a level: children are numbered in the order their parents
+  split, so the descendants of a run of slots are a run of slots at
+  every later level, and their rows are the rows that lay in the
+  ancestors' range when the rows were last sorted. A block therefore
+  reads the range of its slots' ancestors at that sort (``block_ranges``:
+  two binary searches a block in the keys as the sort left them); on a
+  level that sorted, that is the block's own rows, and between sorts a
+  block shares at most the ancestor at each end of its range with its
+  neighbour, whose rows both scan (a row's LOCAL node id says whose it
+  is). The range is cut into
   chunks of ``CHUNK_ROWS`` rows; a chunk's histogram is the one-hot
   product of ``ops/histogram.py`` over the block's LOCAL node ids — the
   same ``stat_rows`` operand, three bfloat16 pieces a statistic, so
   every sum is a float32 sum. ``SUPER_BLOCKS`` blocks make a
   super-batch: its histogram ``[nodes, F, B, 3]`` goes through the ONE
   split rule (``tree._best_splits``) and is dropped, its rows are routed
-  to their children, and the next super-batch reuses the memory. A level
+  to their children — into the NEXT level's keys, a second array: the
+  keys a level's passes read are the ones it started with — and the
+  next super-batch reuses the memory. A level
   therefore costs ``rows / CHUNK_ROWS + live nodes / NODE_BLOCK`` chunk
   products and ``live nodes`` split scans, not 2^d of either.
 - **the per-node column draw** is a function of (tree key, the node's
@@ -30,11 +45,13 @@ layout and its kernels stay for the shallow levels they fit. Below them
 
 Rows whose node does not split are final: their sort key becomes the
 capacity plus the table index of that node, so they sort behind the live
-rows from then on and the key still says where they ended.
+rows at the next sort (until then they lie among them, and no block
+counts them) and the key still says where they ended.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -49,6 +66,13 @@ from h2o3_tpu.ops.histogram import piece_rows, stat_rows, sum_pieces
 CHUNK_ROWS = 8192
 NODE_BLOCK = 64
 SUPER_BLOCKS = 32
+# the forest fits sort the rows on every SORT_PERIOD-th frontier level
+# (models/drf.py hands it on as TreeParams.frontier_sort_every). On the
+# chip a 2-tree depth-20 job on 48M rows took 17.71 / 16.11 / 15.31 s at
+# 2 / 3 / 4, a sort 0.42 s, with 0.2 / 1.2 / 2.8% of the rows read
+# twice or final (PERF.md §6, PR 38); a regression forest's passes cost
+# three times the cell's a row, so the period stays small
+SORT_PERIOD = 4
 
 
 class DeepLevels(NamedTuple):
@@ -74,6 +98,8 @@ class DeepTree(NamedTuple):
     top: tuple
     deep: DeepLevels
     capped: jax.Array      # bool: a split was refused for want of slots
+    scanned: jax.Array     # [2] float32, summed over the frontier levels:
+                           # the rows the blocks' ranges held, the live rows
 
 
 class DeepForestError(NotImplementedError):
@@ -101,6 +127,40 @@ def _geometry(n_rows: int, lcap: int):
     lb = min(NODE_BLOCK, lcap)
     sb = min(SUPER_BLOCKS, lcap // lb)
     return min(CHUNK_ROWS, n_rows), lb, sb
+
+
+def sort_levels(n_levels: int, period: int) -> int:
+    """How many of a tree's ``n_levels`` frontier levels order the rows:
+    the first, and every ``period``-th after it."""
+    return -(-n_levels // period)
+
+
+def range_blocks(period: int, lb: int) -> int:
+    """How many blocks' ranges a row can lie in. A sort's node has up to
+    2^(period - 1) descendants before the next sort, a run of slots from
+    an even one; a pair of children never straddles a block of an even
+    ``lb``."""
+    run = 2 ** (period - 1)
+    return 1 if run <= 2 else (run - 3) // lb + 2
+
+
+def block_ranges(key_at_sort, anc, n_live, *, lb: int):
+    """``(lo, hi)`` [nblk] int32: block k — the live slots k·lb ..
+    min((k+1)·lb, n_live) - 1 — reads the rows [lo[k], hi[k]), the rows
+    of its first to its last slot's ancestor at the last sort:
+    ``key_at_sort`` [N] the keys as that sort left them (ascending),
+    ``anc`` [lcap] each live slot's slot at that sort (ascending over
+    the live slots; the identity on a level that sorted, where the
+    ranges are the blocks' own rows, end to end). A block with no live
+    slot gets an empty range."""
+    nblk = anc.shape[0] // lb
+    a = jnp.arange(nblk, dtype=jnp.int32) * lb
+    b = jnp.maximum(jnp.minimum(a + lb, n_live) - 1, 0)
+    at = jnp.searchsorted(
+        key_at_sort, jnp.concatenate([anc[a], anc[b] + 1]),
+        side="left").astype(jnp.int32)
+    lo, hi = at[:nblk], at[nblk:]
+    return jnp.where(a < n_live, lo, hi), hi
 
 
 def hist_plan(params, n_rows: int, n_features: int, n_stats: int):
@@ -150,17 +210,19 @@ def _unpack(words, f: int, bits: int):
             & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
 
 
-def chunk_product_hist(blk_start, s, fid, words, stats, *, lb: int, sb: int,
+def chunk_product_hist(lo, hi, s, fid, words, stats, *, lb: int, sb: int,
                        chunk: int, n_features: int, n_bins: int, bits: int,
                        n_pieces: int):
     """Super-batch ``s`` of a frontier level as float32 sums
     [sb·lb, F, B, S], in XLA: what ``treekernel.frontier_hist`` computes
-    where no kernel runs, and the oracle it is held to. Block k's rows
-    [blk_start[k], blk_start[k+1]) of the node-sorted ``fid`` / ``words``
-    / ``stats`` are cut into chunks of ``chunk`` rows from the block's
-    first (the arrays end in ``chunk`` rows that belong to no node, so a
-    chunk cut at any row stays inside); a chunk's histogram is the
-    one-hot product of ops/histogram.py over the block's LOCAL node ids."""
+    where no kernel runs, and the oracle it is held to. Block k's range
+    [lo[k], hi[k]) of ``fid`` / ``words`` / ``stats`` (``block_ranges``:
+    it holds every row of the block's nodes, and may hold rows of other
+    blocks' and final rows) is cut into chunks of ``chunk`` rows from
+    its first (the arrays end in ``chunk`` rows that belong to no node,
+    so a chunk cut at any row stays inside); a chunk's histogram is the
+    one-hot product of ops/histogram.py over the block's LOCAL node ids,
+    which no row of another block has."""
     F, B, S = n_features, n_bins, len(stats)
     FB = F * B
     PR = piece_rows(lb, S, n_pieces)
@@ -200,8 +262,7 @@ def chunk_product_hist(blk_start, s, fid, words, stats, *, lb: int, sb: int,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     def block(kk, hist):
-        r0 = blk_start[k0 + kk]
-        r1 = blk_start[k0 + kk + 1]
+        r0, r1 = lo[k0 + kk], hi[k0 + kk]
         a = jax.lax.fori_loop(
             0, (r1 - r0 + chunk - 1) // chunk,
             lambda c, a: a + chunk_hist(r0 + c * chunk, k0 + kk),
@@ -227,19 +288,24 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
     A level's histogram pass is the Pallas kernel ``tree_frontier_hist``
     (ops/pallas/treekernel.frontier_hist) where ``params.pallas`` asks
     for kernels and a tile fits, and the XLA chunk product elsewhere.
+    The rows are sorted by node on level K and on every
+    ``params.frontier_sort_every``-th level after it: a scan over groups
+    of that many levels, the sort at the head of a group.
 
-    Returns ``(DeepLevels, ref, gains, capped)``: ``ref`` [N] the flat
-    index (level - K) * Lcap + slot of every row's final node; ``capped``
-    whether a split was refused for want of node slots (never, by
-    ``frontier_capacity``; counted all the same)."""
+    Returns ``(DeepLevels, ref, gains, capped, scanned)``: ``ref`` [N]
+    the flat index (level - K) * Lcap + slot of every row's final node;
+    ``capped`` whether a split was refused for want of node slots (never,
+    by ``frontier_capacity``; counted all the same); ``scanned`` [2] the
+    rows the blocks' ranges held and the live rows, summed over the
+    levels (``DeepTree.scanned``)."""
     from h2o3_tpu.models import tree as T
     D, B = params.max_depth, params.nbins_total
     N, F = bins.shape
     lcap = frontier_capacity(N, D)
     C, LB, SB = _geometry(N, lcap)
     SBN = LB * SB
-    nblk = lcap // LB
     nlev = D - K
+    period = min(params.frontier_sort_every, nlev)
     ns = len(stats)
     tile, pieces = hist_plan(params, N, F, ns)
     W = max(1, (B - 1 + 31) // 32) if params.has_cats else 1
@@ -291,36 +357,41 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
             table, onehot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(jnp.int32)
 
-    def level(carry, d):
-        (fid, rid, words, stats, path, may, n_live, last) = carry
+    def level(ordered, carry, d):
+        """Level ``d`` on the rows as the group's sort left them:
+        ``ordered`` = (the keys then, the bin words, the statistics)."""
+        key_at_sort, words, stats = ordered
+        fid, anc, path, may, n_live, last = carry
+        # the last group may run past the depth: such a level has no
+        # live node, its tables are dropped and it hands on what it got
+        real = d < D
+        n_live = jnp.where(real, n_live, 0)
         with jax.named_scope("tree.frontier.route"):
-            out = jax.lax.sort((fid, rid) + words + stats, num_keys=1,
-                               is_stable=False)
-            fid, rid = out[:2]
-            words, stats = tuple(out[2:2 + nw]), tuple(out[2 + nw:])
-            blk_start = jnp.searchsorted(
-                fid, jnp.arange(nblk + 1, dtype=jnp.int32) * LB,
-                side="left").astype(jnp.int32)
+            lo, hi = block_ranges(key_at_sort, anc, n_live, lb=LB)
+            scanned = jnp.stack([
+                jnp.sum((hi - lo).astype(jnp.float32)),
+                jnp.sum(fid < lcap, dtype=jnp.float32)])
             if tile:
                 sched = treekernel.frontier_schedule(
-                    blk_start, tile, fid.shape[0] // tile)
+                    lo, hi, tile, fid.shape[0] // tile,
+                    range_blocks(period, LB))
         n_sb = (n_live + SBN - 1) // SBN
         heap0 = jnp.left_shift(jnp.int32(1), d)
 
         def superbatch(s, acc):
-            fid, tabs, nsplit, gains, capped = acc
+            fid_next, tabs, nsplit, gains, capped = acc
             k0 = s * SB
             n0 = k0 * LB
 
             with jax.named_scope("tree.frontier.hist"):
                 if tile:
                     hist = treekernel.frontier_hist(
-                        sched, blk_start, s, fid, words, stats, lb=LB, sb=SB,
+                        sched, lo, hi, s, fid, words, stats, lb=LB, sb=SB,
                         n_features=F, n_bins=B, bits=bits, n_pieces=pieces,
                         tile=tile, interpret=params.pallas == "interpret")
                 else:
                     hist = chunk_product_hist(
-                        blk_start, s, fid, words, stats, lb=LB, sb=SB,
+                        lo, hi, s, fid, words, stats, lb=LB, sb=SB,
                         chunk=C, n_features=F, n_bins=B, bits=bits,
                         n_pieces=pieces)
                 if ns == 2:
@@ -378,9 +449,9 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
             with jax.named_scope("tree.frontier.route"):
                 done = lcap + (d - K) * lcap     # + slot: a final key
 
-                def block_route(kk, fid):
+                def block_route(kk, fid_next):
                     k = k0 + kk
-                    r0, r1 = blk_start[k], blk_start[k + 1]
+                    r0, r1 = lo[k], hi[k]
 
                     def tab(n):
                         return jax.lax.dynamic_slice(
@@ -392,7 +463,7 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
                     table = block_table(tab("feat"), tab("thresh"), flags,
                                         tab("child"), tab("left_words"))
 
-                    def chunk(c, fid):
+                    def chunk(c, fid_next):
                         at = r0 + c * C
 
                         def cut(v):
@@ -424,12 +495,15 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
                         f_new = jnp.where(
                             (fl & 1) == 1, ch + jnp.where(go, 0, 1),
                             done + f_old)
+                        # a row of another block's keeps what that
+                        # block's pass wrote, or will
                         return jax.lax.dynamic_update_slice(
-                            fid, jnp.where(mine, f_new, f_old), (at,))
+                            fid_next,
+                            jnp.where(mine, f_new, cut(fid_next)), (at,))
                     return jax.lax.fori_loop(0, (r1 - r0 + C - 1) // C,
-                                             chunk, fid)
-                fid = jax.lax.fori_loop(0, SB, block_route, fid)
-            return fid, tabs, nsplit, gains, capped
+                                             chunk, fid_next)
+                fid_next = jax.lax.fori_loop(0, SB, block_route, fid_next)
+            return fid_next, tabs, nsplit, gains, capped
 
         tabs0 = dict(
             feat=jnp.zeros((lcap,), jnp.int32),
@@ -445,7 +519,9 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
             rval=jnp.zeros((lcap,), jnp.float32),
             lwt=jnp.zeros((lcap,), jnp.float32),
             rwt=jnp.zeros((lcap,), jnp.float32))
-        fid, tabs, nsplit, gains, capped = jax.lax.fori_loop(
+        # the next level's keys start as this level's: a final row
+        # keeps its key
+        fid_next, tabs, nsplit, gains, capped = jax.lax.fori_loop(
             0, n_sb, superbatch,
             (fid, tabs0, jnp.int32(0), jnp.zeros((F,), jnp.float32),
              jnp.bool_(False)))
@@ -464,16 +540,36 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
                    weight=to_children(tabs["lwt"], tabs["rwt"], 0.0))
         level_out = {n: tabs[n] for n in DeepLevels._fields if n != "path"}
         level_out["path"] = path
-        carry = (fid, rid, words, stats, nxt_path,
-                 nxt_path >= 0, 2 * nsplit, nxt)
-        return carry, (level_out, gains, capped)
+        nodes = jax.tree.map(
+            lambda new, old: jnp.where(real, new, old),
+            (to_children(anc, anc, 0), nxt_path, nxt_path >= 0, 2 * nsplit,
+             nxt), (anc, path, may, n_live, last))
+        return (fid_next,) + nodes, (level_out, gains, capped, scanned)
+
+    def group(carry, d0):
+        """``period`` levels from ``d0`` on ONE order of the rows: the
+        program's one sort of the rows (it alone compiles for minutes)
+        is the head of the group, the level's body its loop."""
+        fid, rid, words, stats, *nodes = carry
+        with jax.named_scope("tree.frontier.route"):
+            rows = jax.lax.sort((fid, rid) + words + stats, num_keys=1,
+                                is_stable=False)
+        fid, rid = rows[:2]
+        words, stats = tuple(rows[2:2 + nw]), tuple(rows[2 + nw:])
+        (fid, _, *nodes), out = jax.lax.scan(
+            partial(level, (fid, words, stats)), (fid, slots, *nodes),
+            d0 + jnp.arange(period, dtype=jnp.int32))
+        return (fid, rid, words, stats, *nodes), out
 
     last0 = dict(value=jnp.zeros((lcap,), jnp.float32),
                  weight=jnp.zeros((lcap,), jnp.float32))
-    carry, (levels, gains, capped) = jax.lax.scan(
-        level, (fid, rid, words, stats, path0, may0,
-                jnp.int32(2 ** K), last0),
-        jnp.arange(K, D, dtype=jnp.int32))
+    carry, out = jax.lax.scan(
+        group, (fid, rid, words, stats, path0, may0, jnp.int32(2 ** K),
+                last0),
+        K + period * jnp.arange(sort_levels(nlev, period), dtype=jnp.int32))
+    # [groups, period, ...] → the tree's levels
+    levels, gains, capped, scanned = jax.tree.map(
+        lambda a: a.reshape((-1,) + a.shape[2:])[:nlev], out)
     fid, rid, _, _, path_d, _, _, last = carry
 
     # level D: leaves alone, valued from their parents' split sums
@@ -494,7 +590,8 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
         ref = jnp.where(fid < lcap, nlev * lcap + fid, fid - lcap)
         # back to the frame's row order
         ref = jnp.zeros((N,), jnp.int32).at[rid].set(ref, mode="drop")
-    return deep, ref, jnp.sum(gains, axis=0), jnp.any(capped)
+    return (deep, ref, jnp.sum(gains, axis=0), jnp.any(capped),
+            jnp.sum(scanned, axis=0))
 
 
 def route_deep(tree: DeepTree, bins, B: int):
@@ -540,7 +637,10 @@ def forest_facts(forest) -> dict:
     """Counted facts of a stacked forest, on the device: ``depth`` (the
     deepest split's level + 1), ``leaves`` (all trees), ``nodes_max``
     (the widest frontier level's live nodes; 0 for a complete forest),
-    ``capped`` (trees that were refused a split for want of slots)."""
+    ``capped`` (trees that were refused a split for want of slots),
+    ``rescan_pct`` (of the rows the frontier levels' blocks read, the
+    share beyond the live rows: rows that lie in two blocks' ranges
+    between sorts, and final rows still lying among the live ones)."""
     def deepest(is_split):                  # [T, levels, L] → levels + 1
         lv = jnp.any(is_split, axis=(0, 2))
         return jnp.max(jnp.where(
@@ -548,7 +648,8 @@ def forest_facts(forest) -> dict:
     if not isinstance(forest, DeepTree):
         return dict(depth=deepest(forest.is_split),
                     leaves=jnp.sum(forest.leaf_w > 0, dtype=jnp.int32),
-                    nodes_max=jnp.int32(0), capped=jnp.int32(0))
+                    nodes_max=jnp.int32(0), capped=jnp.int32(0),
+                    rescan_pct=jnp.float32(0))
     deep = forest.deep
     K = forest.top.feat.shape[1]
     live = (deep.path >= 0) & (deep.weight > 0)
@@ -558,7 +659,9 @@ def forest_facts(forest) -> dict:
                         deepest(forest.top.is_split)),
         leaves=jnp.sum(live & ~deep.is_split, dtype=jnp.int32),
         nodes_max=jnp.max(jnp.sum(live, axis=2, dtype=jnp.int32)),
-        capped=jnp.sum(forest.capped, dtype=jnp.int32))
+        capped=jnp.sum(forest.capped, dtype=jnp.int32),
+        rescan_pct=100.0 * jnp.sum(forest.scanned[:, 0])
+        / jnp.maximum(jnp.sum(forest.scanned[:, 1]), 1.0) - 100.0)
 
 
 def realized_depth(forest) -> int:

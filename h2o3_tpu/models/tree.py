@@ -147,6 +147,10 @@ class TreeParams:
                                      # the depth passes it; 0 = complete
                                      # layout all the way (GBM: its depths
                                      # fit the layout, ROADMAP R4)
+    frontier_sort_every: int = 1     # the frontier levels between two
+                                     # sorts of the rows by node, the
+                                     # sorting one counted: the forest
+                                     # fits hand on frontier.SORT_PERIOD
     whole_stats: bool = False        # the fit KNOWS every statistic of a
                                      # row is 0 or ±1 (whole weights on a
                                      # class indicator): the frontier's
@@ -513,11 +517,11 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
         empty = jnp.zeros((2 ** D,), jnp.float32)
         top = Tree(feats, threshs, na_lefts, is_splits, empty, empty,
                    cat_splits, left_words)
-        deep, ref, gains, capped = frontier.grow_frontier(
+        deep, ref, gains, capped, scanned = frontier.grow_frontier(
             bins, nb, nid, (w, w * g) if unit_h else (w, w * g, w * h),
             alive, key, col_mask, params=params, K=D, sc=sc, mtries=mtries,
             is_cat=is_cat)
-        return (frontier.DeepTree(top, deep, capped), ref,
+        return (frontier.DeepTree(top, deep, capped, scanned), ref,
                 gain_by_feat + gains)
 
     # leaf Newton values from final assignment (GammaPass analogue)

@@ -283,41 +283,45 @@ def _partition_call(bins, nid, bf, bt, bnal, isp, cs, lmask, *, n_bins,
 
 # ------------------------------------------- the frontier's histogram pass
 #
-# models/frontier.py orders a level's rows by node, so a block of
-# ``lb`` nodes owns one contiguous run of rows, wherever it starts. The
-# kernel streams ALIGNED row tiles and a schedule (frontier_schedule)
-# says, for each grid step, which tile it reads and which block it adds
-# to — the grouped-matmul pattern (jax.experimental.pallas.ops.tpu.
-# megablox.gmm): a tile that straddles blocks is visited once a block,
-# and the rows outside the block's run drop out of the step (their
-# local node id is set to none: a row of an earlier super-batch has been
-# routed since the sort, and its new key may fall in this block's range).
+# models/frontier.py keeps a level's rows ordered by node, or by an
+# ancestor of their node, so the rows of a block of ``lb`` nodes lie in
+# one row range (frontier.block_ranges), wherever it starts; between two
+# sorts the range also holds final rows and may share rows with the
+# neighbouring blocks'. The kernel streams ALIGNED row tiles and a
+# schedule (frontier_schedule) says, for each grid step, which tile it
+# reads and which block it adds to — the grouped-matmul pattern
+# (jax.experimental.pallas.ops.tpu.megablox.gmm): a tile that straddles
+# ranges is visited once a block, and a row counts for the block whose
+# LOCAL node id it has (the keys are the level's own from its first
+# super-batch to its last: routing writes the next level's elsewhere).
 
 
-def frontier_schedule(blk_start, tile: int, n_tiles: int):
-    """The level's steps from ``blk_start`` [nblk + 1] (block k owns the
-    sorted rows [blk_start[k], blk_start[k+1])): ``(step0, blk, tid)`` —
-    block k takes the steps [step0[k], step0[k+1]), one for each tile
-    its rows touch, and ONE where it has no row (its histogram still has
-    to be zeroed); step i adds tile ``tid[i]`` to block ``blk[i]``. The
-    step arrays have the static length ``n_tiles + nblk`` that no level
-    can pass; the steps that exist are the first ``step0[nblk]``."""
-    nblk = blk_start.shape[0] - 1
-    r0, r1 = blk_start[:-1], blk_start[1:]
-    first = jnp.minimum(r0 // tile, n_tiles - 1)
-    count = jnp.where(r1 > r0, (r1 - 1) // tile - first + 1, 1)
+def frontier_schedule(lo, hi, tile: int, n_tiles: int, overlap: int = 1):
+    """The level's steps from the blocks' row ranges [lo[k], hi[k])
+    ([nblk] each, ascending; a row lies in at most ``overlap`` of them,
+    frontier.range_blocks): ``(step0, blk, tid)`` — block k takes the
+    steps [step0[k], step0[k+1]), one for each tile its range touches,
+    and ONE where the range is empty (its histogram still has to be
+    zeroed); step i adds tile ``tid[i]`` to block ``blk[i]``. The step
+    arrays have the static length ``overlap * n_tiles + nblk`` that no
+    level can pass (the blocks k, k + overlap, … share no row, so their
+    steps are at most a step a tile and one more a block); the steps
+    that exist are the first ``step0[nblk]``."""
+    nblk = lo.shape[0]
+    first = jnp.minimum(lo // tile, n_tiles - 1)
+    count = jnp.where(hi > lo, (hi - 1) // tile - first + 1, 1)
     step0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                              jnp.cumsum(count, dtype=jnp.int32)])
-    i = jnp.arange(n_tiles + nblk, dtype=jnp.int32)
+    i = jnp.arange(overlap * n_tiles + nblk, dtype=jnp.int32)
     blk = jnp.clip(jnp.searchsorted(step0, i, side="right").astype(jnp.int32)
                    - 1, 0, nblk - 1)
     tid = jnp.minimum(first[blk] + i - step0[blk], n_tiles - 1)
     return step0, blk, tid
 
 
-def _frontier_hist_kernel(meta_ref, blk_ref, tid_ref, start_ref, fid_ref,
-                          *refs, lb: int, n_features: int, n_bins: int,
-                          bits: int, n_words: int, n_stats: int,
+def _frontier_hist_kernel(meta_ref, blk_ref, tid_ref, lo_ref, hi_ref,
+                          fid_ref, *refs, lb: int, n_features: int,
+                          n_bins: int, bits: int, n_words: int, n_stats: int,
                           n_pieces: int):
     word_refs = refs[:n_words]
     stat_refs = refs[n_words:n_words + n_stats]
@@ -332,7 +336,7 @@ def _frontier_hist_kernel(meta_ref, blk_ref, tid_ref, start_ref, fid_ref,
 
     T = fid_ref.shape[1]
     at_row = tid_ref[at] * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-    mine = (at_row >= start_ref[blk]) & (at_row < start_ref[blk + 1])
+    mine = (at_row >= lo_ref[blk]) & (at_row < hi_ref[blk])
     lid = jnp.where(mine, fid_ref[...] - blk * lb, -1)           # [1, T]
     # the (feature, bin) indicator with the rows on the lanes: feature
     # f's bins are the sublanes [f·Bp, f·Bp + B) — one compare of the
@@ -356,18 +360,17 @@ def _frontier_hist_kernel(meta_ref, blk_ref, tid_ref, start_ref, fid_ref,
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def frontier_hist(sched, blk_start, s, fid, words, stats, *, lb: int, sb: int,
+def frontier_hist(sched, lo, hi, s, fid, words, stats, *, lb: int, sb: int,
                   n_features: int, n_bins: int, bits: int, n_pieces: int,
                   tile: int, interpret: bool):
     """Super-batch ``s`` of a frontier level — the blocks s·sb ..
-    (s+1)·sb - 1 of ``lb`` nodes — as float32 sums [sb·lb, F, B, S]
-    over the node-sorted rows: ``fid`` [N] the rows' node slots (the
-    sort key), ``words`` their packed bin ids ([N] uint32 each, ``bits``
-    a bin), ``stats`` their S statistics ([N] float32 each); N a
-    multiple of ``tile``. ``sched`` is the level's ``frontier_schedule``
-    of ``blk_start``; the grid is the super-batch's own steps, a traced
-    count. ``fid`` need hold the sort's keys only in the super-batch's
-    own rows."""
+    (s+1)·sb - 1 of ``lb`` nodes — as float32 sums [sb·lb, F, B, S]:
+    ``fid`` [N] the rows' node slots at this level, ``words`` their
+    packed bin ids ([N] uint32 each, ``bits`` a bin), ``stats`` their S
+    statistics ([N] float32 each); N a multiple of ``tile``. Block k's
+    rows all lie in [lo[k], hi[k]) (frontier.block_ranges) and ``sched``
+    is the level's ``frontier_schedule`` of those ranges; the grid is
+    the super-batch's own steps, a traced count."""
     step0, blk, tid = sched
     N = fid.shape[0]
     assert N % tile == 0, (N, tile)
@@ -377,7 +380,7 @@ def frontier_hist(sched, blk_start, s, fid, words, stats, *, lb: int, sb: int,
     k0 = s * sb
     off = step0[k0]
     meta = jnp.stack([off, k0]).astype(jnp.int32)
-    row = pl.BlockSpec((1, tile), lambda i, meta, blk, tid, start:
+    row = pl.BlockSpec((1, tile), lambda i, meta, blk, tid, lo, hi:
                        (0, tid[meta[0] + i]))
     rows = [fid, *words, *stats]
     pallas_policy.record_launch("tree_frontier_hist")
@@ -386,17 +389,17 @@ def frontier_hist(sched, blk_start, s, fid, words, stats, *, lb: int, sb: int,
             _frontier_hist_kernel, lb=lb, n_features=F, n_bins=B,
             bits=bits, n_words=len(words), n_stats=S, n_pieces=n_pieces),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(step0[k0 + sb] - off,),
             in_specs=[row] * len(rows),
             out_specs=pl.BlockSpec(
-                (None, M, F * Bp), lambda i, meta, blk, tid, start:
+                (None, M, F * Bp), lambda i, meta, blk, tid, lo, hi:
                 (blk[meta[0] + i] - meta[1], 0, 0))),
         out_shape=jax.ShapeDtypeStruct((sb, M, F * Bp), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name="tree_frontier_hist",
-    )(meta, blk, tid, blk_start, *(r[None, :] for r in rows))
+    )(meta, blk, tid, lo, hi, *(r[None, :] for r in rows))
     # [sb, M, F·Bp] rows piece·S·lb + S·node + stat → [nodes, F, B, S]
     sums = jax.vmap(lambda a: sum_pieces(a, lb, S, n_pieces))(acc)
     return sums.reshape(sb * lb, S, F, Bp)[..., :B].transpose(0, 2, 3, 1)

@@ -216,14 +216,16 @@ def test_tree_frontier_hist_kernel_native(topo, n_stats, n_pieces):
     n += -n % tile
     vec = lambda k, dt=jnp.int32: S((k,), dt, sharding=one)   # noqa: E731
 
-    def call(step0, blk, tid, blk_start, s, fid, w0, w1, w2, *stats):
+    def call(step0, blk, tid, lo, hi, s, fid, w0, w1, w2, *stats):
         return tk.frontier_hist(
-            (step0, blk, tid), blk_start, s, fid, (w0, w1, w2), stats,
+            (step0, blk, tid), lo, hi, s, fid, (w0, w1, w2), stats,
             lb=lb, sb=sb, n_features=F, n_bins=B, bits=8,
             n_pieces=n_pieces, tile=tile, interpret=False)
-    steps = n // tile + nblk
+    # the schedule of a level between two sorts: a row in two ranges
+    steps = frontier.range_blocks(frontier.SORT_PERIOD, lb) * (n // tile) \
+        + nblk
     out = jax.jit(call).lower(
-        vec(nblk + 1), vec(steps), vec(steps), vec(nblk + 1),
+        vec(nblk + 1), vec(steps), vec(steps), vec(nblk), vec(nblk),
         S((), jnp.int32, sharding=one), vec(n),
         *[vec(n, jnp.uint32)] * 3, *[vec(n, jnp.float32)] * n_stats
     ).compile()

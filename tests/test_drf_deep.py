@@ -3,12 +3,16 @@ complete layout in the frontier regime (models/frontier.py) — whole, to
 ``max_depth``, bit-equal to the complete layout where both can grow a
 tree, chunk after chunk and restart after restart — and the readers of
 the complete ``Tree`` that were not carried over raise a named error on a
-forest that is really deeper than that layout holds. Small frames only:
-no test here grows a tree on more than a few thousand rows."""
+forest that is really deeper than that layout holds. The rows are
+ordered by node once every few frontier levels (PR 38): the trees and
+every row's final node are those of a sort at every level. Small frames
+only: no test here grows a tree on more than a few thousand rows."""
 
 import dataclasses
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -135,6 +139,10 @@ def two_regimes(mixed_frame, request):
     assert chunk["frontier_hist"] == (
         "kernel" if request.param == "interpret" else "xla")
     assert chunk["levels_frontier"] == 7 and chunk["hist_operand_rows"] == 2
+    # how often the rows were ordered, and what the order in between cost
+    assert chunk["levels_sorted"] == frontier.sort_levels(
+        7, frontier.SORT_PERIOD) < 7
+    assert 0 < chunk["frontier_rescan_pct"] < 100
     return fits[0], fits[3]
 
 
@@ -285,3 +293,119 @@ def test_frontier_capacity_comes_from_rows_and_depth():
     assert frontier.complete_levels(100, 20, 9) == 7
     assert frontier.complete_levels(3000, 6, 9) == 6
     assert frontier.complete_levels(3000, 20, 0) == 20
+
+
+# ------------------------------------------ a sort every few levels (PR 38)
+
+FROM, DEPTH = 3, 14             # 11 frontier levels: 2 * 4 + 1 and two more
+
+
+def _grow(bm, y, *, period, pallas="off", whole=True, seed=0):
+    """One bagged tree of ``models/drf._bag_body``'s kind straight from
+    ``grow_tree`` → (DeepTree, ref): the frontier regime from level 3,
+    a sort every ``period`` levels."""
+    from h2o3_tpu.parallel.mesh import get_mesh
+    tp = tree_mod.TreeParams(
+        max_depth=DEPTH, min_rows=1.0, learn_rate=1.0, reg_lambda=0.0,
+        min_split_improvement=1e-5, nbins_total=bm.nbins_total,
+        cat_feats=tuple(bool(v) for v in bm.is_cat), pallas=pallas,
+        frontier_from=FROM, frontier_sort_every=period, whole_stats=whole)
+    kb, kt = jax.random.split(jax.random.PRNGKey(seed))
+    n = bm.bins.shape[0]
+    w = jax.random.bernoulli(kb, 0.632, (n,)).astype(jnp.float32) \
+        * (jnp.arange(n) < len(y))
+    g = -jnp.zeros((n,), jnp.float32).at[: len(y)].set(
+        jnp.asarray(y, jnp.float32))
+    grown, ref, _ = jax.jit(lambda: tree_mod.grow_tree(
+        bm.bins, bm.nbins, w, g, None, jnp.ones((bm.bins.shape[1],), bool),
+        params=tp, mesh=get_mesh(), mtries=2, key=kt))()
+    return grown, np.asarray(ref)
+
+
+@pytest.mark.parametrize("pallas", ["off", "interpret"])
+def test_a_sort_every_few_levels_grows_the_same_tree(default_forest,
+                                                     mixed_frame, pallas):
+    assert frontier.SORT_PERIOD > 1
+    assert DEPTH - FROM >= 2 * frontier.SORT_PERIOD + 1
+    bm, y = default_forest.bm, mixed_frame.col("y").to_numpy()
+    each, ref_each = _grow(bm, y, period=1, pallas=pallas)
+    some, ref_some = _grow(bm, y, period=frontier.SORT_PERIOD,
+                           pallas=pallas)
+    assert int(np.asarray(each.deep.is_split).any(axis=1).sum()) \
+        == DEPTH - FROM
+    assert _same(each.top, some.top) and _same(each.deep, some.deep)
+    assert np.array_equal(ref_each, ref_some)
+    # a sort at every level reads the live rows, once
+    assert each.scanned[0] == each.scanned[1] > 0
+    assert some.scanned[0] > some.scanned[1] == each.scanned[1]
+
+
+def test_a_shared_ancestor_across_a_super_batch_boundary(
+        default_forest, mixed_frame, monkeypatch):
+    """Blocks of 4 nodes, super-batches of 2 blocks, chunks of 64 rows:
+    3,000 rows cross dozens of super-batches a level, and three levels
+    after a sort an ancestor's 8 descendants lie in up to three blocks —
+    the rows a super-batch routes lie in the ranges of the next one's
+    blocks too."""
+    monkeypatch.setattr(frontier, "NODE_BLOCK", 4)
+    monkeypatch.setattr(frontier, "SUPER_BLOCKS", 2)
+    monkeypatch.setattr(frontier, "CHUNK_ROWS", 64)
+    assert frontier.range_blocks(4, 4) == 3
+    bm, y = default_forest.bm, mixed_frame.col("y").to_numpy()
+    for pallas in ("off", "interpret"):
+        each, ref_each = _grow(bm, y, period=1, pallas=pallas, seed=1)
+        some, ref_some = _grow(bm, y, period=4, pallas=pallas, seed=1)
+        live = np.asarray(each.deep.path >= 0).sum(axis=1)
+        assert live.max() > 6 * 8       # super-batches of 8 nodes
+        assert _same(each.deep, some.deep)
+        assert np.array_equal(ref_each, ref_some)
+        assert some.scanned[0] > 1.1 * some.scanned[1]
+
+
+def test_a_regression_tree_within_the_references_limits(default_forest,
+                                                        mixed_frame):
+    """Real statistics: three pieces a statistic, float32 sums that
+    depend on the order of a block's rows — the same splits, values and
+    weights within ``benchmark/references/drf.py``'s ``leaf_gap``."""
+    bm = default_forest.bm
+    z = mixed_frame.col("x2").to_numpy() + mixed_frame.col("y").to_numpy()
+    each, ref_each = _grow(bm, z, period=1, whole=False)
+    some, ref_some = _grow(bm, z, period=frontier.SORT_PERIOD, whole=False)
+    for name in ("feat", "thresh", "na_left", "is_split", "cat_split",
+                 "left_words", "child", "path"):
+        assert np.array_equal(np.asarray(getattr(each.deep, name)),
+                              np.asarray(getattr(some.deep, name))), name
+    assert np.array_equal(ref_each, ref_some)
+    for name in ("value", "weight"):
+        a, b = (np.asarray(getattr(t.deep, name)) for t in (each, some))
+        assert np.max(np.abs(a - b) / np.maximum(np.abs(a), 1.0)) <= 1e-5
+
+
+def test_the_lowered_frontier_holds_one_sort_of_the_rows(default_forest,
+                                                         mixed_frame):
+    """Lowered, not compiled: the sort of the rows — seven operands at
+    the airlines widths, minutes of compiling on the chip — is in the
+    program once, whatever the period."""
+    from h2o3_tpu.models.tree import scalars_of
+    bm = default_forest.bm
+    n, F = bm.bins.shape
+    tp = tree_mod.TreeParams(
+        max_depth=DEPTH, nbins_total=bm.nbins_total,
+        cat_feats=tuple(bool(v) for v in bm.is_cat), frontier_from=FROM,
+        frontier_sort_every=frontier.SORT_PERIOD, whole_stats=True)
+    is_cat = jnp.asarray(np.asarray(tp.cat_feats))
+
+    def frontier_levels(bins, nb, nid, w, wg, key):
+        return frontier.grow_frontier(
+            bins, nb, nid, (w, wg), jnp.ones((2 ** FROM,), bool), key,
+            jnp.ones((F,), bool), params=tp, K=FROM, sc=scalars_of(tp),
+            mtries=2, is_cat=is_cat)
+    text = jax.jit(frontier_levels).lower(
+        bm.bins, bm.nbins, jnp.zeros((n,), jnp.int32),
+        jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+        jax.random.PRNGKey(0)).as_text()
+    sorts = [len(re.findall(r"%", m.group(1))) for m in re.finditer(
+        r'"?stablehlo\.sort"?\(([^)]*)\)', text)]
+    assert sorts, "no sort in the lowered text"
+    # (key, row id, the packed bin words, two statistics)
+    assert [k for k in sorts if k > 2] == [2 + -(-F // 4) + 2]

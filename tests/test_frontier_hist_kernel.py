@@ -2,7 +2,10 @@
 ``treekernel.frontier_hist`` against the XLA chunk product it replaces
 (``frontier.chunk_product_hist``), in interpret mode, on a schedule as
 ragged as a level's can be; the statistics operand at any number of
-statistics and pieces; the tile's arithmetic. Tiny shapes only."""
+statistics and pieces; the tile's arithmetic. The blocks' row ranges on
+a level that sorted its rows and on one that did not (PR 38:
+``frontier.block_ranges``), and the kernel on ranges that overlap. Tiny
+shapes only."""
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +64,7 @@ def _by_hand(fid, bins, stats, s, B):
 def test_the_schedule_is_as_ragged_as_meant():
     _, _, _, _, blk_start, _ = _level(3, 5, 2, True)
     step0, blk, tid = (np.asarray(a) for a in tk.frontier_schedule(
-        blk_start, TILE, 1024 // TILE))
+        blk_start[:-1], blk_start[1:], TILE, 1024 // TILE))
     steps = step0[-1]
     assert list(np.diff(step0)[:3]) == [1, 3, 1]      # empty; three tiles
     assert sorted(set(blk[:steps][tid[:steps] == 2])) == [1, 2, 3, 4, 5]
@@ -81,17 +84,18 @@ def test_the_kernel_is_the_chunk_product_bit_for_bit(n_stats, n_pieces):
     F, B = 5, 21
     fid, words, bits, stats, blk_start, bins = _level(
         F, B, n_stats, whole=n_pieces == 1)
-    sched = tk.frontier_schedule(blk_start, TILE, fid.shape[0] // TILE)
+    lo, hi = blk_start[:-1], blk_start[1:]
+    sched = tk.frontier_schedule(lo, hi, TILE, fid.shape[0] // TILE)
     geo = dict(lb=LB, sb=SB, n_features=F, n_bins=B, bits=bits,
                n_pieces=n_pieces)
     kernel = jax.jit(lambda s, fid: tk.frontier_hist(
-        sched, blk_start, s, fid, words, stats, tile=TILE, interpret=True,
+        sched, lo, hi, s, fid, words, stats, tile=TILE, interpret=True,
         **geo))
     xla = jax.jit(lambda s, fid: frontier.chunk_product_hist(
-        blk_start, s, fid, words, stats, chunk=CHUNK, **geo))
+        lo, hi, s, fid, words, stats, chunk=CHUNK, **geo))
     for s in range(3):
-        # the rows of earlier super-batches have been routed since the
-        # sort: their keys may name any node, one of this super-batch too
+        # a row outside a block's range counts for nothing there,
+        # whatever node its key names
         routed = jnp.where(jnp.arange(fid.shape[0]) < blk_start[s * SB],
                            s * SB * LB + 1, fid)
         got = np.asarray(kernel(jnp.int32(s), routed))
@@ -107,13 +111,128 @@ def test_sixteen_bit_bin_ids():
     F, B = 3, 300
     fid, words, bits, stats, blk_start, bins = _level(F, B, 2, True, seed=1)
     assert bits == 16 and len(words) == 2
-    sched = tk.frontier_schedule(blk_start, TILE, fid.shape[0] // TILE)
+    lo, hi = blk_start[:-1], blk_start[1:]
+    sched = tk.frontier_schedule(lo, hi, TILE, fid.shape[0] // TILE)
     got = jax.jit(lambda: tk.frontier_hist(
-        sched, blk_start, jnp.int32(1), fid, words, stats, lb=LB, sb=SB,
+        sched, lo, hi, jnp.int32(1), fid, words, stats, lb=LB, sb=SB,
         n_features=F, n_bins=B, bits=bits, n_pieces=1, tile=TILE,
         interpret=True))()
     assert np.array_equal(np.asarray(got), _by_hand(
         np.asarray(fid), bins, [np.asarray(v) for v in stats], 1, B))
+
+
+def _grown_on(fid, n_live, levels, seed=0):
+    """``levels`` levels of growth from a sorted level without moving a
+    row, in numpy: a slot splits nine times in ten, its rows go left
+    or right by a coin, the children are numbered in the order their
+    parents split (frontier.grow_frontier). → (fid, anc, n_live)."""
+    r = np.random.default_rng(seed)
+    fid, anc = fid.copy(), np.arange(LCAP, dtype=np.int32)
+    for _ in range(levels):
+        split = r.random(n_live) < 0.9
+        child = 2 * (np.cumsum(split) - split)
+        live = np.nonzero(fid < LCAP)[0]
+        slot = fid[live]
+        fid[live] = np.where(split[slot],
+                             child[slot] + r.integers(0, 2, len(live)),
+                             LCAP + slot)
+        nxt = np.zeros(LCAP, np.int32)
+        nxt[child[split]] = nxt[child[split] + 1] = anc[:n_live][split]
+        anc, n_live = nxt, 2 * int(split.sum())
+    return fid, anc, n_live
+
+
+def test_block_ranges_on_a_level_that_sorted_are_the_blocks_own_rows():
+    fid, _, _, _, blk_start, _ = _level(3, 5, 2, True)
+    lo, hi = frontier.block_ranges(
+        fid, jnp.arange(LCAP, dtype=jnp.int32), jnp.int32(10 * LB), lb=LB)
+    assert np.array_equal(np.asarray(lo), np.asarray(blk_start[:-1]))
+    assert np.array_equal(np.asarray(hi), np.asarray(blk_start[1:]))
+    # a last live block that is not full ends with its last live slot
+    lo, hi = frontier.block_ranges(
+        fid, jnp.arange(LCAP, dtype=jnp.int32), jnp.int32(9 * LB + 3), lb=LB)
+    assert int(hi[9]) == np.searchsorted(np.asarray(fid), 9 * LB + 3) \
+        == int(lo[10]) == int(hi[11])
+
+
+# twelve nodes at the sort (rows a node; the fifth holds none), grown
+# three levels on without a sort: up to 96 slots, eight to an ancestor,
+# so an ancestor's slots can lie in two blocks of eight
+PARENTS = (40, 130, 7, 260, 0, 90, 55, 31, 170, 12, 66, 101)
+
+
+def _stale_level(F, B, n_stats, whole, seed=0):
+    r = np.random.default_rng(seed)
+    key = np.concatenate(
+        [np.repeat(np.arange(len(PARENTS)), PARENTS),
+         np.sort(LCAP + r.integers(0, 50, N_FINAL))])
+    n = len(key) + CHUNK
+    n += -n % TILE
+    key = np.concatenate([key, np.full(n - len(key), np.iinfo(np.int32).max)
+                          ]).astype(np.int32)
+    fid, anc, n_live = _grown_on(key, len(PARENTS), 3, seed)
+    bins = r.integers(0, B, (n, F)).astype(np.int32)
+    span = (-1, 2) if whole else (-(1 << 18), 1 << 18)
+    stats = tuple(jnp.asarray(r.integers(*span, n).astype(np.float32))
+                  for _ in range(n_stats))
+    words, bits = frontier.pack_bins(jnp.asarray(bins), B)
+    lo, hi = frontier.block_ranges(jnp.asarray(key), jnp.asarray(anc),
+                                   jnp.int32(n_live), lb=LB)
+    return key, fid, anc, n_live, lo, hi, words, bits, stats, bins
+
+
+def test_block_ranges_between_sorts_hold_the_blocks_rows():
+    key, fid, anc, n_live, lo, hi, *_ = _stale_level(3, 5, 2, True)
+    lo, hi = np.asarray(lo), np.asarray(hi)
+    live_blocks = -(-n_live // LB)
+    assert 4 <= live_blocks < LCAP // LB
+    shared = 0
+    for k in range(live_blocks):
+        at = np.nonzero((fid >= k * LB) & (fid < (k + 1) * LB))[0]
+        assert len(at) and lo[k] <= at.min() and at.max() < hi[k]
+        if k + 1 < live_blocks:
+            last, first = anc[min((k + 1) * LB, n_live) - 1], anc[(k + 1) * LB]
+            if last == first:       # one ancestor, slots in both blocks
+                shared += 1
+                assert hi[k] - lo[k + 1] == PARENTS[last] > 0
+            else:                   # final ancestors' rows may lie between
+                assert hi[k] <= lo[k + 1]
+    assert shared >= 2
+    # a block with no live slot: an empty range, and one step to zero it
+    assert (lo[live_blocks:] == hi[live_blocks:]).all()
+    n_tiles = len(fid) // TILE
+    step0, blk, _ = (np.asarray(a) for a in tk.frontier_schedule(
+        jnp.asarray(lo), jnp.asarray(hi), TILE, n_tiles,
+        frontier.range_blocks(4, LB)))
+    assert (np.diff(step0)[live_blocks:] == 1).all()
+    assert frontier.range_blocks(4, LB) == 2 and step0[-1] <= len(blk)
+    # rows lie in two ranges, final rows among them: more than the live
+    assert (hi - lo).sum() > (fid < LCAP).sum()
+
+
+@pytest.mark.parametrize("n_stats,n_pieces", [(2, 1), (3, 3)])
+def test_the_kernel_is_the_chunk_product_on_ranges_that_overlap(
+        n_stats, n_pieces):
+    F, B = 5, 21
+    _, fid, _, n_live, lo, hi, words, bits, stats, bins = _stale_level(
+        F, B, n_stats, whole=n_pieces == 1, seed=2)
+    fid_d = jnp.asarray(fid)
+    sched = tk.frontier_schedule(lo, hi, TILE, len(fid) // TILE,
+                                 frontier.range_blocks(4, LB))
+    geo = dict(lb=LB, sb=SB, n_features=F, n_bins=B, bits=bits,
+               n_pieces=n_pieces)
+    kernel = jax.jit(lambda s: tk.frontier_hist(
+        sched, lo, hi, s, fid_d, words, stats, tile=TILE, interpret=True,
+        **geo))
+    xla = jax.jit(lambda s: frontier.chunk_product_hist(
+        lo, hi, s, fid_d, words, stats, chunk=CHUNK, **geo))
+    assert n_live > 2 * SB * LB         # three super-batches hold nodes
+    assert (np.asarray(hi)[:-1] > np.asarray(lo)[1:]).any()
+    for s in range(3):
+        got = np.asarray(kernel(jnp.int32(s)))
+        assert np.array_equal(got, np.asarray(xla(jnp.int32(s))))
+        assert np.array_equal(got, _by_hand(
+            fid, bins, [np.asarray(v) for v in stats], s, B))
 
 
 def _stat_rows_of_pr35(nid, stats, n_nodes):
