@@ -152,8 +152,17 @@ def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
     the stacked f32 design matrix the builders materialize, and one
     algo-native unit's worth of the roofline streamed-bytes estimate
     (one tree / one IRLS iteration / one epoch — the transient working
-    set alive between chunk boundaries)."""
-    from h2o3_tpu.core.cleaner import _frame_nbytes
+    set alive between chunk boundaries).
+
+    Where the device reports its own memory, the budget it is held to is
+    ONE device's (``bytes_limit`` and ``bytes_in_use`` of device 0), so
+    the projection is a device's share: every part above is row-sized
+    and the rows are sharded over the mesh's ``data`` axis (a 116M-row
+    fit on four chips was refused as 27 GB against one chip's 16,
+    PERF.md §6, PR 39). Without device stats the budget is the knob's,
+    held against the tracked total, and so is the projection."""
+    from h2o3_tpu.core.cleaner import _frame_nbytes, device_memory_stats
+    from h2o3_tpu.parallel.mesh import data_size
     est = _frame_nbytes(frame)
     if validation_frame is not None and validation_frame is not frame:
         est += _frame_nbytes(validation_frame)
@@ -184,6 +193,8 @@ def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
             # refused a chip it fits, PERF.md §6, PR 35)
             units *= depth / TREE_WORKING_LEVELS
         est += int(float(cost.get("bytes", 0.0)) / max(units, 1.0))
+    if device_memory_stats():
+        est = -(-int(est) // data_size())
     return int(est)
 
 

@@ -45,10 +45,10 @@ from h2o3_tpu.models.tree import (Tree, TreeParams, TreeScalars,
                                   bucket_depth, concat_forests,
                                   grow_tree, kernel_levels,
                                   predict_forest, predict_tree,
-                                  select_levels, stack_trees,
+                                  psum_bytes, select_levels, stack_trees,
                                   trees_per_chunk, unstack_model_trees)
 from h2o3_tpu.ops import pallas as pallas_ops
-from h2o3_tpu.parallel.mesh import (get_mesh, put_sharded,
+from h2o3_tpu.parallel.mesh import (data_size, get_mesh, put_sharded,
                                     row_sharding)
 from h2o3_tpu import telemetry
 from h2o3_tpu.telemetry import observed_jit, stepprof
@@ -286,6 +286,19 @@ def _level_paths(tp: TreeParams, n_features: int) -> dict:
     fused = kernel_levels(tp, n_features)
     return {"levels_kernel": sum(fused),
             "levels_xla": len(fused) - sum(fused)}
+
+
+def _mesh_split(tp: TreeParams, n_rows: int, n_features: int,
+                trees: int) -> dict:
+    """What a ``gbm.chunk`` span says of how a chunk of ``trees`` trees
+    was split over the mesh: the ``data`` shards, the padded rows a
+    shard, and the bytes all-reduced over them (models/tree.psum_bytes;
+    0 on one shard, where a psum moves nothing). From static shapes: no
+    fetch."""
+    shards = data_size()
+    return {"data_shards": shards, "rows_per_shard": n_rows // shards,
+            "psum_bytes": trees * psum_bytes(tp, n_features)
+            if shards > 1 else 0}
 
 
 def _route_paths(forest: Tree) -> dict:
@@ -713,9 +726,7 @@ def _init_single(p: dict, frame: Frame, bm: BinnedMatrix, w, y_dev,
                 margin = margin + off
         elif off is None:
             f0 = np.float32(dist.init_margin(mean_y))
-            margin = put_sharded(
-                jnp.full((bm.bins.shape[0],), f0, jnp.float32),
-                row_sharding(mesh))
+            margin = _start_margin(f0, bm.bins.shape[0], row_sharding(mesh))
         else:
             # Newton solve of the offset-adjusted init
             # (DistributionFactory init task role)
@@ -744,15 +755,16 @@ def _init_multi(bm: BinnedMatrix, rows: RowSummary, ckpt=None):
                                      row_sharding(mesh))
         else:
             f0 = np.log(pri).astype(np.float32)
-            margins = put_sharded(_start_margin(f0, bm.bins.shape[0]),
-                                  row_sharding(mesh))
+            margins = _start_margin(f0, bm.bins.shape[0], row_sharding(mesh))
     return f0, margins
 
 
-def _start_margin(f0, n_rows: int):
-    """[n_rows] (or [n_rows, K]) float32 margins at the initial value."""
-    return jnp.broadcast_to(jnp.asarray(f0, jnp.float32),
-                            (n_rows,) + np.shape(f0))
+def _start_margin(f0, n_rows: int, sharding):
+    """[n_rows] (or [n_rows, K]) float32 margins at the initial value,
+    made where ``sharding`` puts them: each chip fills its own rows, no
+    row-sized array is made on one device first."""
+    return jnp.full((n_rows,) + np.shape(f0), f0, jnp.float32,
+                    device=sharding)
 
 
 def _cut_trees(trees: Tree, keep: int, n_class: int = 0) -> Tree:
@@ -769,7 +781,8 @@ def _cut_trees(trees: Tree, keep: int, n_class: int = 0) -> Tree:
 
 def _run_chunks(scan, carry, cut, *, tp: TreeParams, n_features: int,
                 ntrees: int, tree0: int, stopper: EarlyStopper,
-                score_interval: int, job, fc, deadline, light: bool):
+                score_interval: int, job, fc, deadline, light: bool,
+                n_class: int = 0):
     """The chunk loop of a sequential fit: the boosting loop as compiled
     scans over tree chunks — the per-tree host round trip (dominant on a
     remote chip) amortizes over a chunk's trees, while the inter-chunk
@@ -778,7 +791,8 @@ def _run_chunks(scan, carry, cut, *, tp: TreeParams, n_features: int,
 
     What varies between fits comes in as data: ``scan(carry, ntrees=,
     tree0=)`` (_boost_scan with the fit's arrays bound), the ``carry``
-    (margin, validation margin or None) and ``cut(trees, keep)``.
+    (margin, validation margin or None) and ``cut(trees, keep)``; a
+    K-class scan (``n_class``) grows K trees an iteration.
     Returns (forest, gains_total, scoring_history)."""
     chunk = trees_per_chunk(tp, carry[0].shape[0], deadline is not None)
     paths = _level_paths(tp, n_features)
@@ -820,7 +834,9 @@ def _run_chunks(scan, carry, cut, *, tp: TreeParams, n_features: int,
     while done < ntrees:
         k = min(chunk, ntrees - done)
         stepprof.chunk_begin()
-        with telemetry.span("gbm.chunk", trees=k, **paths):
+        with telemetry.span("gbm.chunk", trees=k, **paths,
+                            **_mesh_split(tp, carry[0].shape[0], n_features,
+                                          k * max(n_class, 1))):
             trees, carry, gains, devs = scan(carry, ntrees=k,
                                              tree0=tree0 + done)
             stepprof.compute_done((carry, gains, devs))
@@ -1074,7 +1090,8 @@ class GBMEstimator(ModelBuilder):
             if ckpt is not None:   # resume incl. the prior forest's part
                 val_margin = ckpt._margins(vbm).astype(jnp.float32)
             else:
-                val_margin = _start_margin(f0, vbm.bins.shape[0])
+                val_margin = _start_margin(f0, vbm.bins.shape[0],
+                                           row_sharding(get_mesh()))
             voff = None if multinomial else _offset_of(
                 p, validation_frame, vbm.bins.shape[0])
             if voff is not None:
@@ -1089,7 +1106,7 @@ class GBMEstimator(ModelBuilder):
             (margin, val_margin), partial(_cut_trees, n_class=K),
             tp=tp, n_features=len(x), ntrees=ntrees, tree0=prior_T,
             stopper=stopper, score_interval=int(p["score_tree_interval"]) or 5,
-            job=job, fc=fc, deadline=deadline, light=light)
+            job=job, fc=fc, deadline=deadline, light=light, n_class=K)
         if ckpt is not None:
             forest = Tree(*(jnp.concatenate([getattr(ckpt.forest, f),
                                              getattr(forest, f)])
@@ -1173,7 +1190,7 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
             if category == ModelCategory.BINOMIAL
             else get_distribution(dist_name, **p0))
     off, f0, margin1 = _init_single(p0, frame, bm, w, y_dev, rows, dist)
-    margins = jnp.zeros((M, bm.bins.shape[0]), jnp.float32) + margin1
+    margins = jnp.broadcast_to(margin1, (M,) + margin1.shape)
 
     # The batched chunk loop stays its own: a model that stops is MASKED
     # (its lane keeps running, its later trees are discarded) where the
@@ -1190,7 +1207,9 @@ def fit_gbm_batched(builder_cls, params_list: List[dict], frame: Frame,
         k = min(chunk, ntrees - done)
         alive = M - sum(stopped)
         stepprof.chunk_begin()
-        with telemetry.span("gbm.chunk", trees=k, batch=M, **paths):
+        with telemetry.span("gbm.chunk", trees=k, batch=M, **paths,
+                            **_mesh_split(tps[0], bm.bins.shape[0], len(x),
+                                          k * M)):
             tr_b, (margins, _), gains_b, devs_b = _boost_scan(
                 bm.bins, bm.nbins, y_dev, w, (margins, None), keys, None,
                 constraints, interaction_sets, tp=tps, sample_rate=rates,

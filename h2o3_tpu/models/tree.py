@@ -33,6 +33,7 @@ import numpy as np
 from h2o3_tpu.ops.histogram import histogram
 from h2o3_tpu.ops.segments import segment_sum
 from h2o3_tpu.ops.split_scan import best_splits
+from h2o3_tpu.parallel.mesh import data_size
 
 
 class TreeScalars(NamedTuple):
@@ -83,17 +84,34 @@ def trees_per_chunk(tp: "TreeParams", n_rows: int, capped: bool) -> int:
     Row scale bounds single-program runtime: a 25-tree fused scan at
     50M rows runs minutes inside ONE XLA program, between which no
     cancel point, checkpoint or progress update can fire — chunks
-    shrink past ~5M padded rows so each program stays ~tens of seconds;
-    <=5M rows keep 25 (the pyunit shapes are untouched).
+    shrink past ~5M padded rows A DATA SHARD (``n_rows`` is the global
+    padded count: on a mesh every chip runs its own shard's rows at
+    once, so a chip's rows set the program's runtime) so each program
+    stays ~tens of seconds; <=5M rows keep 25 (the pyunit shapes are
+    untouched).
     A fit under max_runtime_secs (``capped``) can only stop at a chunk
     boundary, so its chunk also shrinks as per-tree cost grows
     (complete-tree layout: ~2^depth * nbins per tree) — a 25-deep-tree
     chunk at depth bucket 10 runs ~20-80s, far past a ~30s AutoML
     slice. Uncapped fits keep 25 (no extra program shapes)."""
-    cost = max(1.0, n_rows / 5_242_880.0)
+    cost = max(1.0, n_rows / data_size() / 5_242_880.0)
     if capped:
         cost *= (2.0 ** tp.max_depth / 64.0) * (tp.nbins_total / 65.0)
     return max(1, min(25, int(round(25.0 / max(cost, 1.0)))))
+
+
+def psum_bytes(params: "TreeParams", n_features: int) -> int:
+    """Bytes one tree of the complete layout all-reduces over the mesh's
+    ``data`` axis, from static shapes: at each of its compiled levels
+    the histogram of the nodes the level builds (the root, then the left
+    children: max(2^(d-1), 1)) — 3 float32 statistics × F × B each —
+    and the [2^D, 3] float32 leaf sums (ops/segments.py; D a power of
+    two already). The same on either level path: the kernels' and the
+    XLA histogram reduce the same sums."""
+    D = bucket_depth(params.max_depth)
+    B = params.nbins_total
+    nodes = sum(max(2 ** (d - 1), 1) for d in range(D))
+    return 4 * (3 * n_features * B * nodes + 3 * 2 ** D)
 
 
 class Tree(NamedTuple):

@@ -205,7 +205,9 @@ def histogram(bins, nid, w, g, h, *, n_nodes: int, n_bins: int,
             hist = _local_histogram(bins_l, nid_l, stats_l, n_nodes, n_bins,
                                     block_rows)
         # psum over 'data' only: inputs are replicated over 'model', so
-        # including it would scale every stat by the model-axis size
-        return jax.lax.psum(hist, DATA_AXIS)
+        # including it would scale every stat by the model-axis size;
+        # a scope of its own, so that a trace prices the all-reduce
+        with jax.named_scope("tree.psum"):
+            return jax.lax.psum(hist, DATA_AXIS)
 
     return _task(bins, nid, stats)

@@ -470,6 +470,7 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
             lh = _hist_call(bins_l, nid_l, stats_l, d=d, n_nodes=n_nodes,
                             n_bins=n_bins, block_rows=block_rows,
                             interpret=interpret)
+        with jax.named_scope("tree.psum"):
             lh = jax.lax.psum(lh, DATA_AXIS)
         with jax.named_scope("tree.split_scan"):
             hist, bg, bf, bt, bnal, blv, brv, lmask, split, cs = \

@@ -83,7 +83,9 @@ def _segment_sum_jit(nid, vals, *, n_nodes, block_rows, mesh):
                              block_rows=block_rows)
 
     def _body(nid_l, vals_l):
-        return jax.lax.psum(task(nid_l, vals_l), DATA_AXIS)
+        part = task(nid_l, vals_l)
+        with jax.named_scope("tree.leaf_psum"):
+            return jax.lax.psum(part, DATA_AXIS)
 
     return shard_map(_body, mesh=mesh,
                      in_specs=(P(DATA_AXIS), P(DATA_AXIS)),

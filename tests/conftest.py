@@ -13,6 +13,17 @@ if "--xla_force_host_platform_device_count" not in _flags:
     _flags += " --xla_force_host_platform_device_count=8"
 os.environ["XLA_FLAGS"] = _flags.strip()
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# each xdist worker keeps a persistent compile cache of its own: workers
+# that shared one loaded executables another had written, and XLA:CPU's
+# loader crashed in one (a segfault in compilation_cache.
+# get_executable_and_time, PR 39; the workers that aborted in the runs
+# of PRs 37-38 shared the cache too). Set before jax is imported: jax
+# reads the variable itself.
+_worker = os.environ.get("PYTEST_XDIST_WORKER")
+if _worker and "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache", "pytest-" + _worker)
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402

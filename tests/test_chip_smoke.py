@@ -75,3 +75,31 @@ def test_compile_cache_follows_the_variable_or_the_checkout(tmp_path,
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.split() == [mine, mine]
     assert not os.path.exists(os.path.join(mine, "..", ".jax_cache"))
+
+
+
+def test_each_test_worker_keeps_a_compile_cache_of_its_own():
+    """tests/conftest.py: under xdist a worker's persistent compile cache
+    is its own directory — workers that shared one loaded executables
+    another had written, and XLA:CPU's loader crashed in them. A cache
+    the caller names stays the caller's."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    probe = ("import os, runpy; runpy.run_path('tests/conftest.py'); "
+             "print(os.environ.get('JAX_COMPILATION_CACHE_DIR'))")
+    for worker, named, want in (
+            ("gw7", None, os.path.join(REPO, ".jax_cache", "pytest-gw7")),
+            (None, None, "None"),
+            ("gw7", "/elsewhere", "/elsewhere")):
+        e = dict(env, JAX_PLATFORMS="cpu")
+        if worker:
+            e["PYTEST_XDIST_WORKER"] = worker
+        else:
+            e.pop("PYTEST_XDIST_WORKER", None)
+        if named:
+            e["JAX_COMPILATION_CACHE_DIR"] = named
+        out = subprocess.run([sys.executable, "-c", probe], cwd=REPO,
+                             env=e, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.split()[-1] == want

@@ -138,6 +138,30 @@ def test_estimate_fit_bytes_scales():
     assert est_v >= est + _frame_nbytes(vf)
 
 
+@pytest.mark.parametrize("shards", [1, 4])
+def test_a_device_is_held_to_its_share_of_the_fit(monkeypatch, shards):
+    """Where the device reports its own bytes, the budget is one
+    device's, and so is the projection: a fit's rows are sharded over
+    the mesh's data axis (a 116M-row fit on four chips was refused as
+    the whole fit against one chip)."""
+    import jax
+    from h2o3_tpu.core import cleaner
+    from h2o3_tpu.parallel import mesh as mesh_mod
+    fr = _classif_frame()
+    x = [f"x{i}" for i in range(5)]
+    whole = memgov.estimate_fit_bytes("gbm", {"ntrees": 50}, fr, x)
+    monkeypatch.setattr(cleaner, "device_memory_stats", lambda: {
+        "bytes_in_use": 0, "bytes_limit": 16 << 30})
+    old = mesh_mod.get_mesh()
+    mesh_mod.set_global_mesh(mesh_mod.make_mesh(jax.devices()[:shards],
+                                                shards, 1))
+    try:
+        share = memgov.estimate_fit_bytes("gbm", {"ntrees": 50}, fr, x)
+    finally:
+        mesh_mod.set_global_mesh(old)
+    assert share == -(-whole // shards)
+
+
 # ---------------------------------------------- predictive admission
 
 
