@@ -1,11 +1,11 @@
-"""Distributed Gram matrix — X'WX / X'Wz via blocked matmuls + psum.
+"""Distributed Gram matrix — X'WX / X'Wz as row contractions + psum.
 
 Reference: hex/gram/Gram.java:15 — GLM's IRLS inner loop accumulates the
 weighted Gram over an MRTask (GLMIterationTask, hex/glm/GLMTask.java) and
 solves by Cholesky with collinear-column dropping (Gram.java:229,452).
-TPU-native: the accumulation is a single einsum contraction over the
-row-sharded data axis; `lax.scan` over row blocks bounds the [C, P]
-design-block memory; `psum` replaces the reduce tree.
+TPU-native: each shard contracts its rows in one dot_general over the
+row-sharded data axis, reading the design matrix in the layout it has;
+`psum` replaces the reduce tree.
 """
 
 from __future__ import annotations
@@ -20,44 +20,29 @@ from jax.sharding import PartitionSpec as P
 from h2o3_tpu.parallel.mesh import DATA_AXIS
 
 
-def _local_gram(X, wz, block_rows: int):
-    """Accumulate [P, P] X'WX, [P] X'Wz, scalars over one shard.
+def _local_gram(X, wz):
+    """[P, P] X'WX, [P] X'Wz and sum w over one shard, each ONE contraction
+    over the shard's rows.
+
+    X is not cut into row blocks: on the TPU it lies rows-minor, and a
+    reshape into blocks makes XLA lay the whole matrix out again and
+    slice it in sparsely filled tiles, with more temporaries than the
+    matrix itself (tests/test_chip_compile.py ``test_glm_irls_solve``).
 
     wz: [N, 2] = (w, w*z) stacked. Returns (XtWX, XtWz, wsum).
     """
-    N, Pdim = X.shape
-    C = min(block_rows, N)
-    nblk = (N + C - 1) // C
-    Npad = nblk * C
-
-    # scope names are what a device trace shows of this code:
-    # gram.blocks is the cutting into row blocks (pad, reshape and the
-    # scan's own slicing), gram.accumulate the products of one block
-    @jax.named_scope("gram.accumulate")
-    def step(acc, xs):
-        xtx, xtz, ws = acc
-        Xc, wzc = xs
-        wX = Xc * wzc[:, 0:1]
-        xtx = xtx + jax.lax.dot_general(
-            wX.T, Xc, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        xtz = xtz + Xc.T @ wzc[:, 1]   # X'(w·z)
-        ws = ws + jnp.sum(wzc[:, 0])
-        return (xtx, xtz, ws), None
-
-    init = (jnp.zeros((Pdim, Pdim), jnp.float32),
-            jnp.zeros((Pdim,), jnp.float32), jnp.float32(0.0))
-    with jax.named_scope("gram.blocks"):
-        if Npad != N:
-            X = jnp.pad(X, ((0, Npad - N), (0, 0)))
-            wz = jnp.pad(wz, ((0, Npad - N), (0, 0)))
-        Xb = X.reshape(nblk, C, Pdim)
-        wzb = wz.reshape(nblk, C, 2)
-        (xtx, xtz, ws), _ = jax.lax.scan(step, init, (Xb, wzb))
+    # the scope name is what a device trace shows of this code
+    with jax.named_scope("gram.accumulate"):
+        rows = (((0,), (0,)), ((), ()))
+        xtx = jax.lax.dot_general(X * wz[:, 0:1], X, rows,
+                                  preferred_element_type=jnp.float32)
+        xtz = jax.lax.dot_general(X, wz[:, 1], rows,
+                                  preferred_element_type=jnp.float32)
+        ws = jnp.sum(wz[:, 0])
     return xtx, xtz, ws
 
 
-def gram(X, w, z, *, mesh, block_rows: int = 8192):
+def gram(X, w, z, *, mesh):
     """All-reduced (X'WX, X'Wz, sum w) over the mesh.
 
     X [N, P] row-sharded design matrix (with intercept column appended by
@@ -76,7 +61,7 @@ def gram(X, w, z, *, mesh, block_rows: int = 8192):
         in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(), P(), P()), check_vma=False)
     def _task(X_l, wz_l):
-        xtx, xtz, ws = _local_gram(X_l, wz_l, block_rows)
+        xtx, xtz, ws = _local_gram(X_l, wz_l)
         with jax.named_scope("gram.psum"):
             return (jax.lax.psum(xtx, DATA_AXIS),
                     jax.lax.psum(xtz, DATA_AXIS),
@@ -85,7 +70,7 @@ def gram(X, w, z, *, mesh, block_rows: int = 8192):
     return _task(X, wz)
 
 
-def gram_model_sharded(X, w, z, *, mesh, block_rows: int = 8192):
+def gram_model_sharded(X, w, z, *, mesh):
     """Model-axis-sharded Gram: X columns sharded over 'model', rows over
     'data'; the X'X cross-block products stream around the model axis as
     a ppermute ring (the collective-matmul recipe — each device holds one
@@ -105,7 +90,7 @@ def gram_model_sharded(X, w, z, *, mesh, block_rows: int = 8192):
     N, Pdim = X.shape
     P0 = Pdim
     if nmodel == 1:
-        return gram(X, w, z, mesh=mesh, block_rows=block_rows)
+        return gram(X, w, z, mesh=mesh)
     if Pdim % nmodel != 0:
         padc = nmodel - Pdim % nmodel
         X = jnp.pad(X, ((0, 0), (0, padc)))

@@ -403,9 +403,31 @@ def test_serving_jit_with_donation(topo, tiny_gbm, monkeypatch):
         S((8, F), jnp.int8, sharding=_one_chip(topo))).compile()
 
 
+def _element_count(dims):
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+def _row_sized_moves(txt, n):
+    """The ``copy`` and ``dynamic-slice`` instructions of a compiled
+    module whose operand holds at least ``n`` elements: a relayout of a
+    row-sized array, or a block cut out of one."""
+    shape_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", txt))
+    return [(op, shape_of[src]) for op, src in re.findall(
+        r" (copy|dynamic-slice)\(%([\w.\-]+)", txt)
+        if src in shape_of and _element_count(shape_of[src]) >= n]
+
+
 @pytest.mark.parametrize("chips", [1, 4])
-def test_glm_irls_solve(topo, chips):
-    from h2o3_tpu.models.glm import GLMEstimator
+@pytest.mark.parametrize("rows,batch", [(GLM_ROWS, None), (HIGGS_ROWS, None),
+                                        (HIGGS_ROWS, 8)])
+def test_glm_irls_solve(topo, chips, rows, batch):
+    """The IRLS solve at the smoke's rows and at ``glm-higgs.fit-11m``'s,
+    alone and ``vmap``-batched over 8 (alpha, lambda) lanes. The Gram
+    reads the design matrix in the layout it arrives in: no relayout
+    copy of it and no row block sliced out of it, and no temporaries as
+    large as the matrix (a blocked scan held 2.69 GB of them at the
+    cell's rows on one chip, against the matrix's 1.28 GB)."""
+    from h2o3_tpu.models.glm import GLMEstimator, _irls_solve_batched
     r = np.random.RandomState(3)
     X = r.randn(TINY_ROWS, 28).astype(np.float32)
     cols = {f"x{i}": X[:, i] for i in range(28)}
@@ -413,9 +435,28 @@ def test_glm_irls_solve(topo, chips):
     fr = h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
     GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
                  max_iterations=2, standardize=True).train(fr, y="y")
-    txt = _compiled_text(_lower_recorded(
-        "glm.irls_solve", _mesh(topo, chips), fr.nrows_padded, GLM_ROWS))
+    mesh = _mesh(topo, chips)
+    n_real = mesh_mod.padded_rows(rows, mesh)
+    if batch:
+        # the recorded single solve's arguments, l1 / l2 / objective
+        # epsilon stacked on the vmapped axis as fit_glm_batched does
+        _, aargs, akwargs = compile_observer.aot_source("glm.irls_solve")
+        aargs = list(_at_scale(aargs, fr.nrows_padded, n_real, mesh))
+        aargs[5] = aargs[6] = aargs[13] = S(
+            (batch,), jnp.float32, sharding=NamedSharding(mesh, P()))
+        with _as_global_mesh(mesh):
+            lowered = _irls_solve_batched.__wrapped__.lower(*aargs,
+                                                           **akwargs)
+    else:
+        lowered = _lower_recorded("glm.irls_solve", mesh, fr.nrows_padded,
+                                  rows)
+    compiled = lowered.compile()
+    txt = compiled.as_text()
     assert ("all-reduce" in txt) == (chips > 1)
+    n_local = n_real // chips
+    assert not _row_sized_moves(txt, n_local)
+    x_bytes = n_local * 29 * 4                   # X1: 28 columns + intercept
+    assert compiled.memory_analysis().temp_size_in_bytes < x_bytes
 
 
 @pytest.mark.parametrize("chips", [1, 4])

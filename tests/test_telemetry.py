@@ -577,8 +577,7 @@ def _lowered_text(what):
 
 @pytest.mark.parametrize("what, scopes", [
     ("irls_solve", ("glm.irls_iter", "glm.reweight", "glm.newton_solve",
-                    "glm.line_search", "gram.blocks", "gram.accumulate",
-                    "gram.psum")),
+                    "glm.line_search", "gram.accumulate", "gram.psum")),
     ("boost_scan", ("tree.hist", "tree.split_scan", "tree.partition")),
     ("predict_forest", ("forest.level",)),
 ])
