@@ -147,7 +147,8 @@ TREE_WORKING_LEVELS = 6.0
 
 
 def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
-                       validation_frame=None) -> int:
+                       validation_frame=None,
+                       row_bytes: Optional[int] = None) -> int:
     """Projected device footprint of one fit: the resident input frames,
     the stacked f32 design matrix the builders materialize, and one
     algo-native unit's worth of the roofline streamed-bytes estimate
@@ -160,7 +161,9 @@ def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
     and the rows are sharded over the mesh's ``data`` axis (a 116M-row
     fit on four chips was refused as 27 GB against one chip's 16,
     PERF.md §6, PR 39). Without device stats the budget is the knob's,
-    held against the tracked total, and so is the projection."""
+    held against the tracked total, and so is the projection.
+    ``row_bytes``: a row's bytes of the fit's design and row state, as
+    ``ModelBuilder.design_row_bytes`` counts them; else 4 B a feature."""
     from h2o3_tpu.core.cleaner import _frame_nbytes, device_memory_stats
     from h2o3_tpu.parallel.mesh import data_size
     est = _frame_nbytes(frame)
@@ -169,7 +172,7 @@ def estimate_fit_bytes(algo: str, params: Optional[Dict], frame, x,
     feats = max(len(x or []), 1)
     npad = int(getattr(frame, "nrows_padded", None)
                or getattr(frame, "nrows", 0) or 0)
-    est += npad * feats * 4
+    est += npad * (feats * 4 if row_bytes is None else int(row_bytes))
     try:
         from h2o3_tpu.telemetry import roofline
         cost = roofline.analytic_fit_cost(algo, params or {}, None,
@@ -434,11 +437,12 @@ class MemoryGovernor:
         self.refresh_gauges()
 
     def admit_fit(self, algo: str, params: Optional[Dict], frame, x,
-                  validation_frame=None) -> Reservation:
+                  validation_frame=None,
+                  row_bytes: Optional[int] = None) -> Reservation:
         """ModelBuilder.train's pre-dispatch hook: estimate → reserve
         (spill / bounded wait / reject)."""
         projected = estimate_fit_bytes(algo, params, frame, x,
-                                       validation_frame)
+                                       validation_frame, row_bytes)
         exclude = {getattr(frame, "key", None),
                    getattr(validation_frame, "key", None)} - {None}
         return self.reserve(f"{algo}:{getattr(frame, 'key', '?')}",

@@ -3,18 +3,24 @@
 Reference: hex/DataInfo.java:16 — GLM/DeepLearning/GLRM iterate rows
 through a view that expands categoricals to indicator columns (skipping
 the first level unless useAllFactorLevels), imputes NAs (mean imputation
-default) and standardizes numerics. TPU-native: the expansion is
-materialized once into a dense [Npad, P] f32 device matrix, row-sharded —
-dense one-hot blocks are MXU fuel, and P stays modest for the tabular
-regimes H2O targets (wide one-hot spaces are the one TP-style sharding
-candidate, SURVEY §2.4 item 6).
+default) and standardizes numerics. TPU-native, two layouts:
+
+- dense: the expansion materialized once into an [Npad, P] f32 device
+  matrix, row-sharded — what the dense layers, GAM, PCA, GLRM and an
+  all-numeric GLM read;
+- codes (``CodesDesign``): the factor columns kept as the frame holds
+  them, integer codes with each column's offset into the coefficients,
+  and only the numerics as a dense matrix — what hex/DataInfo itself
+  keeps (categoricals as level indices) and what a GLM with factor
+  predictors reads (``ops/gram.py`` forms X'WX from the codes). An
+  [Npad, P] matrix of 756 indicator columns at 116M rows is 351 GB.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -52,11 +58,64 @@ def _design_device(datas, nas, stats, *, spec: tuple, standardize: bool):
     return jnp.concatenate(blocks, axis=1)
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class CodesDesign:
+    """The design matrix as codes: what ``X`` is, never materialized.
+
+    Row ``i`` of the matrix it stands for has, for factor ``f`` with
+    ``factors[f] = (offset, first, card)``, a 1 in column
+    ``offset + code - first`` where ``first <= code < card`` and the row
+    is not NA in that column (no 1 at all otherwise: the dropped first
+    level, or an NA row — the dense view's all-zero indicator block);
+    and ``dense[i, j]`` in column ``dense_cols[j]``. ``codes`` / ``nas``
+    are the frame's own row-sharded arrays, not copies.
+
+    A pytree: the arrays are leaves, the layout is static, so a jitted
+    function compiles once a layout and the rows ride the data axis.
+    """
+    codes: Tuple[jax.Array, ...]     # per factor [Npad] integer codes
+    nas: Tuple[jax.Array, ...]       # per factor [Npad] bool
+    dense: jax.Array                 # [Npad, len(dense_cols)] float32
+    factors: Tuple[Tuple[int, int, int], ...] = ()
+    dense_cols: Tuple[int, ...] = ()
+    p: int = 0                       # columns of the matrix it stands for
+
+    def tree_flatten(self):
+        return ((self.codes, self.nas, self.dense),
+                (self.factors, self.dense_cols, self.p))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return (self.dense.shape[0], self.p)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(getattr(a, "nbytes", 0) or 0)
+                   for a in jax.tree_util.tree_leaves(self))
+
+    @property
+    def cat_levels(self) -> int:
+        """Indicator columns the factors stand for."""
+        return sum(card - first for _, first, card in self.factors)
+
+    def with_intercept(self) -> "CodesDesign":
+        """The same design with a last column of ones (GLM's ``X1``)."""
+        ones = jnp.ones((self.dense.shape[0], 1), jnp.float32)
+        return dataclasses.replace(
+            self, dense=jnp.concatenate([self.dense, ones], axis=1),
+            dense_cols=self.dense_cols + (self.p,), p=self.p + 1)
+
+
 @dataclasses.dataclass
 class DataInfo:
     names: List[str]                 # source columns
     coef_names: List[str]            # expanded coefficient names
-    X: jax.Array                     # [Npad, P] design matrix (row-sharded)
+    X: Union[jax.Array, CodesDesign]  # [Npad, P] (row-sharded)
     is_cat: np.ndarray
     cat_offsets: np.ndarray          # start index of each cat block
     num_means: np.ndarray            # imputation means of numeric cols
@@ -75,17 +134,21 @@ def build_datainfo(frame: Frame, features: Sequence[str],
                    standardize: bool = True,
                    use_all_factor_levels: bool = False,
                    missing_values_handling: str = "mean_imputation",
-                   stats_override: Optional[dict] = None) -> DataInfo:
+                   stats_override: Optional[dict] = None,
+                   codes: bool = False) -> DataInfo:
     """Expand ``features`` into the design matrix.
 
     ``stats_override`` carries training-time means/sigmas/domains when
-    adapting a scoring frame (adaptTestForTrain role).
+    adapting a scoring frame (adaptTestForTrain role). ``codes``: ``X``
+    is a ``CodesDesign`` where a feature is categorical (the numerics
+    alone are built dense); names, offsets and statistics are the dense
+    view's either way.
     """
     cols = [frame.col(n) for n in features]
     is_cat = np.array([c.is_categorical for c in cols], dtype=bool)
     coef_names: List[str] = []
     cat_offsets = []
-    num_means, num_sigmas = [], []
+    num_means, num_sigmas, num_coefs = [], [], []
     domains: List[Optional[List[str]]] = []
     shard = row_sharding()
 
@@ -103,10 +166,11 @@ def build_datainfo(frame: Frame, features: Sequence[str],
             if stats_override is not None:
                 dom = stats_override["domains"][i]
                 from h2o3_tpu.models.model import adapt_domain
-                codes = adapt_domain(c, dom)
-                codes = np.pad(codes, (0, frame.nrows_padded - frame.nrows),
-                               constant_values=-1)
-                code, na = np.maximum(codes, 0).astype(np.int32), codes < 0
+                adapted = np.pad(adapt_domain(c, dom),
+                                 (0, frame.nrows_padded - frame.nrows),
+                                 constant_values=-1)
+                code, na = np.maximum(adapted, 0).astype(np.int32), \
+                    adapted < 0
                 datas.append(jax.device_put(code, shard))
                 nas.append(jax.device_put(na, shard))
                 host_arrays += 2
@@ -140,14 +204,34 @@ def build_datainfo(frame: Frame, features: Sequence[str],
             stats[i] = (float(mu), float(sd))
             datas.append(c.data)
             nas.append(c.na_mask)
+            num_coefs.append(len(coef_names))
             coef_names.append(c.name)
 
-    if cols:
-        X = _design_device(tuple(datas), tuple(nas), stats,
-                           spec=tuple(spec), standardize=bool(standardize))
+    # a factor whose only level is the dropped one has no column
+    factors = [(int(off), i) for off, i in
+               zip(cat_offsets, np.flatnonzero(is_cat))
+               if spec[i][2] > spec[i][1]]
+    if codes and factors:
+        num = np.flatnonzero(~is_cat)
+        dense = (_design_device(tuple(datas[i] for i in num),
+                                tuple(nas[i] for i in num), stats[num],
+                                spec=tuple(spec[i] for i in num),
+                                standardize=bool(standardize))
+                 if num.size else
+                 jnp.zeros((frame.nrows_padded, 0), jnp.float32))
+        X = CodesDesign(
+            codes=tuple(datas[i] for _, i in factors),
+            nas=tuple(nas[i] for _, i in factors),
+            dense=jax.device_put(dense, shard),
+            factors=tuple((off, spec[i][1], spec[i][2])
+                          for off, i in factors),
+            dense_cols=tuple(num_coefs),
+            p=len(coef_names))
     else:
-        X = jnp.zeros((frame.nrows_padded, 0), jnp.float32)
-    X = jax.device_put(X, shard)
+        X = (_design_device(tuple(datas), tuple(nas), stats,
+                            spec=tuple(spec), standardize=bool(standardize))
+             if cols else jnp.zeros((frame.nrows_padded, 0), jnp.float32))
+        X = jax.device_put(X, shard)
     annotate(columns=len(cols), host_arrays=host_arrays,
              host_bytes=host_bytes)
     return DataInfo(
@@ -156,6 +240,21 @@ def build_datainfo(frame: Frame, features: Sequence[str],
         num_means=np.asarray(num_means), num_sigmas=np.asarray(num_sigmas),
         domains=domains, standardize=standardize,
         use_all_factor_levels=use_all_factor_levels, nrows=frame.nrows)
+
+
+def design_row_bytes(frame: Frame, features: Sequence[str],
+                     use_all_factor_levels: bool = False,
+                     codes: bool = False) -> int:
+    """Device bytes a row of the ``X`` that ``build_datainfo`` builds
+    with these arguments: 4 B a column of the dense matrix — every
+    indicator and numeric, or with ``codes`` where a factor has a column
+    the numerics alone (the codes are the frame's own arrays)."""
+    cols = [frame.col(n) for n in features]
+    first = 0 if use_all_factor_levels else 1
+    levels = sum(max(len(c.domain or []), 1) - first
+                 for c in cols if c.is_categorical)
+    nums = sum(not c.is_categorical for c in cols)
+    return 4 * (nums if codes and levels > 0 else nums + levels)
 
 
 def stats_of(di: DataInfo) -> dict:

@@ -32,14 +32,14 @@ import numpy as np
 
 from h2o3_tpu.parallel.mesh import fetch_replicated as _fetch_np
 
-from h2o3_tpu.frame.datainfo import (DataInfo, build_datainfo,
-                                     coef_stats, stats_of)
+from h2o3_tpu.frame.datainfo import (CodesDesign, DataInfo, build_datainfo,
+                                     coef_stats, design_row_bytes, stats_of)
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models import metrics as mm
 from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
                                    adapt_domain, infer_category,
                                    response_on_device)
-from h2o3_tpu.ops.gram import gram
+from h2o3_tpu.ops.gram import codes_matvec, codes_rmatvec, gram
 from h2o3_tpu.ops.optimize import (admm_l1_quadratic,
                                    cholesky_solve_regularized, lbfgs)
 from h2o3_tpu.parallel.mesh import get_mesh, row_sharding
@@ -161,6 +161,35 @@ class Family:
         raise ValueError(self.name)
 
 
+# a fit's float32 row state beside its design: response, weights, the
+# linear predictor, the IRLS weights and working response
+ROW_STATE_BYTES = 24
+
+
+def holds_codes(family) -> bool:
+    """Whether a GLM of ``family`` holds its factor predictors as codes
+    (``frame/datainfo.CodesDesign``, where a predictor is a factor):
+    every family but the ordinal, whose fit slices a dense matrix."""
+    return str(family).lower() != "ordinal"
+
+
+def _linear(X1, B):
+    """``X1 @ B``: where the design is held as codes, coefficient lookups
+    per factor plus the numerics' product (``ops/gram.codes_matvec``)."""
+    if isinstance(X1, CodesDesign):
+        with jax.named_scope("glm.eta"):
+            return codes_matvec(X1, B, mesh=get_mesh())
+    return X1 @ B
+
+
+def _with_intercept(X):
+    """The design with the intercept column appended (``X1``)."""
+    if isinstance(X, CodesDesign):
+        return X.with_intercept()
+    ones = jnp.ones((X.shape[0], 1), jnp.float32)
+    return jnp.concatenate([X, ones], axis=1)
+
+
 @partial(jax.jit, static_argnames=("family", "link", "use_l1"))
 def _irls_iter(X1, coef, y, w, off, l1, l2, family: str, link: str,
                tweedie_power, theta=1e-5, *, use_l1: bool):
@@ -169,25 +198,40 @@ def _irls_iter(X1, coef, y, w, off, l1, l2, family: str, link: str,
     path reuses one compiled program (GLM.java fitIRLSM per-lambda loop).
     """
     fam = Family(family, tweedie_power, link, theta=theta)
+    # a design held as codes takes the Newton step from the score,
+    # X'W(z - eta), summed straight from the rows: the IRLS form's
+    # X'Wz - X'WX beta cancels to it in float32 and a factor whose
+    # dropped first level is rare leaves a nearly flat direction (its
+    # other levels against the intercept) that multiplies what the
+    # cancellation loses — and that a rank-safety ridge would move the
+    # IRLS fixed point along; a step's fixed point is the score's zero
+    # whatever damps the step. A dense design keeps the IRLS form: the
+    # step form moves its fits within the stopping tolerance, and they
+    # are held to their float32 results (on a nearly separable frame it
+    # flipped one AUC pair between CV's two fold paths)
+    step_form = isinstance(X1, CodesDesign) and not use_l1
     with jax.named_scope("glm.reweight"):
-        eta = X1 @ coef + off
+        eta = _linear(X1, coef) + off
         mu = fam.linkinv(eta)
         d = fam.dmu_deta(eta, mu)
         var = fam.variance(mu)
         # working response net of the fixed offset (GLMTask with offset)
-        z = eta - off + (y - mu) / jnp.where(jnp.abs(d) < 1e-10, 1e-10, d)
+        resid = (y - mu) / jnp.where(jnp.abs(d) < 1e-10, 1e-10, d)
+        z = eta - off + resid
         w_irls = w * d * d / jnp.maximum(var, 1e-10)
         dev = jnp.sum(w * fam.deviance(y, mu))
 
     mesh = get_mesh()
     from h2o3_tpu.parallel.mesh import MODEL_AXIS
-    if mesh.shape.get(MODEL_AXIS, 1) > 1:
+    if mesh.shape.get(MODEL_AXIS, 1) > 1 and \
+            not isinstance(X1, CodesDesign):
         # wide one-hot designs on a (data, model) mesh: column-sharded
         # Gram via the ppermute ring (SURVEY §2.4 item 6 TP-like axis)
         from h2o3_tpu.ops.gram import gram_model_sharded
         xtx, xtz, _ = gram_model_sharded(X1, w_irls, z, mesh=mesh)
     else:
-        xtx, xtz, _ = gram(X1, w_irls, z, mesh=mesh)
+        xtx, xtz, _ = gram(X1, w_irls, resid if step_form else z,
+                           mesh=mesh)
     with jax.named_scope("glm.newton_solve"):
         nobs = jnp.maximum(jnp.sum(w), 1.0)
         A = xtx / nobs
@@ -198,10 +242,17 @@ def _irls_iter(X1, coef, y, w, off, l1, l2, family: str, link: str,
         if use_l1:
             new_coef = admm_l1_quadratic(A + l2 * jnp.diag(penalize), q,
                                          l1, penalize)
+        elif step_form:
+            # the ridge a share of each column's own diagonal (a level's
+            # weight): it damps a rare level's step no more than a
+            # common one's
+            new_coef = coef + cholesky_solve_regularized(
+                A, q - l2 * penalize * coef, l2, penalize,
+                ridge_boost=1e-6 * jnp.diag(A) + 1e-30)
         else:
             new_coef = cholesky_solve_regularized(A, q, l2, penalize)
         delta = jnp.max(jnp.abs(new_coef - coef))
-    return new_coef, delta, dev
+    return new_coef, delta, dev, eta
 
 
 @observed_jit("glm.irls_solve")
@@ -236,37 +287,60 @@ def _irls_solve(X1, coef, y, w, off, l1, l2, beta_eps, max_iter,
             + 0.5 * l2 * jnp.sum(c[:-1] * c[:-1])
 
     def cond(state):
-        coef, delta, obj_prev, obj, it = state
-        rel = jnp.abs(obj_prev - obj) / jnp.maximum(jnp.abs(obj), 1e-10)
+        coef, delta, obj, rel, it = state
         return (delta > beta_eps) & (rel > obj_eps) & (it < max_iter)
 
     # scope names are what a device trace shows of this program
     # (benchmark/program_trace.py): metadata only, the program is the same
     @jax.named_scope("glm.irls_iter")
     def body(state):
-        coef, _, _, obj, it = state
-        full, _, _ = _irls_iter(X1, coef, y, w, off, l1, l2,
-                                family, link, tweedie_power,
-                                theta, use_l1=use_l1)
+        coef, _, obj, _, it = state
+        full, _, dev, eta = _irls_iter(X1, coef, y, w, off, l1, l2,
+                                       family, link, tweedie_power,
+                                       theta, use_l1=use_l1)
         with jax.named_scope("glm.line_search"):
             # candidates coef + s*(full-coef); objectives in ONE batched
             # pass
             cands = coef[None, :] + steps[:, None] * (full - coef)[None, :]
-            mus = fam.linkinv(X1 @ cands.T + off[:, None])       # [N, 9]
-            devs = jnp.sum(w[:, None] * fam.deviance(y[:, None], mus),
-                           axis=0)
             pens = jax.vmap(pen_of)(cands)
-            objs = devs + pens
-            k = jnp.argmin(objs)
+            if isinstance(X1, CodesDesign):
+                # eta is linear in the coefficients: the candidates' from
+                # the reweight's and ONE lookup of the step, no [N, 9]
+                # product (and no [N, 9] array where the reduction fuses)
+                step_eta = _linear(X1, full - coef)
+                mus = fam.linkinv(eta[:, None]
+                                  + steps[None, :] * step_eta[:, None])
+                # each candidate against the last, no step, row by row,
+                # and the largest step within rounding of the best: along
+                # a rare level's flat direction a step gains less than
+                # the float32 spacing of the deviance's total, where
+                # totals compared pick a step, or none, by rounding
+                gain = jnp.sum(w[:, None] * (
+                    fam.deviance(y[:, None], mus)
+                    - fam.deviance(y, fam.linkinv(eta))[:, None]), axis=0)
+                objs = gain + pens - pens[-1]
+                at = dev + pens[-1]
+                k = jnp.argmax(objs <= jnp.min(objs) + 1e-6 * jnp.abs(at))
+                new_obj = at + objs[k]
+                rel = jnp.abs(objs[k]) / jnp.maximum(jnp.abs(at), 1e-10)
+            else:
+                mus = fam.linkinv(X1 @ cands.T + off[:, None])   # [N, 9]
+                devs = jnp.sum(w[:, None] * fam.deviance(y[:, None], mus),
+                               axis=0)
+                objs = devs + pens
+                k = jnp.argmin(objs)
+                new_obj = objs[k]
+                rel = jnp.abs(obj - new_obj) / jnp.maximum(
+                    jnp.abs(new_obj), 1e-10)
             new_coef = cands[k]
             delta = jnp.max(jnp.abs(new_coef - coef))
-        return new_coef, delta, obj, objs[k], it + 1
+        return new_coef, delta, new_obj, rel, it + 1
 
     # finite sentinels: ±inf would make rel = inf/inf = NaN and the
     # NaN > eps comparison (False) would skip the loop entirely
     coef, _, _, _, it = jax.lax.while_loop(
-        cond, body, (coef, jnp.float32(1e30), jnp.float32(-1e30),
-                     jnp.float32(1e30), jnp.int32(0)))
+        cond, body, (coef, jnp.float32(1e30), jnp.float32(1e30),
+                     jnp.float32(2.0), jnp.int32(0)))
     return coef, it
 
 
@@ -324,7 +398,7 @@ def _irls_iter_cod(X1, coef, y, w, off, l1, l2, lo, hi, family: str,
     non_negative projected path."""
     from h2o3_tpu.ops.optimize import coordinate_descent_quadratic
     fam = Family(family, tweedie_power, link, theta=theta)
-    eta = X1 @ coef + off
+    eta = _linear(X1, coef) + off
     mu = fam.linkinv(eta)
     d = fam.dmu_deta(eta, mu)
     var = fam.variance(mu)
@@ -354,9 +428,19 @@ def _glm_value_grad(coef, X1, y, w, off, l2, family: str, link: str,
     penalize = jnp.concatenate([jnp.ones(Pp1 - 1), jnp.zeros(1)]).astype(jnp.float32)
     nobs = jnp.maximum(jnp.sum(w), 1.0)
 
+    def dev_of(eta):
+        return jnp.sum(w * fam.deviance(y, fam.linkinv(eta))) / (2.0 * nobs)
+
+    if isinstance(X1, CodesDesign):
+        # the chain rule by hand: d/dcoef = X' d/deta (no residual per
+        # chunk of the lookup's scan is kept for a backward pass)
+        dev, g = jax.value_and_grad(dev_of)(
+            _linear(X1, coef.astype(jnp.float32)) + off)
+        return (dev + 0.5 * l2 * jnp.sum(penalize * coef * coef),
+                codes_rmatvec(X1, g, mesh=get_mesh()) + l2 * penalize * coef)
+
     def obj(c):
-        mu = fam.linkinv(X1 @ c.astype(jnp.float32) + off)
-        dev = jnp.sum(w * fam.deviance(y, mu)) / (2.0 * nobs)
+        dev = dev_of(X1 @ c.astype(jnp.float32) + off)
         return dev + 0.5 * l2 * jnp.sum(penalize * c * c)
 
     return jax.value_and_grad(obj)(coef)
@@ -369,10 +453,21 @@ def _multinomial_value_grad(flat, X1, y_int, w, l2, K: int):
     Y = (y_int[:, None] == jnp.arange(K)[None, :]).astype(jnp.float32)
     nobs = jnp.maximum(jnp.sum(w), 1.0)
 
+    def nll_of(eta):
+        return -jnp.sum(w[:, None] * Y * jax.nn.log_softmax(eta, axis=1)) \
+            / nobs
+
+    if isinstance(X1, CodesDesign):
+        B = flat.reshape(Pp1, K).astype(jnp.float32)
+        nll, g = jax.value_and_grad(nll_of)(_linear(X1, B))
+        pen = penalize[:, None] * B
+        return (nll + 0.5 * l2 * jnp.sum(pen ** 2),
+                (codes_rmatvec(X1, g, mesh=get_mesh())
+                 + l2 * penalize[:, None] * pen).reshape(flat.shape))
+
     def obj(fl):
         B = fl.reshape(Pp1, K).astype(jnp.float32)
-        logp = jax.nn.log_softmax(X1 @ B, axis=1)
-        nll = -jnp.sum(w[:, None] * Y * logp) / nobs
+        nll = nll_of(X1 @ B)
         return nll + 0.5 * l2 * jnp.sum((penalize[:, None] * B) ** 2)
 
     return jax.value_and_grad(obj)(flat)
@@ -394,7 +489,7 @@ def _multinomial_irls_solve(X1, B, y_int, w, l1, l2, beta_eps, max_iter,
     mesh = get_mesh()
 
     def one_class(B, c):
-        eta = X1 @ B
+        eta = _linear(X1, B)
         p = jax.nn.softmax(eta, axis=1)
         pc = p[:, c]
         yc = (y_int == c).astype(jnp.float32)
@@ -551,9 +646,9 @@ class GLMModel(Model):
                             standardize=self.params.get("standardize", True),
                             use_all_factor_levels=self.params.get(
                                 "use_all_factor_levels", False),
-                            stats_override=self.di_stats)
-        ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
-        return jnp.concatenate([di.X, ones], axis=1)
+                            stats_override=self.di_stats,
+                            codes=holds_codes(self.output.get("family")))
+        return _with_intercept(di.X)
 
     def _frame_offset(self, frame: Frame):
         oc = self.params.get("offset_column")
@@ -571,8 +666,9 @@ class GLMModel(Model):
             # reference ignores it for multinomial with a warning
             # (hex/glm/GLM.java:978 "offset has no effect on
             # multinomial and will be ignored")
-            return X1 @ jnp.asarray(self.coef_multinomial, jnp.float32)
-        eta = X1 @ jnp.asarray(self.coef, jnp.float32)
+            return _linear(X1, jnp.asarray(self.coef_multinomial,
+                                           jnp.float32))
+        eta = _linear(X1, jnp.asarray(self.coef, jnp.float32))
         return eta if off is None else eta + off
 
     def _ordinal_probs(self, frame: Frame) -> jax.Array:
@@ -626,9 +722,10 @@ class GLMModel(Model):
                  jnp.ones((eta.shape[0], 1), jnp.float32)], axis=1)
             return jnp.diff(cum, axis=1)
         if self.coef_multinomial is not None:
-            return jax.nn.softmax(
-                X1 @ jnp.asarray(self.coef_multinomial, jnp.float32), axis=1)
-        return self.family.linkinv(X1 @ jnp.asarray(self.coef, jnp.float32))
+            return jax.nn.softmax(_linear(
+                X1, jnp.asarray(self.coef_multinomial, jnp.float32)), axis=1)
+        return self.family.linkinv(
+            _linear(X1, jnp.asarray(self.coef, jnp.float32)))
 
     def _serve_finish(self, fetched: np.ndarray, n: int) -> Dict[str, np.ndarray]:
         """Host half of the serving fast path: the exact host tail of
@@ -743,6 +840,14 @@ class GLMEstimator(ModelBuilder):
         keep_cross_validation_predictions=False,
         keep_cross_validation_fold_assignment=False,
     )
+
+    def design_row_bytes(self, frame: Frame, x) -> int:
+        """``DataInfo.X`` and ``X1`` (the same with the intercept) a row,
+        as ``_fit`` builds them, and the row state."""
+        p = self.params
+        X = design_row_bytes(frame, x, bool(p["use_all_factor_levels"]),
+                             holds_codes(p["family"]))
+        return 2 * X + 4 + ROW_STATE_BYTES
 
     def __init__(self, **params):
         merged = dict(self.DEFAULTS)
@@ -931,10 +1036,13 @@ class GLMEstimator(ModelBuilder):
             di = build_datainfo(
                 di_frame, x, standardize=bool(p["standardize"]),
                 use_all_factor_levels=bool(p["use_all_factor_levels"]),
-                missing_values_handling=p["missing_values_handling"])
-            ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
-            X1 = jax.device_put(jnp.concatenate([di.X, ones], axis=1),
-                                row_sharding(mesh))
+                missing_values_handling=p["missing_values_handling"],
+                codes=holds_codes(fam_name))
+            X1 = jax.device_put(_with_intercept(di.X), row_sharding(mesh))
+            codes = isinstance(X1, CodesDesign)
+            telemetry.annotate(design="codes" if codes else "dense",
+                               p=int(X1.shape[1]),
+                               cat_levels=X1.cat_levels if codes else 0)
             # the response column joins the design row for row, as it lies
             assert X1.shape[0] == frame.nrows_padded
 
@@ -1040,7 +1148,8 @@ class GLMEstimator(ModelBuilder):
                                       solver=msolver, l1=l1_m)
             model = GLMModel(p, output, B[:, 0], Family("binomial"),
                              stats_of(di), list(x), coef_multinomial=B)
-            probs = jax.nn.softmax(X1 @ jnp.asarray(B, jnp.float32), axis=1)
+            probs = jax.nn.softmax(_linear(X1, jnp.asarray(B, jnp.float32)),
+                                   axis=1)
             model.training_metrics = mm.multinomial_metrics(
                 probs, y_dev, w, domain=rc.domain)
             job.update(1.0)
@@ -1091,7 +1200,8 @@ class GLMEstimator(ModelBuilder):
                               jnp.float32)
             stepprof.chunk_begin()
             with telemetry.span("glm.solve", solver=solver,
-                                lambdas=len(lambdas)) as sp:
+                                lambdas=len(lambdas),
+                                p=int(X1.shape[1])) as sp:
                 best, coef_path, its = _irls_solve_path(
                     X1, jnp.asarray(coef, jnp.float32), y_dev, w, off_or0,
                     l1s, l2s, jnp.float32(p["beta_epsilon"]),
@@ -1129,7 +1239,8 @@ class GLMEstimator(ModelBuilder):
                 l2 = lam * (1.0 - alpha)
                 stepprof.chunk_begin()
                 with telemetry.span("glm.solve", solver=solver,
-                                    lam=float(lam)) as sp:
+                                    lam=float(lam),
+                                    p=int(X1.shape[1])) as sp:
                     if solver in ("coordinate_descent",
                                   "coordinate_descent_naive"):
                         coef, its = self._fit_cod(
@@ -1191,7 +1302,8 @@ class GLMEstimator(ModelBuilder):
             model._coef_path = np.asarray(coef_path)      # [L, P+1]
             model._lambda_path_vals = list(lambdas)
         with telemetry.span("glm.metrics"):
-            mu = fam.linkinv(X1 @ jnp.asarray(coef, jnp.float32) + off_or0)
+            mu = fam.linkinv(_linear(X1, jnp.asarray(coef, jnp.float32))
+                             + off_or0)
             if category == ModelCategory.BINOMIAL:
                 model.training_metrics = mm.binomial_metrics(mu, y_dev, w)
                 model.output["default_threshold"] = \
@@ -1226,7 +1338,10 @@ def _lambda_path(p, X1, y, w, nobs, alpha, mesh) -> List[float]:
         return list(lam) if isinstance(lam, (list, tuple)) else [float(lam)]
     # lambda_max: smallest lambda with all (penalized) coefs zero
     ybar = float(jnp.sum(w * y) / jnp.maximum(jnp.sum(w), 1e-12))
-    xty = jnp.abs((X1 * w[:, None]).T @ (y - ybar))[:-1]  # exclude intercept
+    if isinstance(X1, CodesDesign):
+        xty = jnp.abs(codes_rmatvec(X1, w * (y - ybar), mesh=mesh))[:-1]
+    else:
+        xty = jnp.abs((X1 * w[:, None]).T @ (y - ybar))[:-1]  # no intercept
     lam_max = float(jnp.max(xty)) / (nobs * max(alpha, 1e-3))
     lmr = float(p["lambda_min_ratio"])
     if lmr <= 0:            # wire default -1 = auto (GLMParameters)
@@ -1248,7 +1363,7 @@ def _p_values_table(X1, y, w, coef, fam: Family, names, nobs: float,
     moment-estimated dispersion, other families the normal (z) with
     dispersion 1 (binomial/poisson) or the Pearson estimate (gamma/
     tweedie), matching the reference's computePValues path."""
-    eta = X1 @ coef if off is None else X1 @ coef + off
+    eta = _linear(X1, coef) if off is None else _linear(X1, coef) + off
     mu = fam.linkinv(eta)
     name = fam.name
     # general GLM Fisher weight: (dmu/deta)^2 / Var(mu) — exact for every
@@ -1256,7 +1371,10 @@ def _p_values_table(X1, y, w, coef, fam: Family, names, nobs: float,
     dmu = fam.dmu_deta(eta, mu)
     vw = dmu * dmu / jnp.maximum(fam.variance(mu), 1e-12)
     wi = w * vw
-    info = (X1 * wi[:, None]).T @ X1
+    if isinstance(X1, CodesDesign):
+        info = gram(X1, wi, jnp.zeros_like(wi), mesh=get_mesh())[0]
+    else:
+        info = (X1 * wi[:, None]).T @ X1
     info_h = np.asarray(info, dtype=np.float64)
     P = info_h.shape[0]
     try:
@@ -1364,10 +1482,9 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
     di = build_datainfo(frame, x, standardize=bool(p0["standardize"]),
                         use_all_factor_levels=bool(
                             p0["use_all_factor_levels"]),
-                        missing_values_handling=p0["missing_values_handling"])
-    ones = jnp.ones((di.X.shape[0], 1), jnp.float32)
-    X1 = jax.device_put(jnp.concatenate([di.X, ones], axis=1),
-                        row_sharding(mesh))
+                        missing_values_handling=p0["missing_values_handling"],
+                        codes=holds_codes(fam_name))
+    X1 = jax.device_put(_with_intercept(di.X), row_sharding(mesh))
     assert X1.shape[0] == frame.nrows_padded
     w = frame.valid_weights()
     if p0.get("weights_column"):
@@ -1428,7 +1545,8 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
         output["lambda_best"] = lams[m]
         model = GLMModel(builders[m].params, output, coefs[m], fam,
                          stats_of(di), list(x))
-        mu = fam.linkinv(X1 @ jnp.asarray(coefs[m], jnp.float32) + off_or0)
+        mu = fam.linkinv(_linear(X1, jnp.asarray(coefs[m], jnp.float32))
+                         + off_or0)
         if category == ModelCategory.BINOMIAL:
             model.training_metrics = mm.binomial_metrics(mu, y_dev, w)
             model.output["default_threshold"] = \

@@ -572,6 +572,13 @@ class ModelBuilder:
             **config)
         return bm, "miss" if built else "hit"
 
+    def design_row_bytes(self, frame: Frame,
+                         x: Sequence[str]) -> Optional[int]:
+        """Device bytes a row of the design and row state this fit
+        builds, for admission (``core/memgov.estimate_fit_bytes``); None:
+        4 B a feature."""
+        return None
+
     # -- public train --------------------------------------------------
     def resolve_x(self, frame: Frame, x: Optional[Sequence[str]],
                   y: Optional[str]) -> List[str]:
@@ -616,9 +623,9 @@ class ModelBuilder:
             # minutes in). The reservation releases when the job ends,
             # whatever status.
             from h2o3_tpu.core import memgov as _memgov
-            _rsv = _memgov.governor.admit_fit(self.algo, self.params,
-                                              training_frame, x,
-                                              validation_frame)
+            _rsv = _memgov.governor.admit_fit(
+                self.algo, self.params, training_frame, x, validation_frame,
+                self.design_row_bytes(training_frame, x))
         # the model key must exist BEFORE training starts: the real h2o-py
         # captures job.dest at submission time (h2o-py/h2o/job.py:48).
         # The job is made outside the admission span: it parents under

@@ -44,6 +44,10 @@ GLM_ROWS, DL_ROWS, SCORE_ROWS = 2_000_000, 200_000, 100_000
 HIGGS_ROWS = 11_000_000                  # benchmark cell glm-higgs.fit-11m
 AIR48_ROWS = 48_000_000                  # cell gbm-airlines-d6.fit-48m (F, B)
 MNIST1M_ROWS = 1_048_576                 # cell dl-mnist8m-200x200.fit-1m
+CAT116_ROWS = 116_000_000                # cell glm-airlines-116m-cat.fit-116m
+# that cell's factors (12, 31, 7, 29, 340, 340 levels) and two numerics
+CAT_LEVELS = {"Month": 12, "DayofMonth": 31, "DayOfWeek": 7,
+              "UniqueCarrier": 29, "Origin": 340, "Dest": 340}
 TINY_ROWS = 3000
 LEVELS = (0, 3, 5)                       # of depth-bucket 6
 
@@ -457,6 +461,51 @@ def test_glm_irls_solve(topo, chips, rows, batch):
     assert not _row_sized_moves(txt, n_local)
     x_bytes = n_local * 29 * 4                   # X1: 28 columns + intercept
     assert compiled.memory_analysis().temp_size_in_bytes < x_bytes
+
+
+def _row_by_width_arrays(txt, n, width):
+    """Shapes of a compiled module with a dimension of at least ``n``
+    elements and ``width`` or more elements beside it."""
+    out = []
+    for dims in re.findall(r"\w+\[([\d,]+)\]", txt):
+        d = [int(x) for x in dims.split(",") if x]
+        if max(d) >= n and np.prod(d) // max(d) >= width:
+            out.append(tuple(d))
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_glm_irls_solve_on_codes(topo, chips):
+    """The IRLS solve of ``glm-airlines-116m-cat.fit-116m`` at its 116M
+    rows: the design held as codes, 756 coefficients. No array of rows by
+    coefficients (351 GB) is in the program, nor rows by any width past
+    the line search's nine candidates — the factor Gram and the lookups
+    walk a shard's rows a chunk at a time; the program fits the chip."""
+    from h2o3_tpu.models.glm import GLMEstimator
+    r = np.random.RandomState(4)
+    cols = {f: r.randint(0, L, TINY_ROWS) for f, L in CAT_LEVELS.items()}
+    cols["DepTime"] = r.randint(0, 2400, TINY_ROWS)
+    cols["Distance"] = r.randint(30, 4983, TINY_ROWS)
+    cols["y"] = r.randint(0, 2, TINY_ROWS)
+    doms = {f: [f"{f}{i:03d}" for i in range(L)]
+            for f, L in CAT_LEVELS.items()}
+    doms["y"] = ["NO", "YES"]
+    fr = h2o3_tpu.Frame.from_numpy(cols, domains=doms)
+    GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
+                 max_iterations=1).train(fr, y="y")
+    mesh = _mesh(topo, chips)
+    compiled = _lower_recorded("glm.irls_solve", mesh, fr.nrows_padded,
+                               CAT116_ROWS).compile()
+    txt = compiled.as_text()
+    n_local = mesh_mod.padded_rows(CAT116_ROWS, mesh) // chips
+    assert "gram.cat" in txt and "glm.eta" in txt
+    assert ("all-reduce" in txt) == (chips > 1)
+    assert not _row_by_width_arrays(txt, n_local, 10)
+    mem = compiled.memory_analysis()
+    # the frame's codes and the numerics are the arguments; what the
+    # solve adds is row-sized vectors (3.3 GB on one chip)
+    assert mem.temp_size_in_bytes < 40 * n_local
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
 
 
 @pytest.mark.parametrize("chips", [1, 4])
