@@ -73,6 +73,9 @@ class CodesDesign:
 
     A pytree: the arrays are leaves, the layout is static, so a jitted
     function compiles once a layout and the rows ride the data axis.
+    ``gram_kernel`` is how ``ops/gram.py`` forms the factor Gram — the
+    ``ops/pallas`` mode a fit resolved (``gram.with_gram_kernel``), static
+    like the layout, so that another mode compiles another program.
     """
     codes: Tuple[jax.Array, ...]     # per factor [Npad] integer codes
     nas: Tuple[jax.Array, ...]       # per factor [Npad] bool
@@ -80,10 +83,11 @@ class CodesDesign:
     factors: Tuple[Tuple[int, int, int], ...] = ()
     dense_cols: Tuple[int, ...] = ()
     p: int = 0                       # columns of the matrix it stands for
+    gram_kernel: str = "off"         # off | native | interpret
 
     def tree_flatten(self):
         return ((self.codes, self.nas, self.dense),
-                (self.factors, self.dense_cols, self.p))
+                (self.factors, self.dense_cols, self.p, self.gram_kernel))
 
     @classmethod
     def tree_unflatten(cls, aux, children):

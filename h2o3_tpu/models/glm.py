@@ -39,7 +39,8 @@ from h2o3_tpu.models import metrics as mm
 from h2o3_tpu.models.model import (Model, ModelBuilder, ModelCategory,
                                    adapt_domain, infer_category,
                                    response_on_device)
-from h2o3_tpu.ops.gram import codes_matvec, codes_rmatvec, gram
+from h2o3_tpu.ops.gram import (codes_matvec, codes_rmatvec, gram,
+                               gram_kernel_name, with_gram_kernel)
 from h2o3_tpu.ops.optimize import (admm_l1_quadratic,
                                    cholesky_solve_regularized, lbfgs)
 from h2o3_tpu.parallel.mesh import get_mesh, row_sharding
@@ -1038,7 +1039,9 @@ class GLMEstimator(ModelBuilder):
                 use_all_factor_levels=bool(p["use_all_factor_levels"]),
                 missing_values_handling=p["missing_values_handling"],
                 codes=holds_codes(fam_name))
-            X1 = jax.device_put(_with_intercept(di.X), row_sharding(mesh))
+            # the factor Gram's kernel mode: resolved once a fit, static
+            X1 = with_gram_kernel(
+                jax.device_put(_with_intercept(di.X), row_sharding(mesh)))
             codes = isinstance(X1, CodesDesign)
             telemetry.annotate(design="codes" if codes else "dense",
                                p=int(X1.shape[1]),
@@ -1201,7 +1204,8 @@ class GLMEstimator(ModelBuilder):
             stepprof.chunk_begin()
             with telemetry.span("glm.solve", solver=solver,
                                 lambdas=len(lambdas),
-                                p=int(X1.shape[1])) as sp:
+                                p=int(X1.shape[1]),
+                                gram_kernel=gram_kernel_name(X1)) as sp:
                 best, coef_path, its = _irls_solve_path(
                     X1, jnp.asarray(coef, jnp.float32), y_dev, w, off_or0,
                     l1s, l2s, jnp.float32(p["beta_epsilon"]),
@@ -1240,7 +1244,8 @@ class GLMEstimator(ModelBuilder):
                 stepprof.chunk_begin()
                 with telemetry.span("glm.solve", solver=solver,
                                     lam=float(lam),
-                                    p=int(X1.shape[1])) as sp:
+                                    p=int(X1.shape[1]),
+                                    gram_kernel=gram_kernel_name(X1)) as sp:
                     if solver in ("coordinate_descent",
                                   "coordinate_descent_naive"):
                         coef, its = self._fit_cod(
@@ -1478,7 +1483,9 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
     fam = Family(fam_name, float(p0["tweedie_power"]), p0["link"],
                  theta=float(p0.get("theta") or 1e-5))
 
-    # ---- shared preamble (identical to the sequential _fit) ----------
+    # ---- shared preamble (identical to the sequential _fit, but for the
+    # factor Gram's mode: a design held as codes keeps ``off``, the XLA
+    # scan — the kernel is not batched) ----------------------------------
     di = build_datainfo(frame, x, standardize=bool(p0["standardize"]),
                         use_all_factor_levels=bool(
                             p0["use_all_factor_levels"]),
@@ -1522,7 +1529,8 @@ def fit_glm_batched(builder_cls, params_list: List[dict], frame: Frame,
             continue
         stepprof.chunk_begin()
         with telemetry.span("glm.solve_batched", solver="irlsm",
-                            width=int(idx.size)) as sp:
+                            width=int(idx.size),
+                            gram_kernel=gram_kernel_name(X1)) as sp:
             out = _irls_solve_batched(
                 X1, coef0, y_dev, w, off_or0,
                 jnp.asarray(l1_all[idx]), jnp.asarray(l2_all[idx]),

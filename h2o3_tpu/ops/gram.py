@@ -12,12 +12,16 @@ matrix: as hex/gram/Gram accumulates its categorical block from level
 indices, each shard walks its rows a chunk at a time, builds the chunk's
 0/1 indicator rows on the chip and multiplies them with three bfloat16
 pieces of every weight (``ops/histogram.split3``): products exact,
-float32 sums, compensated from chunk to chunk (scope ``gram.cat``). The same chunk walk gives the linear
+float32 sums, compensated from chunk to chunk (scope ``gram.cat``) — an
+XLA scan, or where the fit's ``ops/pallas`` mode asks for kernels ONE
+Pallas kernel a shard that keeps the sums in VMEM
+(``ops/pallas/gramkernel.py``). The same chunk walk gives the linear
 predictor ``X @ beta`` (coefficient lookups) and ``X' v``.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -27,6 +31,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu.frame.datainfo import CodesDesign
+from h2o3_tpu.ops import pallas as pallas_policy
 from h2o3_tpu.ops.histogram import split3
 from h2o3_tpu.parallel.mesh import DATA_AXIS
 
@@ -44,6 +49,11 @@ CAT_SUM = 8192
 # a left operand of the factor pairs' products gathers factors up to the
 # MXU's height; a wider factor is an operand of its own
 CAT_GROUP_ROWS = 128
+# rows a grid step of the factor Gram's Pallas kernel reads; its sums are
+# added with compensation every gramkernel.SUB_ROWS rows. A pass at the
+# airlines cell's rows on a v5e: 0.737 s at 4,096, 0.756 at 2,048, 0.807
+# at 16,384, 0.860 at 8,192 (PERF.md §6, PR 42)
+CAT_KERNEL_ROWS = 4096
 
 
 def _local_gram(X, wz):
@@ -197,14 +207,97 @@ def _cat_plan(factors):
     return order, groups
 
 
+def _right_of(members, n_factors: int) -> range:
+    """The factors a left operand meets: its own where it gathers
+    several, and every factor after it."""
+    first = members[0] if len(members) > 1 else members[-1] + 1
+    return range(first, n_factors)
+
+
+def _kernel_geometry(X):
+    """The plan of ``X`` as ``ops/pallas/gramkernel.Geometry``: its left
+    operands as groups of factors, a product for each that meets a
+    factor (its right factors start at a group: a lone factor meets the
+    next group on)."""
+    from h2o3_tpu.ops.pallas.gramkernel import Geometry
+    order, groups = _cat_plan(X.factors)
+    F = len(order)
+    first = [m[0] for _, _, m in groups]
+    return Geometry(
+        widths=tuple(X.factors[f][2] - X.factors[f][1] for f in order),
+        groups=tuple((m[0], m[-1] + 1) for _, _, m in groups),
+        products=tuple((g, first.index(_right_of(m, F).start))
+                       for g, (_, _, m) in enumerate(groups)
+                       if len(_right_of(m, F))),
+        nd=X.dense.shape[1])
+
+
+def with_gram_kernel(X):
+    """``X`` with the factor Gram's mode for a fit, resolved once a fit as
+    the trees' ``TreeParams.pallas`` is (``ops/pallas.resolve_tree_mode``):
+    the Pallas kernel where the mode asks for kernels and the plan's sums
+    and a block's operands fit ``VMEM_BUDGET_BYTES``, else the XLA scan —
+    a plan that does not fit counted as the fallback ``cat_gram_vmem``.
+    A dense design is returned as it is."""
+    if not isinstance(X, CodesDesign):
+        return X
+    mode = pallas_policy.resolve_tree_mode()
+    if mode != "off":
+        from h2o3_tpu.ops.pallas import gramkernel
+        if not gramkernel.fits(_kernel_geometry(X), CAT_KERNEL_ROWS):
+            pallas_policy.record_fallback("cat_gram_vmem")
+            mode = "off"
+    return dataclasses.replace(X, gram_kernel=mode)
+
+
+def gram_kernel_name(X) -> str:
+    """What forms the Gram of ``X``: ``pallas`` or ``xla`` (span meta)."""
+    return ("pallas" if isinstance(X, CodesDesign)
+            and X.gram_kernel != "off" else "xla")
+
+
+def _kernel_sums(X, wz, order, groups):
+    """``_scan_sum``'s sums of the plan — the pairs' blocks, the
+    statistics rows and the numeric block — from ONE Pallas kernel over
+    the shard (``ops/pallas/gramkernel.cat_gram_sums``), its padded
+    sums cut back to the plan's shapes."""
+    from h2o3_tpu.ops.pallas import gramkernel
+    geo = _kernel_geometry(X)
+    ks = [jnp.where(X.nas[f], -1,
+                    X.codes[f].astype(jnp.int32) - X.factors[f][1])
+          for f in order]
+    *prods, stats, num = gramkernel.cat_gram_sums(
+        ks, wz[:, 0], wz[:, 1], X.dense, geo=geo, block=CAT_KERNEL_ROWS,
+        interpret=X.gram_kernel == "interpret")
+    # a factor's first row in the kernel's stacked tiles
+    row = []
+    for (a, b), start in zip(geo.groups, geo.starts):
+        row += [start + sum(geo.widths[a:f]) for f in range(a, b)]
+    out_of = dict(zip((g for g, _ in geo.products), prods))
+    pairs, by_stats = [], []
+    for g, (_, m, members) in enumerate(groups):
+        right = _right_of(members, len(order))
+        at = row[right.start] if len(right) else 0
+        pairs.append([out_of[g][:m, row[f] - at:row[f] - at + geo.widths[f]]
+                      for f in right])
+        by_stats.append(stats[:3 * geo.nv, row[members[0]]:
+                              row[members[0]] + m].T)
+    nd = geo.nd
+    q = num[:geo.nq, 0]
+    return pairs, by_stats, (q[:nd * nd].reshape(nd, nd),
+                             q[nd * nd:nd * nd + nd], q[-1])
+
+
 def _local_codes_gram(X, wz):
     """One shard's (X'WX, X'Wz, sum w) of a ``CodesDesign``, in the
     coefficients' order, from one scan over chunks of rows whose terms
-    are added with compensation (``_kahan``). A chunk: each factor's
-    indicator rows against three bfloat16 pieces of the weights (pairs
-    of factors) and of the statistics rows ``w·dense_j``, ``w``, ``w·z``
-    (factor x numeric, the factors' diagonals, X'Wz) — exact products,
-    float32 sums — and the numeric block at float32 precision."""
+    are added with compensation (``_kahan``) — or from the Pallas kernel
+    where ``X.gram_kernel`` asks for it (``_kernel_sums``). A chunk: each
+    factor's indicator rows against three bfloat16 pieces of the weights
+    (pairs of factors) and of the statistics rows ``w·dense_j``, ``w``,
+    ``w·z`` (factor x numeric, the factors' diagonals, X'Wz) — exact
+    products, float32 sums — and the numeric block at float32
+    precision."""
     n = wz.shape[0]
     c = _chunk(n)
     order, groups = _cat_plan(X.factors)
@@ -217,10 +310,7 @@ def _local_codes_gram(X, wz):
     hi = jax.lax.Precision.HIGHEST
 
     def right_of(members):
-        """The factors a left operand meets: its own where it gathers
-        several, and every factor after it."""
-        first = members[0] if len(members) > 1 else members[-1] + 1
-        return range(first, len(fac))
+        return _right_of(members, len(fac))
 
     with jax.named_scope("gram.cat"):
         def step(i):
@@ -250,7 +340,11 @@ def _local_codes_gram(X, wz):
                  [jnp.zeros((m, 3 * nv), jnp.float32) for _, m, _ in groups],
                  (jnp.zeros((nd, nd), jnp.float32),
                   jnp.zeros((nd,), jnp.float32), jnp.float32(0.0)))
-        pairs, by_stats, (xtx_n, xtz_n, ws) = _scan_sum(step, zeros, n // c)
+        if X.gram_kernel == "off":
+            sums = _scan_sum(step, zeros, n // c)
+        else:
+            sums = _kernel_sums(X, wz, order, groups)
+        pairs, by_stats, (xtx_n, xtz_n, ws) = sums
 
         G = jnp.zeros((pc + nd, pc + nd), jnp.float32)
         xtz_c = jnp.zeros((pc,), jnp.float32)

@@ -88,9 +88,11 @@ def decide(knob: str, backend: str, data_shards: int,
 
 
 def resolve_tree_mode() -> str:
-    """Resolve the tree-kernel mode for a fit (counts + logs fallbacks).
+    """Resolve the kernel mode for a fit (counts + logs fallbacks).
 
-    Called once per model fit by the tree builders; the result rides in
+    Called once per model fit by the tree builders and by a GLM whose
+    design is held as codes (``ops/gram.with_gram_kernel``, where the
+    result rides in ``CodesDesign.gram_kernel``); the trees' rides in
     ``TreeParams.pallas`` (a STATIC jit field), so flipping the knob
     mid-process compiles a fresh boosting program instead of silently
     reusing a cached one with the old decision.
@@ -111,7 +113,7 @@ def record_fallback(reason: str) -> None:
         _LOGGED_REASONS.add(reason)
         from h2o3_tpu.utils.log import get_logger
         get_logger("h2o3_tpu.ops.pallas").info(
-            "Pallas tree kernels falling back to XLA (%s); further "
+            "Pallas kernels falling back to XLA (%s); further "
             "occurrences counted in pallas_fallbacks_total, not logged",
             reason)
 

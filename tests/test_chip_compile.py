@@ -268,12 +268,20 @@ def _at_scale(tree, n_tiny, n_real, mesh):
     return jax.tree_util.tree_map(place, tree)
 
 
-def _lower_recorded(name, mesh, n_tiny, rows, **static_overrides):
+def _lower_recorded(name, mesh, n_tiny, rows, gram_kernel=None,
+                    **static_overrides):
+    """``gram_kernel``: the factor Gram's mode a design held as codes
+    carries into the lowering (the CPU fit that recorded it resolved
+    ``off``)."""
+    from h2o3_tpu.frame.datainfo import CodesDesign
     jit_fn, aargs, akwargs = compile_observer.aot_source(name)
     n_real = mesh_mod.padded_rows(rows, mesh)
     akwargs = dict(_at_scale(akwargs, n_tiny, n_real, mesh))
     akwargs.update(static_overrides)
     aargs = _at_scale(aargs, n_tiny, n_real, mesh)
+    if gram_kernel is not None:
+        aargs = [dataclasses.replace(a, gram_kernel=gram_kernel)
+                 if isinstance(a, CodesDesign) else a for a in aargs]
     assert any(x.shape[:1] == (n_real,)
                for x in jax.tree_util.tree_leaves(aargs)
                if isinstance(x, S)), "no argument carries the row axis"
@@ -481,6 +489,20 @@ def test_glm_irls_solve_on_codes(topo, chips):
     coefficients (351 GB) is in the program, nor rows by any width past
     the line search's nine candidates — the factor Gram and the lookups
     walk a shard's rows a chunk at a time; the program fits the chip."""
+    _solve_on_codes(topo, chips, None)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_glm_irls_solve_on_codes_with_the_kernel(topo, chips):
+    """The same solve with the factor Gram as the Pallas kernel
+    (``ops/pallas/gramkernel.py``), the mode ``auto`` resolves to on a
+    TPU: the same asserts, and the kernel in the program."""
+    assert _auto_on_tpu(chips) == "native"
+    txt = _solve_on_codes(topo, chips, "native")
+    assert "tpu_custom_call" in txt and "glm_cat_gram" in txt
+
+
+def _solve_on_codes(topo, chips, gram_kernel):
     from h2o3_tpu.models.glm import GLMEstimator
     r = np.random.RandomState(4)
     cols = {f: r.randint(0, L, TINY_ROWS) for f, L in CAT_LEVELS.items()}
@@ -495,7 +517,7 @@ def test_glm_irls_solve_on_codes(topo, chips):
                  max_iterations=1).train(fr, y="y")
     mesh = _mesh(topo, chips)
     compiled = _lower_recorded("glm.irls_solve", mesh, fr.nrows_padded,
-                               CAT116_ROWS).compile()
+                               CAT116_ROWS, gram_kernel=gram_kernel).compile()
     txt = compiled.as_text()
     n_local = mesh_mod.padded_rows(CAT116_ROWS, mesh) // chips
     assert "gram.cat" in txt and "glm.eta" in txt
@@ -506,6 +528,60 @@ def test_glm_irls_solve_on_codes(topo, chips):
     # solve adds is row-sized vectors (3.3 GB on one chip)
     assert mem.temp_size_in_bytes < 40 * n_local
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    return txt
+
+
+def _cat116_design(mesh, gram_kernel):
+    """The cell's design as shapes: six factors (first levels dropped),
+    DepTime, Distance and the intercept, 116M rows over ``mesh``."""
+    from h2o3_tpu.frame.datainfo import CodesDesign
+    n = mesh_mod.padded_rows(CAT116_ROWS, mesh)
+    row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
+    factors, off = [], 0
+    for card in CAT_LEVELS.values():
+        factors.append((off, 1, card))
+        off += card - 1
+    X = CodesDesign(
+        codes=tuple(S((n,), jnp.int32, sharding=row) for _ in factors),
+        nas=tuple(S((n,), jnp.bool_, sharding=row) for _ in factors),
+        dense=S((n, 3), jnp.float32, sharding=row), factors=tuple(factors),
+        dense_cols=(off, off + 1, off + 2), p=off + 3,
+        gram_kernel=gram_kernel)
+    return X, S((n,), jnp.float32, sharding=row), n
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_glm_cat_gram_kernel_native(topo, chips):
+    """The factor Gram's kernel at the cell's widths and rows: 116M rows
+    on one chip, a quarter a chip on four. It compiles; within the
+    kernel's VMEM budget (compiled with its operands as they arrive,
+    held to ``vmem_limit_bytes`` = ``VMEM_BUDGET_BYTES``: the fused form
+    is placed without that limit); its operands reach it as views, with
+    their producers (the NA select, the weights' slices, the numerics'
+    transpose) fused into it — no row-sized temporary."""
+    from h2o3_tpu.ops import gram as gram_mod
+    from h2o3_tpu.ops.pallas import gramkernel
+    mesh = _mesh(topo, chips)
+    X, vec, n = _cat116_design(mesh, "native")
+    geo = gram_mod._kernel_geometry(X)
+    assert geo.widths == (6, 11, 28, 30, 339, 339)
+    assert gramkernel.fits(geo, gram_mod.CAT_KERNEL_ROWS)
+    with _as_global_mesh(mesh):
+        compiled = jax.jit(lambda X, w, z: gram_mod.gram(
+            X, w, z, mesh=mesh)).lower(X, vec, vec).compile()
+    txt = compiled.as_text()
+    assert "glm_cat_gram" in txt
+    assert ("all-reduce" in txt) == (chips > 1)
+    n_local = n // chips
+    assert compiled.memory_analysis().temp_size_in_bytes < n_local
+    one = _one_chip(topo)
+    rows = lambda *s: S(s, jnp.float32, sharding=one)   # noqa: E731
+    unfused = jax.jit(lambda ks, w, wz, d: gramkernel.cat_gram_sums(
+        ks, w, wz, d, geo=geo, block=gram_mod.CAT_KERNEL_ROWS,
+        interpret=False, fuse_inputs=False)).lower(
+        [S((n_local,), jnp.int32, sharding=one)] * 6, rows(n_local),
+        rows(n_local), rows(n_local, 3)).compile()
+    assert unfused.memory_analysis().temp_size_in_bytes < n_local
 
 
 @pytest.mark.parametrize("chips", [1, 4])
