@@ -441,15 +441,16 @@ class DRFEstimator(ModelBuilder):
         n_complete = frontier.complete_levels(N, tp.max_depth,
                                               tp.frontier_from)
         kl = kernel_levels(tp, F)[:n_complete]
-        # a forest's trees have a hessian of 1: two statistics a row
-        tile, pieces = frontier.hist_plan(tp, N, F, 2)
+        # a forest's trees have a hessian of 1: two statistics a row,
+        # in the complete levels' kernel passes and the frontier's alike
+        tile, ns, pieces = frontier.hist_plan(tp, N, F, unit_h=True)
         paths = {"levels_kernel": sum(kl),
                  "levels_xla": n_complete - sum(kl),
                  "levels_frontier": tp.max_depth - n_complete,
                  "levels_sorted": frontier.sort_levels(
                      tp.max_depth - n_complete, tp.frontier_sort_every),
                  "frontier_hist": "kernel" if tile else "xla",
-                 "hist_operand_rows": 2 * pieces}
+                 "hist_operand_rows": ns * pieces}
 
         # target matrix ys [Npad, K] from the device response (weighted-
         # out rows read 0): the values, or indicators for classification

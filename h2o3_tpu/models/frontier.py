@@ -31,11 +31,12 @@ layout and its kernels stay for the shallow levels they fit. Below them
   is). The range is cut into
   chunks of ``CHUNK_ROWS`` rows; a chunk's histogram is the one-hot
   product of ``ops/histogram.py`` over the block's LOCAL node ids — the
-  same ``stat_rows`` operand, three bfloat16 pieces a statistic, so
-  every sum is a float32 sum. ``SUPER_BLOCKS`` blocks make a
-  super-batch: its histogram ``[nodes, F, B, 3]`` goes through the ONE
-  split rule (``tree._best_splits``) and is dropped, its rows are routed
-  to their children — into the NEXT level's keys, a second array: the
+  same ``stat_rows`` operand as the complete levels' (``tree.stats_plan``
+  gives both their statistics and bfloat16 pieces), so every sum is a
+  float32 sum. ``SUPER_BLOCKS`` blocks make a super-batch: its
+  histogram ``[nodes, F, B, 3]`` goes through the ONE split rule
+  (``tree._best_splits``) and is dropped, its rows are routed to their
+  children — into the NEXT level's keys, a second array: the
   keys a level's passes read are the ones it started with — and the
   next super-batch reuses the memory. A level
   therefore costs ``rows / CHUNK_ROWS + live nodes / NODE_BLOCK`` chunk
@@ -163,23 +164,25 @@ def block_ranges(key_at_sort, anc, n_live, *, lb: int):
     return jnp.where(a < n_live, lo, hi), hi
 
 
-def hist_plan(params, n_rows: int, n_features: int, n_stats: int):
-    """(tile, pieces) of a frontier level's histogram pass: the row tile
-    of the Pallas kernel ``tree_frontier_hist`` — 0 where the fit asked
-    for no kernels or no tile fits, and the XLA chunk product runs — and
-    the bfloat16 pieces a statistic enters the product in (1 where
-    ``params.whole_stats``, else 3). ``n_stats * pieces`` is the operand's
-    rows a node. The one place that decides it: ``grow_frontier`` follows
-    it and the forest fit reports it on ``drf.chunk``."""
-    pieces = 1 if params.whole_stats else 3
+def hist_plan(params, n_rows: int, n_features: int, unit_h: bool):
+    """(tile, n_stats, pieces) of a frontier level's histogram pass: the
+    row tile of the Pallas kernel ``tree_frontier_hist`` — 0 where the
+    fit asked for no kernels or no tile fits, and the XLA chunk product
+    runs — and the operand's statistics and bfloat16 pieces a statistic
+    as ``tree.stats_plan`` gives them to the complete levels too.
+    ``n_stats * pieces`` is the operand's rows a node. The one place
+    that decides it: ``grow_frontier`` follows it and the forest fit
+    reports it on ``drf.chunk``."""
+    from h2o3_tpu.models.tree import stats_plan
+    n_stats, pieces = stats_plan(params, unit_h)
     if params.pallas not in ("native", "interpret"):
-        return 0, pieces
+        return 0, n_stats, pieces
     from h2o3_tpu.ops.pallas import frontier_tile_rows
     B = params.nbins_total
     lb = _geometry(n_rows, frontier_capacity(n_rows, params.max_depth))[1]
     n_words = -(-n_features // (32 // _bin_bits(B)))
     return frontier_tile_rows(n_features, B, piece_rows(lb, n_stats, pieces),
-                              1 + n_words + n_stats), pieces
+                              1 + n_words + n_stats), n_stats, pieces
 
 
 def _bin_bits(n_bins: int) -> int:
@@ -306,8 +309,7 @@ def grow_frontier(bins, nb, nid, stats, alive, key, col_mask, *, params,
     SBN = LB * SB
     nlev = D - K
     period = min(params.frontier_sort_every, nlev)
-    ns = len(stats)
-    tile, pieces = hist_plan(params, N, F, ns)
+    tile, ns, pieces = hist_plan(params, N, F, unit_h=len(stats) == 2)
     W = max(1, (B - 1 + 31) // 32) if params.has_cats else 1
     lam = sc.reg_lambda
 

@@ -46,7 +46,8 @@ from h2o3_tpu.models.tree import (Tree, TreeParams, TreeScalars,
                                   grow_tree, kernel_levels,
                                   predict_forest, predict_tree,
                                   psum_bytes, select_levels, stack_trees,
-                                  trees_per_chunk, unstack_model_trees)
+                                  stats_plan, trees_per_chunk,
+                                  unstack_model_trees)
 from h2o3_tpu.ops import pallas as pallas_ops
 from h2o3_tpu.parallel.mesh import (data_size, get_mesh, put_sharded,
                                     row_sharding)
@@ -282,10 +283,14 @@ def _neutral_tp(tp: TreeParams) -> TreeParams:
 def _level_paths(tp: TreeParams, n_features: int) -> dict:
     """What a ``gbm.chunk`` span says of the level passes: how many of a
     tree's levels (at its compile depth) ran through the Pallas kernels
-    and how many through the XLA sequence (models/tree.kernel_levels)."""
+    and how many through the XLA sequence (models/tree.kernel_levels),
+    and the kernels' histogram operand rows a node
+    (models/tree.stats_plan: a boosted tree carries its hessian)."""
     fused = kernel_levels(tp, n_features)
+    n_stats, n_pieces = stats_plan(tp, unit_h=False)
     return {"levels_kernel": sum(fused),
-            "levels_xla": len(fused) - sum(fused)}
+            "levels_xla": len(fused) - sum(fused),
+            "hist_operand_rows": n_stats * n_pieces}
 
 
 def _mesh_split(tp: TreeParams, n_rows: int, n_features: int,

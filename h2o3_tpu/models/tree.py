@@ -171,13 +171,29 @@ class TreeParams:
                                      # fits hand on frontier.SORT_PERIOD
     whole_stats: bool = False        # the fit KNOWS every statistic of a
                                      # row is 0 or ±1 (whole weights on a
-                                     # class indicator): the frontier's
-                                     # histogram operand then carries one
-                                     # bfloat16 piece a statistic
+                                     # class indicator): the histogram
+                                     # operands of the kernel and frontier
+                                     # levels then carry one bfloat16
+                                     # piece a statistic (stats_plan)
 
     @property
     def has_cats(self) -> bool:
         return any(self.cat_feats)
+
+
+def stats_plan(params: TreeParams, unit_h: bool) -> tuple:
+    """(n_stats, n_pieces) of a tree's histogram operand
+    (ops/histogram.stat_rows): the statistics a row carries — 2, (w,
+    w·g), where the hessian is 1 (``unit_h``: grow_tree's ``h`` None)
+    and the third column is a copy of the first, else 3 — and the
+    bfloat16 pieces each enters the product in — 1 where
+    ``params.whole_stats`` (every statistic 0 or ±1: the pieces left
+    out are identically zero), else 3. ``n_stats * n_pieces`` is the
+    operand's rows a node. The one place that decides it, for the
+    complete levels' kernel passes and the frontier's alike; the fits
+    report it on their ``*.chunk`` spans (``hist_operand_rows``). The
+    XLA level sequence (ops/histogram.histogram) keeps 3 x 3."""
+    return (2 if unit_h else 3), (1 if params.whole_stats else 3)
 
 
 def kernel_levels(params: TreeParams, n_features: int) -> tuple:
@@ -185,9 +201,12 @@ def kernel_levels(params: TreeParams, n_features: int) -> tuple:
     through the Pallas level kernels (ops/pallas/treekernel.py) — the
     fit's STATIC ``params.pallas`` knob, and per LEVEL whether the
     level's shapes fit a VMEM tile (ops/pallas.tile_rows — deep levels'
-    accumulators do not; they take the XLA sequence). The one place
-    that decides it: grow_tree follows it and the fits report it on
-    their ``*.chunk`` spans (``levels_kernel`` / ``levels_xla``)."""
+    accumulators do not; they take the XLA sequence). A level is
+    judged at the tallest operand, 3 x 3 rows a node, so that a shorter
+    one (stats_plan) only widens its tile and never moves a level
+    between the paths, nor ``frontier_start``. The one place that
+    decides it: grow_tree follows it and the fits report it on their
+    ``*.chunk`` spans (``levels_kernel`` / ``levels_xla``)."""
     if params.pallas not in ("native", "interpret"):
         return (False,) * params.max_depth
     from h2o3_tpu.ops.pallas import tile_rows
@@ -400,7 +419,8 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
     # way every sum of {w, w·g, w·h} is a float32 sum (ops/histogram.py
     # split3). The stats block is level-invariant, so it is built once
     # here (the XLA path rebuilds the same values inside
-    # ops/histogram.py).
+    # ops/histogram.py): (w, w·g) alone under a hessian of 1, each in
+    # one bfloat16 piece where they are whole (stats_plan).
     fused = kernel_levels(params, F)
     # asked for, which is more than any(fused): a level that then fits
     # no tile is a counted fallback, most of all when none fits
@@ -408,7 +428,9 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
     if use_kernels:
         from h2o3_tpu.ops import pallas as pallas_policy
         from h2o3_tpu.ops.pallas.treekernel import fused_level
-        stats3 = jnp.stack([w, w * g, w * h]).astype(jnp.float32)  # [3, N]
+        n_stats, n_pieces = stats_plan(params, unit_h)
+        stats = jnp.stack([w, w * g, w * h][:n_stats]) \
+            .astype(jnp.float32)                                  # [S, N]
     prev_hist = None
     # a node whose parent did not split is a leaf, not a second chance
     # with a fresh column draw: only mtries can tell the difference (a
@@ -429,10 +451,11 @@ def grow_tree(bins, nb, w, g, h, col_mask, *, params: TreeParams, mesh,
         if use_fused:
             (hist, bg, bf, bt, bnal, blv, brv, leftmask, split,
              nid_next) = fused_level(
-                bins, nid, stats3, prev_hist, cm, nb, is_cat,
+                bins, nid, stats, prev_hist, cm, nb, is_cat,
                 constraints, lo, hi, sc, d=d, n_nodes=L, n_bins=B,
                 block_rows=params.block_rows, mesh=mesh,
-                interpret=(params.pallas == "interpret"))
+                interpret=(params.pallas == "interpret"),
+                n_pieces=n_pieces)
         else:
             with jax.named_scope("tree.hist"):
                 if prev_hist is None:

@@ -139,6 +139,7 @@ def _tile_of(rows: int) -> int:
 
 
 def tile_rows(n_features: int, n_bins: int, n_nodes: int,
+              node_rows: int = 9,
               budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
     """Rows per tile at which BOTH level kernels (histogram, partition)
     fit ``budget_bytes`` of scoped VMEM, as a power of two from 128 to
@@ -152,17 +153,20 @@ def tile_rows(n_features: int, n_bins: int, n_nodes: int,
     (libtpu 0.0.34) over F in 4..60, B in 17..256, levels 0..8: the
     largest tile it accepted was twice this or more at every shape.
     The histogram kernel keeps one float32 accumulator of
-    ``piece_rows`` x F·B (three bfloat16 pieces a statistic,
-    ops/histogram.stat_rows: three times the rows of the sums it
-    yields) and pays per tile row the bfloat16 [F·B] one-hot row and
-    its column of the [piece_rows, C] statistics operand; the
-    partition kernel keeps rows on the lanes and costs the sublanes of
-    its [L, C], [B-1, C] and [F, C] blocks.
+    ``piece_rows`` x F·B — ``node_rows`` rows a node of the level's
+    left children, statistics times bfloat16 pieces
+    (ops/histogram.stat_rows: 3 x 3 where nothing is known of the
+    statistics, 2 x 1 for a forest's whole ones) — and pays per tile
+    row the bfloat16 [F·B] one-hot row and its column of the
+    [piece_rows, C] statistics operand; the partition kernel keeps rows
+    on the lanes and costs the sublanes of its [L, C], [B-1, C] and
+    [F, C] blocks.
     """
     if n_bins > MAX_KERNEL_BINS:
         return 0
     fb = 4 * _up(n_features * n_bins, 128)           # one f32 [., F·B] row
-    pieces = _up(9 * max(n_nodes // 2, 1), 16)  # ops/histogram.piece_rows
+    # ops/histogram.piece_rows
+    pieces = _up(node_rows * max(n_nodes // 2, 1), 16)
     hist_row = (5 * (fb // 2 + 6 * pieces + 1024)) // 4
     hist_fixed = (9 * pieces * fb) // 8
     part_row = 4 * (2 * _up(n_nodes, 8) + 2 * _up(n_bins - 1, 8)

@@ -11,9 +11,12 @@ tree-boosting systems do (Booster arxiv 2011.02022; XGBoost-GPU arxiv
 
 - a per-shard histogram kernel streams the tiles through VMEM and
   accumulates the histogram in a VMEM scratch on the MXU — both
-  operands bfloat16, the float32 statistics as three pieces split on
-  the tile in VMEM (ops/histogram.stat_rows), so every sum is a
-  float32 sum; the three [3L, F·B] slabs are added outside;
+  operands bfloat16, the float32 statistics as bfloat16 pieces split
+  on the tile in VMEM (ops/histogram.stat_rows), so every sum is a
+  float32 sum; the pieces' slabs are added outside. How many
+  statistics (3, or 2 under a hessian of 1) and how many pieces each
+  (3, or 1 where they are 0 or ±1) is the fit's plan
+  (models/tree.stats_plan);
 - the cross-shard ``psum`` (the MRTask reduce tree,
   water/MRTask.java:891 — a no-op on one shard) and the level boundary
   in plain XLA: sibling subtraction against the parent level and the
@@ -83,14 +86,17 @@ def _pad_lanes(arr, n_pad: int):
 # [C, F·B] right operand.
 
 
-def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int):
-    """One tile's [piece_rows(Lh), F·B] partial products — VMEM one-hots
-    feeding the MXU. ``bins`` [C, F], ``nid`` [1, C], ``stats`` [3, C]:
-    the forms of ops/histogram._block_hist, whose values (not just
-    sums) this matches — the one-hot indicators are exact 0/1, the
-    stats operand is the same ``stat_rows`` and the contraction the
-    same ``left @ right``, so the f32 accumulation sees identical
-    operands in an identical order. At levels d >= 1 only LEFT-child
+def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int,
+                n_stats: int = 3, n_pieces: int = 3):
+    """One tile's [piece_rows(Lh, S, P), F·B] partial products — VMEM
+    one-hots feeding the MXU. ``bins`` [C, F], ``nid`` [1, C],
+    ``stats`` [S, C]: the forms of ops/histogram._block_hist, whose
+    values (not just sums) this matches — the one-hot indicators are
+    exact 0/1, the stats operand is the same ``stat_rows`` and the
+    contraction the same ``left @ right``, so the f32 accumulation sees
+    identical operands in an identical order (the operand's rows a
+    node, S statistics x P pieces, are the caller's plan:
+    models/tree.stats_plan). At levels d >= 1 only LEFT-child
     rows accumulate, into their PARENT's slot (the sibling-subtraction
     trick of grow_tree, kept inside the kernel).
 
@@ -116,7 +122,7 @@ def _hist_block(bins, nid, stats, *, n_nodes_h: int, n_bins: int, d: int):
     lane_b = jax.lax.broadcasted_iota(jnp.int32, (1, FB), 1) % n_bins
     right = (row_bin == lane_b.astype(jnp.float32)).astype(jnp.bfloat16)
     return jax.lax.dot_general(
-        stat_rows(nid, stats, n_nodes_h), right,
+        stat_rows(nid, stats, n_nodes_h, n_stats, n_pieces), right,
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
@@ -126,11 +132,17 @@ def _level_boundary(lh, prev_hist, cm, nb, is_cat, constraints, lo, hi,
     """Histogram → split decisions, between the two row passes.
 
     Line-for-line the XLA level head of grow_tree: reshape the matmul
-    accumulator to [Lh, F, B, 3], sibling-subtract against the parent
-    level (with the f32 cancellation clamps), then the SHARED split scan
-    (ops/split_scan.best_splits) and the split/categorical flags."""
+    accumulator ([S·Lh, F·B], S 2 or 3) to [Lh, F, B, 3] — two
+    statistics are (w, w·g) under a hessian of 1, whose third column
+    w·1 is a copy of the first, bit for bit —, sibling-subtract against
+    the parent level (with the f32 cancellation clamps), then the SHARED
+    split scan (ops/split_scan.best_splits) and the split/categorical
+    flags."""
     Lh = max(n_nodes // 2, 1)
-    lh4 = lh.reshape(Lh, 3, n_features, n_bins).transpose(0, 2, 3, 1)
+    S = lh.shape[0] // Lh
+    lh4 = lh.reshape(Lh, S, n_features, n_bins).transpose(0, 2, 3, 1)
+    if S == 2:
+        lh4 = jnp.concatenate([lh4, lh4[..., :1]], axis=-1)
     if d == 0:
         hist = lh4
     else:
@@ -205,7 +217,8 @@ def _partition_block(bins, nid, bf, bt, bnal, isp, cs, leftmask, *,
 
 
 def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
-                 d: int, n_nodes_h: int, n_bins: int):
+                 d: int, n_nodes_h: int, n_bins: int, n_stats: int,
+                 n_pieces: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -213,7 +226,8 @@ def _hist_kernel(bins_ref, nid_ref, stats_ref, out_ref, acc_ref, *,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     acc_ref[:] += _hist_block(bins_ref[:], nid_ref[:], stats_ref[:],
-                              n_nodes_h=n_nodes_h, n_bins=n_bins, d=d)
+                              n_nodes_h=n_nodes_h, n_bins=n_bins, d=d,
+                              n_stats=n_stats, n_pieces=n_pieces)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -225,26 +239,29 @@ def _lane_tile(k: int, C: int):
 
 
 def _hist_call(bins, nid, stats, *, d, n_nodes, n_bins, block_rows,
-               interpret):
+               interpret, n_pieces=3):
     """Per-shard histogram kernel over ``bins`` [N, F], ``nid`` [1, N],
-    ``stats`` [3, N] → float32 sums [3Lh, F·B] (caller psums)."""
+    ``stats`` [S, N], each statistic in ``n_pieces`` bfloat16 pieces →
+    float32 sums [S·Lh, F·B] (caller psums)."""
     N, F = bins.shape
+    S = stats.shape[0]
     C, nblk, n_pad = _tile_geometry(N, block_rows)
     Lh = max(n_nodes // 2, 1)
-    acc = (piece_rows(Lh), F * n_bins)
+    acc = (piece_rows(Lh, S, n_pieces), F * n_bins)
     pallas_policy.record_launch("tree_hist")
     pieces = pl.pallas_call(
-        functools.partial(_hist_kernel, d=d, n_nodes_h=Lh, n_bins=n_bins),
+        functools.partial(_hist_kernel, d=d, n_nodes_h=Lh, n_bins=n_bins,
+                          n_stats=S, n_pieces=n_pieces),
         grid=(nblk,),
         in_specs=[pl.BlockSpec((C, F), lambda i: (i, 0)),
-                  _lane_tile(1, C), _lane_tile(3, C)],
+                  _lane_tile(1, C), _lane_tile(S, C)],
         out_specs=pl.BlockSpec(acc, lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(acc, jnp.float32),
         scratch_shapes=[pltpu.VMEM(acc, jnp.float32)],
         interpret=interpret, name="tree_hist",
     )(jnp.pad(bins, ((0, n_pad - N), (0, 0))), _pad_lanes(nid, n_pad),
       _pad_lanes(stats, n_pad))
-    return sum_pieces(pieces, Lh)
+    return sum_pieces(pieces, Lh, S, n_pieces)
 
 
 def _partition_kernel(bins_ref, nid_ref, bf_ref, bt_ref, bnal_ref,
@@ -410,14 +427,18 @@ def frontier_hist(sched, lo, hi, s, fid, words, stats, *, lb: int, sb: int,
 
 def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
                 constraints, lo, hi, scalars, *, d: int, n_nodes: int,
-                n_bins: int, block_rows: int, mesh, interpret: bool):
+                n_bins: int, block_rows: int, mesh, interpret: bool,
+                n_pieces: int = 3):
     """One tree level through the kernels: returns (hist [L,F,B,3],
     gain, feat, thresh, na_left, left_val, right_val, leftmask, split,
     new_nid).
 
     Drop-in for grow_tree's per-level XLA sequence (histogram →
     _best_splits → _level_goleft), with identical semantics: ``stats``
-    is the level-invariant [3, N] {w, w·g, w·h} block, ``prev_hist`` the
+    is the level-invariant [3, N] {w, w·g, w·h} block — or [2, N]
+    {w, w·g} where the hessian is 1 —, each statistic entering the
+    product in ``n_pieces`` bfloat16 pieces (1 where every statistic is
+    0 or ±1; models/tree.stats_plan decides both), ``prev_hist`` the
     previous level's histogram (None at the root — sibling subtraction
     starts at level 1), and the returned ``split`` already folds in the
     min-split-improvement and traced depth-limit masks. Rows must be
@@ -443,7 +464,8 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
     hi2 = jnp.asarray(hi, jnp.float32)[None, :]
     F = bins.shape[1]
     if not interpret:
-        tile = pallas_policy.tile_rows(F, n_bins, n_nodes)
+        tile = pallas_policy.tile_rows(F, n_bins, n_nodes,
+                                       stats.shape[0] * n_pieces)
         assert tile > 0, (F, n_bins, n_nodes)
         block_rows = min(block_rows, tile)
 
@@ -469,7 +491,7 @@ def fused_level(bins, nid, stats, prev_hist, col_mask, nb, is_cat,
         with jax.named_scope("tree.hist"):
             lh = _hist_call(bins_l, nid_l, stats_l, d=d, n_nodes=n_nodes,
                             n_bins=n_bins, block_rows=block_rows,
-                            interpret=interpret)
+                            interpret=interpret, n_pieces=n_pieces)
         with jax.named_scope("tree.psum"):
             lh = jax.lax.psum(lh, DATA_AXIS)
         with jax.named_scope("tree.split_scan"):

@@ -100,12 +100,14 @@ def _compiled_text(lowered):
 # ------------------------------------------------------- the level pass
 
 
-def _lower_level_pass(mesh, d):
+def _lower_level_pass(mesh, d, rows=AIR_ROWS, n_stats=3, n_pieces=3):
     """One tree level as grow_tree runs it when ``auto`` resolves on a
-    TPU: the kernels where the level fits a tile, else XLA."""
+    TPU: the kernels where the level fits a tile, else XLA — with a
+    statistics operand of ``n_stats`` x ``n_pieces`` rows a node
+    (models/tree.stats_plan)."""
     from h2o3_tpu.models.tree import TreeScalars
     from h2o3_tpu.ops.pallas import treekernel as tk
-    n = mesh_mod.padded_rows(AIR_ROWS, mesh)
+    n = mesh_mod.padded_rows(rows, mesh)
     L = 2 ** d
     row = NamedSharding(mesh, P(mesh_mod.DATA_AXIS))
     rep = NamedSharding(mesh, P())
@@ -119,14 +121,15 @@ def _lower_level_pass(mesh, d):
     def level(bins, nid, stats, prev, cm, nb, lo, hi):
         if kernels:
             return tk.fused_level(bins, nid, stats, prev, cm, nb, is_cat,
-                                  None, lo, hi, sc, interpret=False, **kw)
+                                  None, lo, hi, sc, interpret=False,
+                                  n_pieces=n_pieces, **kw)
         return tk.xla_level(bins, nid, stats[0], stats[1], stats[2], prev,
                             cm, nb, is_cat, None, lo, hi, sc, **kw)
 
     prev = (S((L // 2, F, B, 3), jnp.float32, sharding=rep) if d else None)
     lowered = jax.jit(level).lower(
         S((n, F), jnp.int8, sharding=row), S((n,), jnp.int32, sharding=row),
-        S((3, n), jnp.float32,
+        S((n_stats, n), jnp.float32,
           sharding=NamedSharding(mesh, P(None, mesh_mod.DATA_AXIS))),
         prev, S((F,), jnp.bool_, sharding=rep),
         S((F,), jnp.int32, sharding=rep), S((L,), jnp.float32, sharding=rep),
@@ -147,6 +150,18 @@ def test_level_pass_four_chips(topo, d):
     txt = _compiled_text(lowered)
     assert "all-reduce" in txt, "the histogram is not reduced over 'data'"
     assert ("tpu_custom_call" in txt) == kernels
+
+
+@pytest.mark.parametrize("d", range(9))
+def test_forest_level_pass_on_its_operand_plan(topo, d):
+    """Levels 0-8 of drf-airlines-d20.fit-48m as the kernels run them
+    there: 48M rows, (w, w·g) in one bfloat16 piece each — 2 operand
+    rows a node — and the tile that shorter operand gets."""
+    assert plx.tile_rows(F, B, 2 ** d, 2) >= plx.tile_rows(F, B, 2 ** d)
+    lowered, kernels = _lower_level_pass(_mesh(topo, 1), d, AIR48_ROWS,
+                                         n_stats=2, n_pieces=1)
+    assert kernels
+    assert "tpu_custom_call" in _compiled_text(lowered)
 
 
 @pytest.mark.parametrize("chips", [1, 4])

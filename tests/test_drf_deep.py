@@ -10,6 +10,7 @@ only: no test here grows a tree on more than a few thousand rows."""
 
 import dataclasses
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -246,6 +247,45 @@ def test_the_histogram_operand_is_as_short_as_the_statistics(
         assert _same(model.grown, forced.grown)
         assert model.training_metrics["logloss"] == \
             forced.training_metrics["logloss"]
+
+
+@pytest.mark.parametrize("pallas", ["off", "interpret"])
+@pytest.mark.parametrize("whole", [False, True])
+@pytest.mark.parametrize("unit_h", [False, True])
+def test_one_plan_sizes_both_regimes_operands(unit_h, whole, pallas):
+    """``tree.stats_plan`` is the one decision: the frontier's plan is
+    its (statistics, pieces), whichever histogram pass runs there."""
+    tp = tree_mod.TreeParams(max_depth=20, nbins_total=126,
+                             cat_feats=(False,) * 10, whole_stats=whole,
+                             pallas=pallas)
+    plan = tree_mod.stats_plan(tp, unit_h)
+    assert plan == (2 if unit_h else 3, 1 if whole else 3)
+    assert frontier.hist_plan(tp, 48_000_000, 10, unit_h)[1:] == plan
+
+
+def test_the_complete_levels_and_the_frontier_ask_the_same_plan(
+        mixed_frame, monkeypatch):
+    """A forest grown through the level kernels (interpret mode) above
+    the frontier and the frontier's kernel below it: both regimes take
+    their operand from ``stats_plan``, with the forest's statistics."""
+    asked = []
+    real = tree_mod.stats_plan
+
+    def spy(params, unit_h):
+        asked.append((sys._getframe(1).f_code.co_name, unit_h,
+                      params.whole_stats))
+        return real(params, unit_h)
+    monkeypatch.setattr(tree_mod, "stats_plan", spy)
+    monkeypatch.setattr(tree_mod, "FRONTIER_FROM", 3)
+    monkeypatch.setenv("H2O3TPU_PALLAS", "interpret")
+    jax.clear_caches()                  # grow_tree is traced anew
+    DRFEstimator(ntrees=1, seed=1, max_depth=6).train(mixed_frame, y="y")
+    chunk = [s for s in telemetry.spans_snapshot(last=1 << 16)
+             if s["name"] == "drf.chunk"][-1]["meta"]
+    assert chunk["levels_kernel"] == 3 and chunk["levels_frontier"] == 3
+    assert chunk["hist_operand_rows"] == 2
+    assert {c for c, *_ in asked} == {"grow_tree", "hist_plan"}
+    assert {(u, w) for _, u, w in asked} == {(True, True)}
 
 
 def test_chunked_is_single_scan(default_forest, mixed_frame):

@@ -75,6 +75,7 @@ def test_gbm_multinomial_opens_the_fit_phases():
             "gbm.metrics"} <= set(spans)
     assert spans["gbm.chunk"]["trees"] == 3
     assert {"levels_kernel", "levels_xla"} <= set(spans["gbm.chunk"])
+    assert spans["gbm.chunk"]["hist_operand_rows"] == 9
     assert {"levels_select", "levels_gather"} <= set(spans["gbm.rescore"])
 
 

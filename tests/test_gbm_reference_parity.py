@@ -359,6 +359,9 @@ def test_chunk_span_names_the_level_paths(fits, path, kernel, xla):
     assert chunks and sum(c["trees"] for c in chunks) == NTREES
     for meta in chunks:
         assert (meta["levels_kernel"], meta["levels_xla"]) == (kernel, xla)
+        # a binomial GBM's float gradient and hessian: 3 statistics x 3
+        # bfloat16 pieces a node, the plan models/tree.stats_plan gives
+        assert meta["hist_operand_rows"] == 9
 
 
 # ---- (e) bin edges from every row, as the reference cuts them ---------------

@@ -193,6 +193,87 @@ def test_parity_single_device_fully_fused():
         _scalars(), depth=2, L=4, B=B, mesh=mesh1, block_rows=128)
 
 
+# ---------------------------------- a shorter statistics operand, same level
+
+
+def _assert_plan_parity(bins, w, g, nb, is_cat, cms, *, n_stats, n_pieces,
+                        B, block_rows=256):
+    """Levels 0..len(cms)-1 through the kernels twice — with the 3 x 3
+    operand of {w, w·g, w·1} and with the ``n_stats`` x ``n_pieces`` one
+    of models/tree.stats_plan — each routing with its own node ids:
+    every output of every level is bit-identical. ``cms[d]`` is level
+    d's per-node column mask [2^d, F]."""
+    mesh = get_mesh()
+    lo = jnp.full((1,), -jnp.inf, jnp.float32)
+    hi = jnp.full((1,), jnp.inf, jnp.float32)
+    sc = _scalars(min_rows=1.0)
+
+    def sweep(stats, pieces):
+        outs, prev = [], None
+        nid = jnp.zeros((bins.shape[0],), jnp.int32)
+        for d, cm in enumerate(cms):
+            out = tk.fused_level(
+                bins, nid, stats, prev, cm, nb, is_cat, None, lo, hi, sc,
+                d=d, n_nodes=2 ** d, n_bins=B, block_rows=block_rows,
+                mesh=mesh, interpret=True, n_pieces=pieces)
+            outs.append(out)
+            prev, nid = out[0], out[-1]
+        return outs
+
+    @jax.jit
+    def both(w, g):
+        stats = jnp.stack([w, w * g, w]).astype(jnp.float32)
+        return sweep(stats, 3), sweep(stats[:n_stats], n_pieces)
+
+    nine, short = both(w, g)
+    for d, (out_9, out_s) in enumerate(zip(nine, short)):
+        for name, a, b in zip(OUT_NAMES, out_9, out_s):
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b),
+                err_msg=f"level {d} output '{name}' diverged")
+    # the levels split: the parity is not one of empty trees
+    assert np.asarray(nine[-1][8]).any()
+
+
+@pytest.mark.parametrize("n_stats,n_pieces", [(2, 1), (2, 3)])
+def test_a_shorter_operand_grows_the_same_levels(n_stats, n_pieces):
+    """A forest's operand: two statistics under a hessian of 1, in one
+    bfloat16 piece where they are 0 or ±1 (a class indicator under
+    whole weights: 2 rows a node) and in three where w·g is any float
+    (a regression forest: 6) — against the 9-row operand, over four
+    levels with categorical features and a per-node column mask."""
+    bins, w, g, h, nb, r = _level_inputs(n=900, seed=13, B=9)
+    F = bins.shape[1]
+    if n_pieces == 1:
+        g = jnp.asarray(r.randint(-1, 2, g.shape[0]).astype(np.float32))
+    is_cat = jnp.asarray(np.array([True, False, True, False]))
+    cms = [jnp.asarray(r.rand(2 ** d, F) > 0.3)
+           | (jnp.arange(F)[None, :] == d % F) for d in range(4)]
+    _assert_plan_parity(bins, w, g, nb, is_cat, cms, n_stats=n_stats,
+                        n_pieces=n_pieces, B=9)
+
+
+AIR_F, AIR_B = 10, 126                # the airlines frame, binned
+
+
+def test_the_nine_row_operand_keeps_its_tiles():
+    """The GBM cells' levels (3 statistics x 3 pieces) get the tiles
+    they had before the operand's height became an argument."""
+    today = [2048] * 6 + [1024, 1024, 256, 0]
+    assert [plx.tile_rows(AIR_F, AIR_B, 2 ** d) for d in range(10)] == today
+    assert [plx.tile_rows(AIR_F, AIR_B, 2 ** d, 9)
+            for d in range(10)] == today
+
+
+@pytest.mark.parametrize("node_rows", [2, 6])
+def test_a_shorter_operand_gets_a_tile_as_large(node_rows):
+    for d in range(9):
+        nine = plx.tile_rows(AIR_F, AIR_B, 2 ** d)
+        assert plx.tile_rows(AIR_F, AIR_B, 2 ** d, node_rows) >= nine > 0
+    # the forest's level 8: 256 operand rows in place of 1,152
+    assert plx.tile_rows(AIR_F, AIR_B, 256, 2) == 1024
+
+
 # --------------------------------------- binning edge-case sweep parity
 
 
@@ -302,6 +383,12 @@ def test_seeded_drf_forest_parity_interpret(monkeypatch):
     monkeypatch.setenv("H2O3TPU_PALLAS", "interpret")
     m_p = DRFEstimator(**params).train(fr, y="y")
     _forests_equal(m_x, m_p)
+    # the kernels took every level (of the compiled depth bucket) on the
+    # 2-row operand: two whole statistics, one bfloat16 piece each
+    chunk = [s for s in telemetry.spans_snapshot(last=1 << 16)
+             if s["name"] == "drf.chunk"][-1]["meta"]
+    assert chunk["levels_kernel"] >= 4 and chunk["levels_xla"] == 0
+    assert chunk["hist_operand_rows"] == 2
 
 
 # --------------------------------------------- knob + import guard
