@@ -28,7 +28,7 @@ CREATED, RUNNING, DONE, FAILED, CANCELLED = (
     "CREATED", "RUNNING", "DONE", "FAILED", "CANCELLED")
 
 # classification + retry policy live in core/watchdog.py (shared with
-# bench.py and the probe); kept as an alias for existing importers
+# the backend probe); kept as an alias for existing importers
 _INFRA_SIGNS = watchdog.INFRA_SIGNS
 
 

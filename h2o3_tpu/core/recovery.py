@@ -30,8 +30,7 @@ chain, early-stop history and scoring history — so a resumed fit is
 **bit-identical** to an uninterrupted one (the DrJAX-style replayable
 state-capture discipline, arxiv 2403.07128; Orbax-style async
 snapshotting per SNIPPETS.md costs <1% of step time — ours is bounded
-by the `fit_checkpoint_seconds` histogram and the bench.py
-``checkpoint`` leg).
+by the `fit_checkpoint_seconds` histogram).
 
 A corrupt/truncated snapshot is *quarantined* (renamed ``*.corrupt``,
 ``snapshot_load_failures_total`` incremented) and the fit restarts

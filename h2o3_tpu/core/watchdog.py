@@ -11,7 +11,7 @@ scoreboard to exactly that — the first ``device_put`` hit a corpse and
 every ad-hoc retry hit it again.
 
 This module centralizes what used to be scattered one-shot retries
-(core/job.py, bench.py):
+(core/job.py):
 
 - ``probe_backend()``    — cheap liveness check: ``jax.devices()`` plus a
   tiny ``device_put`` round-trip, optionally bounded by a thread-timeout

@@ -57,7 +57,6 @@ from h2o3_tpu.telemetry import slo
 from h2o3_tpu.telemetry import cluster
 from h2o3_tpu.telemetry import roofline
 from h2o3_tpu.telemetry import stepprof
-from h2o3_tpu.telemetry import perfbase
 
 snapshot = REGISTRY.snapshot
 to_prometheus = REGISTRY.to_prometheus
@@ -75,5 +74,4 @@ __all__ = [
     "install", "observed_jit", "snapshot", "to_prometheus",
     "compiles_snapshot", "programs_snapshot", "flight_recorder", "trace_export",
     "trace_context", "slo", "cluster", "roofline", "stepprof",
-    "perfbase",
 ]

@@ -27,8 +27,7 @@ pipeline (arxiv 1810.09868) reports utilization per compiled program:
 - :func:`record_model_fit` (hooked into the ``<algo>.fit`` span,
   models/model.py) emits ``model_fit_mfu{algo}`` and
   ``model_fit_hbm_util{algo}`` gauges, annotates the fit span (so the
-  numbers land in flight-recorder capsules), and returns the record
-  bench.py re-emits per config.
+  numbers land in flight-recorder capsules), and returns the record.
 
 Mode knob ``H2O3TPU_ROOFLINE`` / ``Config.roofline``: ``auto``
 (default) attaches cost_analysis diagnostics on TPU backends — where
@@ -164,10 +163,10 @@ def analytic_tree_cost(rows: int, features: int, trees: int, depth: int,
                        bins: int) -> Dict:
     """Histogram-build matmuls — the tree FLOPs that touch the MXU: per
     row per tree, levels 0..depth-1 contract [3·2^l, C] x [C, F·B]
-    (ops/histogram.py _block_hist; same count bench.py's historical
-    mfu_pct used). A float32 product counted once: that each statistic
-    enters as three bfloat16 pieces, so the MXU does three times this,
-    is how the sums stay float32, not more work done.
+    (ops/histogram.py _block_hist). A float32 product counted once:
+    that each statistic enters as three bfloat16 pieces, so the MXU
+    does three times this, is how the sums stay float32, not more work
+    done.
     Bytes: each level re-streams the int8 binned matrix,
     the 3-stat payload, and the node-id vector."""
     flops = 2.0 * 3.0 * (2 ** depth - 1) * features * bins * rows * trees
@@ -397,10 +396,3 @@ def record_model_fit(builder, model, frame, x, seconds: float,
     except Exception:   # noqa: BLE001 - accounting must never fail a fit
         return None
 
-
-def last_fit(algo: str) -> Dict:
-    """Most recent fit's utilization gauges (bench.py per-config
-    fields): {"mfu": fraction, "hbm_util": fraction}."""
-    return {"mfu": float(REGISTRY.value("model_fit_mfu", algo=algo)),
-            "hbm_util": float(REGISTRY.value("model_fit_hbm_util",
-                                             algo=algo))}

@@ -40,7 +40,7 @@ merged across ONE shared bucket grid), REST availability
 heartbeat health (``heartbeat_misses_total`` vs
 ``heartbeat_rounds_total``), and a fit-MFU floor
 (``model_fit_mfu{algo}``, off by default). Everything here is
-deliberately jax-free: bench.py's ``_stub_slo`` leg drives the full
+deliberately jax-free: tests/test_tracing_slo.py drives the full
 state machine with a private registry and a fake clock.
 """
 
@@ -236,31 +236,6 @@ def _mfu_check(reg: MetricsRegistry) -> Tuple[bool, Dict]:
                                   "worst_algo": worst}
 
 
-def _step_regression_bound() -> float:
-    try:
-        return float(os.environ.get("H2O3TPU_SLO_STEP_REGRESSION",
-                                    "1.25"))
-    except ValueError:
-        return 1.25
-
-
-def _step_regression_check(reg: MetricsRegistry) -> Tuple[bool, Dict]:
-    """Every ``fit_step_baseline_ratio{algo}`` gauge (current mean step
-    time / stored best, telemetry/perfbase.py) stays under the bound —
-    a ratio at 1.25 means this fit's step-time distribution degraded
-    ≥25% against its persisted baseline."""
-    bound = _step_regression_bound()
-    vals = {str(g.labels.get("algo", "?")): g.value
-            for g in reg.find("fit_step_baseline_ratio")}
-    if bound <= 0.0 or not vals:
-        return True, {"bound": bound,
-                      "max_ratio": max(vals.values()) if vals else None}
-    worst = max(vals, key=vals.get)
-    return vals[worst] < bound, {"bound": bound,
-                                 "max_ratio": vals[worst],
-                                 "worst_algo": worst}
-
-
 def default_rules() -> List[object]:
     return [
         RatioRule(
@@ -301,13 +276,6 @@ def default_rules() -> List[object]:
             description="every durability-registered frame keeps at "
                         "least one live replica "
                         "(frames_under_replicated stays 0)"),
-        GaugeRule(
-            "fit_step_regression", check_fn=_step_regression_check,
-            description="no fit's step time degrades past "
-                        "H2O3TPU_SLO_STEP_REGRESSION (default 1.25 = "
-                        "+25%) vs its stored perf baseline "
-                        "(fit_step_baseline_ratio, telemetry/"
-                        "perfbase.py)"),
     ]
 
 

@@ -202,9 +202,8 @@ def reset() -> None:
         _barriers.clear()
     try:
         from h2o3_tpu.telemetry.registry import REGISTRY
-        for name in ("fit_step_baseline_ratio", "pod_step_skew_ratio",
-                     "pod_straggler_host", "stepprof_fits_total",
-                     "model_fit_phase_seconds"):
+        for name in ("pod_step_skew_ratio", "pod_straggler_host",
+                     "stepprof_fits_total", "model_fit_phase_seconds"):
             REGISTRY.drop(name)
     except Exception:   # noqa: BLE001 - reset is best-effort
         pass
@@ -230,9 +229,9 @@ def finish(prof: Optional[FitProfile], model_key: Optional[str] = None,
            seconds: Optional[float] = None,
            mfu: Optional[float] = None) -> Optional[Dict]:
     """Close the profile: flush the trailing host gap, register the
-    completed record for REST/cluster readers, attach it to any active
-    flight-recorder capsule, and feed the perf-regression baseline.
-    Never raises — profiling must never fail a fit."""
+    completed record for REST/cluster readers, and attach it to any
+    active flight-recorder capsule. Never raises — profiling must never
+    fail a fit."""
     if prof is None:
         return None
     try:
@@ -269,11 +268,6 @@ def finish(prof: Optional[FitProfile], model_key: Optional[str] = None,
             flight_recorder.record_step_profile(
                 {k: v for k, v in d.items() if k != "ring"})
         except Exception:   # noqa: BLE001 - capsule capture best-effort
-            pass
-        try:
-            from h2o3_tpu.telemetry import perfbase
-            perfbase.record_fit(prof.algo, prof.nrows, d, mfu=mfu)
-        except Exception:   # noqa: BLE001 - guard must never fail a fit
             pass
         return d
     except Exception:   # noqa: BLE001 - profiling must never fail a fit
@@ -469,8 +463,7 @@ def profile_for(model_key: str) -> Dict:
 
 
 def last_fit_phases(algo: str) -> Dict:
-    """Most recent completed fit's phase totals for an algo — the
-    bench.py per-config phase-breakdown field."""
+    """Most recent completed fit's phase totals for an algo."""
     with _reg_lock:
         for d in reversed(_completed.values()):
             if d.get("algo") == algo:

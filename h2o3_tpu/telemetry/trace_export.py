@@ -277,7 +277,7 @@ def process_trace(last_spans: int = 2048, last_events: int = 2048,
 
 
 def write_trace(path: str, trace: Dict) -> str:
-    """Write a trace JSON artifact (bench.py per-config capture)."""
+    """Write a trace JSON artifact."""
     import json
     d = os.path.dirname(path)
     if d:

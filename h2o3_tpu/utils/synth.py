@@ -1,4 +1,4 @@
-"""Seeded synthetic data shared by ``bench.py`` and ``chip_smoke.py``.
+"""Seeded synthetic data for ``chip_smoke.py``.
 
 Not a dataset loader: the files written here exist so that the ingest
 path (streaming CSV → device) has real on-disk bytes of a known schema

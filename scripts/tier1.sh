@@ -3,7 +3,8 @@
 #
 # Run in ONE process (`-p no:xdist`, as below), the full tier-1 suite
 # does not fit an 870s per-invocation cap; the driver instead runs it
-# under pytest-xdist (`-p xdist -n 6 --dist loadfile`, 8 cores, ~8 min).
+# under pytest-xdist (`-p xdist -n 6 --dist load`, 8 cores, a 1,470 s
+# limit).
 # For single-process runs this script splits the suite
 # DETERMINISTICALLY:
 # `tests/test_*.py` are sorted lexically and alternated by index, and
@@ -17,12 +18,9 @@
 #   scripts/tier1.sh multiprocess # pod smoke: ONLY -m multiprocess legs
 #                                 # (cloud formation, durability, fleet,
 #                                 # tracing, global fit)
-#   scripts/tier1.sh full         # the ROADMAP.md one-shot (needs >870s)
-#   scripts/tier1.sh perfguard    # benchdiff gate vs committed BENCH
-#                                 # snapshot (jax-free, <10s)
+#   scripts/tier1.sh full         # one process, the whole suite (needs >870s)
 #
-# Every mode mirrors the ROADMAP.md tier-1 flags exactly; each capped
-# mode runs under `timeout -k 10 870`.
+# Every mode runs under `timeout -k 10 870`.
 set -u -o pipefail
 
 cd "$(dirname "$0")/.."
@@ -56,16 +54,8 @@ case "$MODE" in
         timeout -k 10 870 "${PYTEST[@]}" -m 'multiprocess and not slow' \
             tests/
         ;;
-    perfguard)
-        # perf-regression gate (ISSUE 20): diff the committed BENCH
-        # snapshot against itself through scripts/benchdiff.py — proves
-        # the gate's parse/compare path end-to-end, jax-free, <10s.
-        # An identical pair MUST pass; a broken parser fails loudly.
-        timeout -k 10 60 env JAX_PLATFORMS='' python \
-            scripts/benchdiff.py BENCH_r05.json BENCH_r05.json
-        ;;
     *)
-        echo "usage: $0 {part1|part2|full|multiprocess|perfguard}" >&2
+        echo "usage: $0 {part1|part2|full|multiprocess}" >&2
         exit 2
         ;;
 esac

@@ -30,6 +30,57 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """Under ``--dist load``, send a test module to ONE worker as a unit.
+
+    Spread test by test, every worker that ran a test of a module built
+    that module's fixtures again (a module-scoped fit, a two-process pod)
+    and compiled the module's programs again into caches of its own.
+    Kept whole, a module builds them once. The modules of
+    ``SPLIT_BY_FUNCTION`` are too long for one worker and share no
+    costly fixture: they go a test function at a time (its cases
+    together, and the functions that read one module-scoped fixture
+    together), or a test at a time where the table holds None."""
+    if config.getvalue("dist") != "load":
+        return None
+    from xdist.scheduler import LoadScopeScheduling
+
+    class ModuleScheduling(LoadScopeScheduling):
+        def _split_scope(self, nodeid):
+            path, _, rest = nodeid.partition("::")
+            name = os.path.basename(path)
+            if name not in SPLIT_BY_FUNCTION:
+                return path
+            units = SPLIT_BY_FUNCTION[name]
+            if units is None:
+                return nodeid
+            function = rest.split("[", 1)[0]
+            return path + "::" + units.get(function, function)
+
+    return ModuleScheduling(config, log)
+
+
+# module -> {test function: the module-scoped fixture it shares}, or None
+SPLIT_BY_FUNCTION = {
+    # a compile for a described chip in every test
+    "test_chip_compile.py": {
+        **dict.fromkeys(("test_gbm_boost_chunk",
+                         "test_gbm_boost_chunk_at_the_benchmark_cell",
+                         "test_predict_forest",
+                         "test_predict_forest_at_the_benchmark_cell",
+                         "test_serving_jit_with_donation"), "tiny_gbm"),
+        **dict.fromkeys(("test_glm_irls_solve_on_codes",
+                         "test_glm_irls_solve_on_codes_with_the_kernel"),
+                        "codes_glm"),
+        **dict.fromkeys(("test_dl_train_chunk",
+                         "test_dl_train_chunk_at_the_benchmark_cell"),
+                        "tiny_dl")},
+    # the Pallas interpreter's programs, one or two a test
+    "test_glm_cat_gram_kernel.py": None,
+}
+
+
 @pytest.fixture(autouse=True, scope="session")
 def _cloud():
     """Boot the cloud once per session (stall_till_cloudsize analogue)."""

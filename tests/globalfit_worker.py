@@ -15,9 +15,6 @@ Modes (``sys.argv[5]``):
   itself mid-boost-loop. pid 0's job must FAIL with an infra-classified
   error within one heartbeat window of the loss being observed — no
   hang, no leaked RUNNING job.
-* ``bench`` — times the global GBM fit on the partitioned frame and
-  reports rows/sec (pid 0), for bench.py's ``globalfit`` config; every
-  pid also drops its ``{outfile}.phases.{pid}`` step-profiler split.
 * ``profile`` — ISSUE 20: 2-process fit with ONE artificially-delayed
   host (``H2O3TPU_STEPPROF_DELAY_PID``/``_S``); pid 0 queries
   ``GET /3/Models/{id}/profile?cluster=1`` and reports the
@@ -165,33 +162,6 @@ def run_fit():
     h2o3_tpu.shutdown()
 
 
-def run_bench():
-    fr = make_frame()
-    ntrees = int(os.environ.get("H2O3TPU_GLOBALFIT_BENCH_NTREES", "30"))
-    GBMEstimator(ntrees=5, max_depth=4, seed=3).train(fr, y="y")  # warmup
-    t0 = time.time()
-    GBMEstimator(ntrees=ntrees, max_depth=4, seed=3).train(fr, y="y")
-    dt = max(time.time() - t0, 1e-9)
-    # EVERY pid reports its own phase split (telemetry/stepprof.py):
-    # bench.py folds these into the per-host compute/collective/host
-    # table printed next to the rows/sec line
-    try:
-        from h2o3_tpu.telemetry import stepprof
-        ph = stepprof.last_fit_phases("gbm")
-        ph["proc"] = int(pid)
-        with open(f"{outfile}.phases.{pid}", "w") as f:
-            json.dump(ph, f)
-    except Exception as e:   # noqa: BLE001 - table is best-effort
-        print(f"WORKER-{pid}-PHASES-FAILED {e}", flush=True)
-    if int(pid) == 0:
-        with open(outfile, "w") as f:
-            json.dump({"mode": mode, "rows_per_sec": N_ROWS * ntrees / dt,
-                       "seconds": dt, "ntrees": ntrees,
-                       "nrows": N_ROWS}, f)
-    print(f"WORKER-{pid}-DONE", flush=True)
-    h2o3_tpu.shutdown()
-
-
 def run_profile():
     """ISSUE 20 acceptance leg: a 2-process global GBM fit with ONE
     artificially-delayed host; ``GET /3/Models/{id}/profile?cluster=1``
@@ -313,8 +283,6 @@ def run_sigkill():
 
 if mode in ("fit", "ref"):
     run_fit()
-elif mode == "bench":
-    run_bench()
 elif mode == "sigkill":
     run_sigkill()
 elif mode == "profile":

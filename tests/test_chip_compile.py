@@ -283,13 +283,15 @@ def _at_scale(tree, n_tiny, n_real, mesh):
     return jax.tree_util.tree_map(place, tree)
 
 
-def _lower_recorded(name, mesh, n_tiny, rows, gram_kernel=None,
+def _lower_recorded(source, mesh, n_tiny, rows, gram_kernel=None,
                     **static_overrides):
-    """``gram_kernel``: the factor Gram's mode a design held as codes
+    """``source``: a fit's recorded call (``compile_observer.aot_source``,
+    taken where the fit ran: a later fit of the worker records its own).
+    ``gram_kernel``: the factor Gram's mode a design held as codes
     carries into the lowering (the CPU fit that recorded it resolved
     ``off``)."""
     from h2o3_tpu.frame.datainfo import CodesDesign
-    jit_fn, aargs, akwargs = compile_observer.aot_source(name)
+    jit_fn, aargs, akwargs = source
     n_real = mesh_mod.padded_rows(rows, mesh)
     akwargs = dict(_at_scale(akwargs, n_tiny, n_real, mesh))
     akwargs.update(static_overrides)
@@ -317,7 +319,8 @@ def tiny_gbm(tmp_path_factory):
         fr, y=AIRLINES_RESPONSE)
     assert model.bm.nbins_total == B and model.bm.bins.shape[1] == F
     assert tuple(bool(c) for c in model.bm.is_cat) == AIR_CATS
-    yield model, fr.nrows_padded
+    yield model, fr.nrows_padded, compile_observer.aot_source(
+        "gbm.boost_scan")
     h2o3_tpu.DKV.remove(model.key)
     h2o3_tpu.DKV.remove(fr.key)
 
@@ -326,11 +329,11 @@ def tiny_gbm(tmp_path_factory):
 @pytest.mark.parametrize("chips", [1, 4])
 def test_gbm_boost_chunk(topo, tiny_gbm, chips):
     """The 25-tree compiled scan of the flagship fit, 5M rows."""
-    _, n_tiny = tiny_gbm
+    _, n_tiny, scan = tiny_gbm
     mesh = _mesh(topo, chips)
-    tp = compile_observer.aot_source("gbm.boost_scan")[2]["tp"]
+    tp = scan[2]["tp"]
     txt = _compiled_text(_lower_recorded(
-        "gbm.boost_scan", mesh, n_tiny, AIR_ROWS, ntrees=25,
+        scan, mesh, n_tiny, AIR_ROWS, ntrees=25,
         tp=dataclasses.replace(tp, pallas=_auto_on_tpu(chips))))
     assert "tpu_custom_call" in txt
     assert ("all-reduce" in txt) == (chips > 1)
@@ -343,12 +346,12 @@ def test_gbm_boost_chunk_at_the_benchmark_cell(topo, tiny_gbm):
     past 8M rows. Every level of both trees runs the two kernels (the
     scan's body holds them once), and the program fits the chip."""
     from h2o3_tpu.models.tree import kernel_levels
-    _, n_tiny = tiny_gbm
-    tp = compile_observer.aot_source("gbm.boost_scan")[2]["tp"]
+    _, n_tiny, scan = tiny_gbm
+    tp = scan[2]["tp"]
     tp = dataclasses.replace(tp, pallas=_auto_on_tpu(1), block_rows=16384)
     assert tp.max_depth == 6 and all(kernel_levels(tp, F))
     txt = _compiled_text(_lower_recorded(
-        "gbm.boost_scan", _mesh(topo, 1), n_tiny, AIR48_ROWS, ntrees=2,
+        scan, _mesh(topo, 1), n_tiny, AIR48_ROWS, ntrees=2,
         tp=tp))
     assert txt.count("tpu_custom_call") >= 2 * tp.max_depth
     assert "all-reduce" not in txt
@@ -357,7 +360,7 @@ def test_gbm_boost_chunk_at_the_benchmark_cell(topo, tiny_gbm):
 @pytest.mark.allow_key_leak
 def test_predict_forest(topo, tiny_gbm):
     from h2o3_tpu.models.tree import predict_forest
-    model, _ = tiny_gbm
+    model, _, _ = tiny_gbm
     one = _one_chip(topo)
     forest = jax.tree_util.tree_map(
         lambda a: S((50,) + a.shape[1:], a.dtype, sharding=one),
@@ -397,7 +400,7 @@ def test_predict_forest_at_the_benchmark_cell(topo, tiny_gbm):
     come back through a refactor, nor as an ``[N, W]`` / ``[N, L]``
     intermediate, which the chip pads to 128 lanes (25 GB here)."""
     from h2o3_tpu.models.tree import predict_forest
-    model, _ = tiny_gbm
+    model, _, _ = tiny_gbm
     one = _one_chip(topo)
     forest = jax.tree_util.tree_map(
         lambda a: S(a.shape, a.dtype, sharding=one), model.forest)
@@ -422,7 +425,7 @@ def test_serving_jit_with_donation(topo, tiny_gbm, monkeypatch):
     """serving/engine.py's accelerator branch: the donated-input jit of
     the model's scoring program, at the 8-row bucket."""
     from h2o3_tpu.serving.engine import donating_jit
-    model, _ = tiny_gbm
+    model, _, _ = tiny_gbm
     # closed-over device arrays would pin the lowering to the CPU mesh
     monkeypatch.setattr(model, "forest", jax.tree_util.tree_map(
         np.asarray, model.forest))
@@ -444,39 +447,50 @@ def _row_sized_moves(txt, n):
         if src in shape_of and _element_count(shape_of[src]) >= n]
 
 
+@pytest.fixture(scope="module")
+def dense_glm():
+    """A binomial GLM at the HIGGS widths (28 numerics), small, on the CPU
+    test mesh: (its frame's padded rows, the recorded ``glm.irls_solve``)."""
+    from h2o3_tpu.models.glm import GLMEstimator
+    r = np.random.RandomState(3)
+    X = r.randn(TINY_ROWS, 28).astype(np.float32)
+    cols = {f"x{i}": X[:, i] for i in range(28)}
+    cols["y"] = np.array(["b", "s"], object)[(X[:, 0] > 0).astype(int)]
+    fr = h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
+    model = GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
+                         max_iterations=2, standardize=True).train(fr, y="y")
+    yield fr.nrows_padded, compile_observer.aot_source("glm.irls_solve")
+    h2o3_tpu.DKV.remove(model.key)
+    h2o3_tpu.DKV.remove(fr.key)
+
+
+@pytest.mark.allow_key_leak          # the module-scoped fit above
 @pytest.mark.parametrize("chips", [1, 4])
 @pytest.mark.parametrize("rows,batch", [(GLM_ROWS, None), (HIGGS_ROWS, None),
                                         (HIGGS_ROWS, 8)])
-def test_glm_irls_solve(topo, chips, rows, batch):
+def test_glm_irls_solve(topo, dense_glm, chips, rows, batch):
     """The IRLS solve at the smoke's rows and at ``glm-higgs.fit-11m``'s,
     alone and ``vmap``-batched over 8 (alpha, lambda) lanes. The Gram
     reads the design matrix in the layout it arrives in: no relayout
     copy of it and no row block sliced out of it, and no temporaries as
     large as the matrix (a blocked scan held 2.69 GB of them at the
     cell's rows on one chip, against the matrix's 1.28 GB)."""
-    from h2o3_tpu.models.glm import GLMEstimator, _irls_solve_batched
-    r = np.random.RandomState(3)
-    X = r.randn(TINY_ROWS, 28).astype(np.float32)
-    cols = {f"x{i}": X[:, i] for i in range(28)}
-    cols["y"] = np.array(["b", "s"], object)[(X[:, 0] > 0).astype(int)]
-    fr = h2o3_tpu.Frame.from_numpy(cols, categorical=["y"])
-    GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
-                 max_iterations=2, standardize=True).train(fr, y="y")
+    from h2o3_tpu.models.glm import _irls_solve_batched
+    n_tiny, solve = dense_glm
     mesh = _mesh(topo, chips)
     n_real = mesh_mod.padded_rows(rows, mesh)
     if batch:
         # the recorded single solve's arguments, l1 / l2 / objective
         # epsilon stacked on the vmapped axis as fit_glm_batched does
-        _, aargs, akwargs = compile_observer.aot_source("glm.irls_solve")
-        aargs = list(_at_scale(aargs, fr.nrows_padded, n_real, mesh))
+        _, aargs, akwargs = solve
+        aargs = list(_at_scale(aargs, n_tiny, n_real, mesh))
         aargs[5] = aargs[6] = aargs[13] = S(
             (batch,), jnp.float32, sharding=NamedSharding(mesh, P()))
         with _as_global_mesh(mesh):
             lowered = _irls_solve_batched.__wrapped__.lower(*aargs,
                                                            **akwargs)
     else:
-        lowered = _lower_recorded("glm.irls_solve", mesh, fr.nrows_padded,
-                                  rows)
+        lowered = _lower_recorded(solve, mesh, n_tiny, rows)
     compiled = lowered.compile()
     txt = compiled.as_text()
     assert ("all-reduce" in txt) == (chips > 1)
@@ -497,27 +511,11 @@ def _row_by_width_arrays(txt, n, width):
     return out
 
 
-@pytest.mark.parametrize("chips", [1, 4])
-def test_glm_irls_solve_on_codes(topo, chips):
-    """The IRLS solve of ``glm-airlines-116m-cat.fit-116m`` at its 116M
-    rows: the design held as codes, 756 coefficients. No array of rows by
-    coefficients (351 GB) is in the program, nor rows by any width past
-    the line search's nine candidates — the factor Gram and the lookups
-    walk a shard's rows a chunk at a time; the program fits the chip."""
-    _solve_on_codes(topo, chips, None)
-
-
-@pytest.mark.parametrize("chips", [1, 4])
-def test_glm_irls_solve_on_codes_with_the_kernel(topo, chips):
-    """The same solve with the factor Gram as the Pallas kernel
-    (``ops/pallas/gramkernel.py``), the mode ``auto`` resolves to on a
-    TPU: the same asserts, and the kernel in the program."""
-    assert _auto_on_tpu(chips) == "native"
-    txt = _solve_on_codes(topo, chips, "native")
-    assert "tpu_custom_call" in txt and "glm_cat_gram" in txt
-
-
-def _solve_on_codes(topo, chips, gram_kernel):
+@pytest.fixture(scope="module")
+def codes_glm():
+    """A binomial GLM on the cell's six factors and two numerics, small,
+    on the CPU test mesh: (its frame's padded rows, the recorded
+    ``glm.irls_solve`` of the design held as codes)."""
     from h2o3_tpu.models.glm import GLMEstimator
     r = np.random.RandomState(4)
     cols = {f: r.randint(0, L, TINY_ROWS) for f, L in CAT_LEVELS.items()}
@@ -528,11 +526,40 @@ def _solve_on_codes(topo, chips, gram_kernel):
             for f, L in CAT_LEVELS.items()}
     doms["y"] = ["NO", "YES"]
     fr = h2o3_tpu.Frame.from_numpy(cols, domains=doms)
-    GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
-                 max_iterations=1).train(fr, y="y")
+    model = GLMEstimator(family="binomial", solver="irlsm", lambda_=0.0,
+                         max_iterations=1).train(fr, y="y")
+    yield fr.nrows_padded, compile_observer.aot_source("glm.irls_solve")
+    h2o3_tpu.DKV.remove(model.key)
+    h2o3_tpu.DKV.remove(fr.key)
+
+
+@pytest.mark.allow_key_leak          # the module-scoped fit above
+@pytest.mark.parametrize("chips", [1, 4])
+def test_glm_irls_solve_on_codes(topo, codes_glm, chips):
+    """The IRLS solve of ``glm-airlines-116m-cat.fit-116m`` at its 116M
+    rows: the design held as codes, 756 coefficients. No array of rows by
+    coefficients (351 GB) is in the program, nor rows by any width past
+    the line search's nine candidates — the factor Gram and the lookups
+    walk a shard's rows a chunk at a time; the program fits the chip."""
+    _solve_on_codes(topo, codes_glm, chips, None)
+
+
+@pytest.mark.allow_key_leak
+@pytest.mark.parametrize("chips", [1, 4])
+def test_glm_irls_solve_on_codes_with_the_kernel(topo, codes_glm, chips):
+    """The same solve with the factor Gram as the Pallas kernel
+    (``ops/pallas/gramkernel.py``), the mode ``auto`` resolves to on a
+    TPU: the same asserts, and the kernel in the program."""
+    assert _auto_on_tpu(chips) == "native"
+    txt = _solve_on_codes(topo, codes_glm, chips, "native")
+    assert "tpu_custom_call" in txt and "glm_cat_gram" in txt
+
+
+def _solve_on_codes(topo, codes_glm, chips, gram_kernel):
+    n_tiny, solve = codes_glm
     mesh = _mesh(topo, chips)
-    compiled = _lower_recorded("glm.irls_solve", mesh, fr.nrows_padded,
-                               CAT116_ROWS, gram_kernel=gram_kernel).compile()
+    compiled = _lower_recorded(solve, mesh, n_tiny, CAT116_ROWS,
+                               gram_kernel=gram_kernel).compile()
     txt = compiled.as_text()
     n_local = mesh_mod.padded_rows(CAT116_ROWS, mesh) // chips
     assert "gram.cat" in txt and "glm.eta" in txt
@@ -698,7 +725,7 @@ def tiny_dl():
     model = DeepLearningEstimator(
         hidden=[200, 200], activation="rectifier", epochs=0.1,
         seed=1).train(fr, y="label")
-    yield fr.nrows_padded
+    yield fr.nrows_padded, compile_observer.aot_source("dl.train_chunk")
     h2o3_tpu.DKV.remove(model.key)
     h2o3_tpu.DKV.remove(fr.key)
 
@@ -706,7 +733,8 @@ def tiny_dl():
 @pytest.mark.allow_key_leak          # the module-scoped fit above
 def test_dl_train_chunk(topo, tiny_dl):
     # the smoke's fit: 200k rows give a 2048-row batch, 200-step chunks
-    _lower_recorded("dl.train_chunk", _mesh(topo, 1), tiny_dl,
+    n_tiny, chunk = tiny_dl
+    _lower_recorded(chunk, _mesh(topo, 1), n_tiny,
                     DL_ROWS, n=DL_ROWS, batch=2048, nsteps=200).compile()
 
 
@@ -717,8 +745,9 @@ def test_dl_train_chunk_at_the_benchmark_cell(topo, tiny_dl):
     of 16,384 rows, bfloat16 operands, 200 static steps. It lowers under
     the name the benchmark's readers look for, and what it asks of the
     chip beside the resident matrix stays a small part of it."""
+    n_tiny, chunk = tiny_dl
     lowered = _lower_recorded(
-        "dl.train_chunk", _mesh(topo, 1), tiny_dl, MNIST1M_ROWS,
+        chunk, _mesh(topo, 1), n_tiny, MNIST1M_ROWS,
         n=MNIST1M_ROWS, batch=16384, nsteps=200, bf16=True)
     assert "module @jit__train_steps_fused" in lowered.as_text()[:400]
     compiled = lowered.compile()

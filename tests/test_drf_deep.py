@@ -19,7 +19,7 @@ import pytest
 
 import h2o3_tpu
 from h2o3_tpu import telemetry
-from h2o3_tpu.models import frontier, tree as tree_mod
+from h2o3_tpu.models import drf as drf_mod, frontier, tree as tree_mod
 from h2o3_tpu.models.drf import MAX_COMPLETE_DEPTH, DRFEstimator
 
 pytestmark = pytest.mark.allow_key_leak     # module-scoped fits below
@@ -181,9 +181,21 @@ def test_a_shallow_forest_is_handed_over_as_the_complete_tree(
     assert a.equals(b)
 
 
+@pytest.fixture
+def frontier_from_3(monkeypatch):
+    """The frontier regime from level 3, as ``two_regimes`` forces it: the
+    complete levels above it are the costliest part of a small forest's
+    program to compile, and a fit reads the constant when it starts."""
+    monkeypatch.setattr(tree_mod, "FRONTIER_FROM", 3)
+
+
 @pytest.fixture(scope="module")
 def default_forest(mixed_frame):
-    return DRFEstimator(ntrees=4, seed=7).train(mixed_frame, y="y")
+    """Four trees at the default depth, 20, from level 3 in the frontier
+    regime (``frontier_from_3``)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tree_mod, "FRONTIER_FROM", 3)
+        return DRFEstimator(ntrees=4, seed=7).train(mixed_frame, y="y")
 
 
 def test_a_default_forest_grows_past_the_old_cap(default_forest):
@@ -195,12 +207,14 @@ def test_a_default_forest_grows_past_the_old_cap(default_forest):
 
 
 def _operand_rows(**fit):
+    """A depth-6 forest, the frontier regime from level 3
+    (``frontier_from_3``)."""
     frame, x, y = fit.pop("frame"), fit.pop("x"), fit.pop("y", "y")
-    model = DRFEstimator(ntrees=2, seed=7, max_depth=12, **fit).train(
+    model = DRFEstimator(ntrees=2, seed=7, max_depth=6, **fit).train(
         frame, y=y, x=x)
     chunk = [s for s in telemetry.spans_snapshot(last=1 << 16)
              if s["name"] == "drf.chunk"][-1]["meta"]
-    assert chunk["frontier_hist"] == "xla" and chunk["levels_frontier"] == 5
+    assert chunk["frontier_hist"] == "xla" and chunk["levels_frontier"] == 3
     return model, chunk["hist_operand_rows"]
 
 
@@ -221,7 +235,7 @@ def weighted_frame(mixed_frame):
 
 
 def test_the_histogram_operand_is_as_short_as_the_statistics(
-        weighted_frame, monkeypatch):
+        weighted_frame, frontier_from_3, monkeypatch):
     """Two statistics a forest (a hessian of 1), and one bfloat16 piece
     each where they are 0 or ±1: a class response under whole weights."""
     x = ["x1", "x2", "c"]
@@ -278,7 +292,7 @@ def test_the_complete_levels_and_the_frontier_ask_the_same_plan(
     monkeypatch.setattr(tree_mod, "stats_plan", spy)
     monkeypatch.setattr(tree_mod, "FRONTIER_FROM", 3)
     monkeypatch.setenv("H2O3TPU_PALLAS", "interpret")
-    jax.clear_caches()                  # grow_tree is traced anew
+    drf_mod._bag_scan.clear_cache()     # grow_tree is traced anew
     DRFEstimator(ntrees=1, seed=1, max_depth=6).train(mixed_frame, y="y")
     chunk = [s for s in telemetry.spans_snapshot(last=1 << 16)
              if s["name"] == "drf.chunk"][-1]["meta"]
@@ -288,7 +302,8 @@ def test_the_complete_levels_and_the_frontier_ask_the_same_plan(
     assert {(u, w) for _, u, w in asked} == {(True, True)}
 
 
-def test_chunked_is_single_scan(default_forest, mixed_frame):
+def test_chunked_is_single_scan(default_forest, mixed_frame,
+                               frontier_from_3):
     chunked = DRFEstimator(ntrees=4, seed=7, max_runtime_secs=1e6).train(
         mixed_frame, y="y")
     chunks = [s for s in telemetry.spans_snapshot(last=1 << 16)
@@ -300,7 +315,8 @@ def test_chunked_is_single_scan(default_forest, mixed_frame):
 
 
 def test_a_checkpoint_restart_appends_bit_equal_trees(default_forest,
-                                                     mixed_frame):
+                                                     mixed_frame,
+                                                     frontier_from_3):
     first = DRFEstimator(ntrees=2, seed=7).train(mixed_frame, y="y")
     more = DRFEstimator(ntrees=4, seed=7, checkpoint=first).train(
         mixed_frame, y="y")

@@ -23,7 +23,7 @@ from benchmark.adapters import drf as drf_adapter
 from benchmark.generators import airlines
 from benchmark.references import drf as drf_reference
 from h2o3_tpu import telemetry
-from h2o3_tpu.models import tree as tree_mod
+from h2o3_tpu.models import drf as drf_mod, tree as tree_mod
 from h2o3_tpu.models.drf import DRFEstimator
 
 pytestmark = pytest.mark.allow_key_leak     # module-scoped frame below
@@ -123,12 +123,12 @@ def test_an_ignored_column_draw_is_not_correct(frame, data, monkeypatch):
         tree_mod, "_mtries_mask",
         lambda key, heap, F, mtries: jax.numpy.ones((heap.shape[0], F),
                                                     bool))
-    jax.clear_caches()                  # the forest program is traced anew
+    drf_mod._bag_scan.clear_cache()     # the forest program is traced anew
     try:
         model, _ = fit(frame, data)
     finally:
         monkeypatch.undo()
-        jax.clear_caches()
+        drf_mod._bag_scan.clear_cache()
     numbers = drf_reference.check(data, drf_adapter.read_outputs(model),
                                   PARAMS)
     assert numbers["mtries_gap"] > 0 and "mtries_gap" in failed(numbers)

@@ -1,5 +1,5 @@
 """Fault-tolerance layer tests — watchdog policy/probe, fault
-injection, recovery snapshots, bench subprocess isolation, and the
+injection, recovery snapshots, and the
 SIGKILL-mid-AutoML resume contract (ISSUE 2; reference
 hex/faulttolerance/Recovery.java + water/HeartBeatThread.java roles).
 
@@ -22,7 +22,6 @@ import pytest
 from h2o3_tpu.core import config, watchdog
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(REPO, "bench.py")
 FT_WORKER = os.path.join(REPO, "tests", "ft_worker.py")
 
 
@@ -228,112 +227,6 @@ def test_automl_recovery_snapshot_and_resume(tmp_path, classif_frame):
     post = [e for e in res.event_log
             if e["stage"] == "model"]
     assert post == []
-
-
-# --------------------------------------------- bench subprocess isolation
-
-
-def _run_bench(tmp_path, extra_env, timeout=300):
-    env = dict(os.environ)
-    env.update({"H2O3TPU_BENCH_STUB": "1",
-                "JAX_PLATFORMS": "cpu",
-                "H2O3TPU_INFRA_BACKOFF_BASE_S": "0.05",
-                "H2O3TPU_INFRA_BACKOFF_MAX_S": "0.1",
-                "H2O3TPU_FAULT_STATE": str(tmp_path / "faultstate")})
-    env.update(extra_env)
-    p = subprocess.run([sys.executable, BENCH], env=env,
-                       capture_output=True, text=True, timeout=timeout)
-    # parse only up to the tail-proof summary (which re-prints every
-    # line and would double-count)
-    stdout = p.stdout.split("# ---- summary")[0]
-    lines = [json.loads(ln) for ln in stdout.splitlines()
-             if ln.strip().startswith("{")]
-    return p, lines
-
-
-@pytest.mark.allow_key_leak
-def test_bench_wedged_config_costs_one_line(tmp_path):
-    """Acceptance: an injected wedged backend (a child that never
-    finishes) costs exactly one config line — the others still emit —
-    and the recorded budget never goes below 0."""
-    p, lines = _run_bench(tmp_path, {
-        "H2O3TPU_BENCH_BUDGET_S": "240",
-        # cap >> any healthy stub config (2.5-3 s alone, several times
-        # that beside six compiling xdist workers) but small: the wedged
-        # child burns the full cap before the kill, straight wall time
-        "H2O3TPU_BENCH_CONFIG_TIMEOUT_S": "20",
-        "H2O3TPU_BENCH_TRACE_DIR": str(tmp_path / "traces")})
-    assert p.returncode == 0, p.stderr[-2000:]
-    by_metric = {}
-    for ln in lines:
-        by_metric.setdefault(ln["metric"], []).append(ln)
-    assert "value" in by_metric["stub config stub_a"][0]
-    assert "value" in by_metric["stub config stub_b"][0]
-    # every SUCCESSFUL config also banked a Chrome-trace artifact
-    trace_line = by_metric["trace stub_a"][0]
-    with open(trace_line["trace_path"]) as f:
-        trace = json.load(f)
-    assert all({"ph", "ts", "pid", "tid"} <= set(e)
-               for e in trace["traceEvents"])
-    wedge = by_metric["stub_wedge"][0]
-    assert "wedged" in wedge["error"]
-    # the roofline stub emits the hardware-relative fields (ISSUE 8):
-    # every BENCH line carries mfu/hbm_util even without a backend
-    rf = next(v[0] for k, v in by_metric.items()
-              if k.startswith("roofline"))
-    assert rf["mfu"] > 0 and rf["hbm_util"] > 0
-    # the stepprof stub (ISSUE 20) proves the profiler's contracts
-    # without a backend: bounded ring, straggler identity on synthetic
-    # peers, and the benchdiff regression gate's pass/fail split
-    sp = next(v[0] for k, v in by_metric.items()
-              if k.startswith("stepprof"))
-    assert sp["ring_len"] == 8 and sp["straggler"] == 1
-    assert sp["skew_ratio"] > 1.5
-    assert sp["benchdiff_identical_rc"] == 0
-    assert sp["benchdiff_regression_rc"] == 1
-    budget = by_metric["budget"][0]
-    assert budget["left_s"] >= 0.0
-    assert budget["budget_s"] >= 0.0
-    for ln in lines:                       # no skipped line went negative
-        if "skipped" in ln:
-            assert "-" not in ln["skipped"]
-
-
-@pytest.mark.allow_key_leak
-def test_bench_preflight_probe_retries_then_recovers(tmp_path):
-    """Transient probe failures (2 injected, shared across probe child
-    processes via H2O3TPU_FAULT_STATE) are absorbed by the bounded
-    backoff; every config line still emits."""
-    # caps sized for a loaded host (six xdist workers compiling beside
-    # this): a healthy stub config or probe child takes ~1-3 s alone
-    p, lines = _run_bench(tmp_path, {
-        "H2O3TPU_FAULTS": "probe:2",
-        "H2O3TPU_BENCH_BUDGET_S": "240",
-        "H2O3TPU_BENCH_CONFIG_TIMEOUT_S": "30"})
-    assert p.returncode == 0, p.stderr[-2000:]
-    metrics = {ln["metric"] for ln in lines if "value" in ln}
-    assert {"stub config stub_a", "stub config stub_b"} <= metrics
-    assert p.stderr.count("probe attempt") == 2
-
-
-@pytest.mark.allow_key_leak
-def test_bench_dead_backend_fails_fast_per_config(tmp_path):
-    """A permanently dead backend costs error lines, not a hung bench:
-    each config fails fast after the probe's bounded backoff."""
-    p, lines = _run_bench(tmp_path, {
-        "H2O3TPU_FAULTS": "probe:999",
-        "H2O3TPU_INFRA_MAX_ATTEMPTS": "2",
-        "H2O3TPU_BENCH_BUDGET_S": "240",
-        "H2O3TPU_BENCH_CONFIG_TIMEOUT_S": "30"})
-    assert p.returncode == 0, p.stderr[-2000:]
-    errors = [ln for ln in lines if "error" in ln]
-    # one per stub config (incl. grid, treekernel, cloud, roofline,
-    # checkpoint, memgov, ingest, serving, sched, slo, fleet,
-    # durability, globalfit, stepprof)
-    assert len(errors) == 17
-    assert all("backend dead" in ln["error"] for ln in errors)
-    budget = [ln for ln in lines if ln["metric"] == "budget"][0]
-    assert budget["left_s"] >= 0.0
 
 
 # ------------------------------------------- SIGKILL-mid-AutoML resume

@@ -407,14 +407,12 @@ def test_metric_names_documented_in_readme():
                      "frame_rebuild_seconds", "cloud_restore_seconds",
                      "frames_under_replicated"):
         assert required in section, required
-    # the ISSUE 20 step-profiling + perf-baseline surface
-    # (telemetry/stepprof.py, telemetry/perfbase.py) is part of the
+    # the step-profiling surface (telemetry/stepprof.py) is part of the
     # stable contract too
     for required in ("model_fit_phase_seconds", "pod_step_skew_ratio",
-                     "pod_straggler_host", "fit_step_baseline_ratio",
-                     "stepprof_fits_total", "H2O3TPU_STEPPROF",
-                     "H2O3TPU_STEPPROF_RING", "benchdiff",
-                     "perf_baselines", "/profile"):
+                     "pod_straggler_host", "stepprof_fits_total",
+                     "H2O3TPU_STEPPROF", "H2O3TPU_STEPPROF_RING",
+                     "/profile"):
         assert required in section, required
 
 

@@ -87,17 +87,21 @@ def close(got, want, rel):
                                    atol=rel * np.abs(d).max())
 
 
+# rows a grid step of the kernel reads in the plans' tests: two 512-row
+# sub-blocks. ``gram.gram`` traces anew at every call, so the patched
+# value reaches the kernel without clearing a cache
+BLOCK = 1024
+
 PLANS = {
     # the cell: four narrow factors gathered, Origin x Dest
-    "airlines": (AIRLINES, 3, 0.0, 12_288),
+    "airlines": (AIRLINES, 3, 0.0, 2 * BLOCK),
     # a factor past CAT_GROUP_ROWS, three narrow ones gathered
-    "wide": ((5, 40, 200, 3), 2, 0.0, 6_000),
-    "single": ((60,), 2, 0.0, 4_096),
-    "no-numerics": ((7, 29, 31), 0, 0.0, 4_096),
-    "na-codes": ((5, 40, 3), 2, 0.05, 4_096),
-    # 2,500 rows a shard on four devices, 10,000 on one: a shard padded
-    # to whole sub-blocks / blocks
-    "ragged": ((12, 31, 7), 1, 0.02, 10_000),
+    "wide": ((5, 40, 200, 3), 2, 0.0, 2 * BLOCK),
+    "single": ((60,), 2, 0.0, 2 * BLOCK),
+    "no-numerics": ((7, 29, 31), 0, 0.0, 2 * BLOCK),
+    "na-codes": ((5, 40, 3), 2, 0.05, 2 * BLOCK),
+    # a shard's second block and its last sub-block padded
+    "ragged": ((12, 31, 7), 1, 0.02, BLOCK + 226),
 }
 
 
@@ -105,8 +109,13 @@ PLANS = {
     (1, "airlines"), (4, "airlines"), (4, "wide"), (1, "single"),
     (4, "no-numerics"), (1, "na-codes"), (4, "ragged")],
     indirect=["devices"])
-def test_the_kernel_is_the_scan_and_the_float64_gram(devices, plan):
-    levels, nd, na, n = PLANS[plan]
+def test_the_kernel_is_the_scan_and_the_float64_gram(devices, plan,
+                                                      monkeypatch):
+    """Each plan on two grid steps a shard (``PLANS``: rows a shard)."""
+    monkeypatch.setattr(gram_mod, "CAT_KERNEL_ROWS", BLOCK)
+    levels, nd, na, rows = PLANS[plan]
+    n = rows * devices
+    assert gramkernel.block_rows(rows, BLOCK) == BLOCK < rows
     X, w, z, Xd = codes_design(levels, n, nd, seed=len(plan), na=na)
     scan, kern = both_grams(X, w, z)
     want = float64_gram(Xd, w, z)
@@ -117,7 +126,7 @@ def test_the_kernel_is_the_scan_and_the_float64_gram(devices, plan):
     close(scan, want, 1e-5)
 
 
-def test_the_compensation_survives_in_the_kernel(monkeypatch, request):
+def test_the_compensation_survives_in_the_kernel(monkeypatch):
     """256 blocks of 1,024 rows, a factor whose dropped first level holds
     0.05% of the rows: that level's weight is the sum of all weights less
     its other levels', a difference of totals 2,000 times larger. The
@@ -127,8 +136,6 @@ def test_the_compensation_survives_in_the_kernel(monkeypatch, request):
     emulation of the kernel's sums); the same blocks' exact sums added in
     plain float32 do not (1.2e-3)."""
     monkeypatch.setattr(gram_mod, "CAT_KERNEL_ROWS", 1024)
-    jax.clear_caches()
-    request.addfinalizer(jax.clear_caches)
     n, blocks = 262_144, 256
     X, _, z, _ = codes_design((4,), n, 1, seed=1, rare=0.0005)
     w = (np.random.default_rng(1).integers(1, 4096, n) / 4096).astype(
@@ -175,7 +182,7 @@ def fit(fr, monkeypatch, kernel, **kw):
             ("pallas" if kernel else "xla")
         return model
     # builds are counted as the program is traced: trace it anew
-    jax.clear_caches()
+    glm_mod._multinomial_irls_solve.clear_cache()
     before = launches()
     model = GLMEstimator(lambda_=0.0, **kw).train(fr, y="y")
     assert (launches() > before) == kernel

@@ -73,12 +73,14 @@ def test_the_codes_gram_is_the_dense_gram(devices, walk, monkeypatch,
     """``walk``: (``CAT_CHUNK``, ``CAT_SUM``) — a shard's rows in several
     steps of several blocks, as at full size, where the defaults make a
     test frame's shard one block of one step. They are read when a
-    program is traced: the caches are cleared on both sides."""
+    program is traced: ``gram`` traces anew at every call, the two jitted
+    products' caches are cleared on both sides."""
     if walk:
         monkeypatch.setattr(gram_mod, "CAT_CHUNK", walk[0])
         monkeypatch.setattr(gram_mod, "CAT_SUM", walk[1])
-        jax.clear_caches()
-        request.addfinalizer(jax.clear_caches)
+        for jitted in (codes_matvec, codes_rmatvec):
+            jitted.clear_cache()
+            request.addfinalizer(jitted.clear_cache)
     cols, doms = mixed_columns()
     fr = h2o3_tpu.Frame.from_numpy(cols, domains=doms)
     codes, dense = designs(fr)

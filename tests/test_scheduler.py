@@ -3,8 +3,7 @@
 Three tiers:
 
 - RunBoard unit tests: the coordinator's lease/complete/reassign state
-  machine, dry (no KV, no backend) — the same surface bench.py's
-  ``_stub_sched`` leg drives.
+  machine, dry (no KV, no backend).
 - Inline-run tests: ``scheduler.run`` on the single-process pytest
   cloud degrades to the inline executor but still exercises the item
   execution path (failure capture, nesting guard, lease gauge).

@@ -129,7 +129,7 @@ def test_a_load_from_the_persistent_cache_is_not_a_compile(tmp_path):
         f(jnp.ones((7,))).block_until_ready()
         row = _row("jit__ledger_probe_cached")
         assert (row["compiles"], row["cache_loads"]) == (1, 0)
-        jax.clear_caches()
+        f.clear_cache()
         x = jnp.ones((7,))      # its own programs, before the counts
         built = _counter("xla_programs_total", source="compile")
         loaded = _counter("xla_programs_total", source="cache")

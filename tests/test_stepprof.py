@@ -1,7 +1,6 @@
 """Training-step profiler acceptance (ISSUE 20): per-chunk phase
 timing as a wall-clock partition, bounded rings, straggler/skew
-verdicts on per-host snapshots, the perf-baseline regression guard,
-and scripts/benchdiff.py's offline gate.
+verdicts on per-host snapshots, and the hand-offs at a fit's end.
 
 The multiprocess leg spawns a REAL 2-process gloo pod
 (tests/globalfit_worker.py ``profile`` mode) with ONE artificially
@@ -9,7 +8,6 @@ delayed host and asserts ``GET /3/Models/{id}/profile?cluster=1``
 names that host as the straggler.
 """
 
-import importlib.util
 import json
 import os
 import socket
@@ -22,7 +20,6 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "globalfit_worker.py")
-BENCHDIFF = os.path.join(REPO, "scripts", "benchdiff.py")
 
 from h2o3_tpu.telemetry import stepprof  # noqa: E402
 
@@ -32,13 +29,6 @@ def _fresh_registry():
     stepprof.reset()
     yield
     stepprof.reset()
-
-
-def _load_benchdiff():
-    spec = importlib.util.spec_from_file_location("benchdiff", BENCHDIFF)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ------------------------------------------------------------ unit tier
@@ -188,111 +178,47 @@ def test_cluster_profile_single_process_sets_gauges():
     assert [g.value for g in REGISTRY.find("pod_straggler_host")] == [0.0]
 
 
-# --------------------------------------------------- perfbase baselines
+# ----------------------------------------------- fit-end hand-offs
 
 
-def test_perfbase_ratio_and_slo_rule(tmp_path, monkeypatch):
-    monkeypatch.setenv("H2O3TPU_PERF_BASELINE_DIR", str(tmp_path))
-    from h2o3_tpu.telemetry import perfbase
-    prof = {"seconds": 2.0, "chunks": 4,
-            "phases": {"host": 0.5, "compute": 1.5}}
-    assert perfbase.record_fit("gbm", 5000, prof, mfu=0.01) == 1.0
-    # 2x step-time regression vs the stored best
-    prof2 = {"seconds": 4.0, "chunks": 4,
-             "phases": {"host": 1.0, "compute": 3.0}}
-    assert perfbase.record_fit("gbm", 5000, prof2) == 2.0
-    doc = perfbase.load(perfbase.baseline_key("gbm", 5000))
-    assert doc["best_step_seconds"] == 0.5       # best is sticky
-    assert len(doc["history"]) == 2
-    # the default SLO rule fires on the gauge the record just set
-    from h2o3_tpu.telemetry import slo
-    from h2o3_tpu.telemetry.registry import REGISTRY
-    rule = {r.name: r for r in slo.default_rules()}["fit_step_regression"]
-    ok, detail = rule.check_fn(REGISTRY)
-    assert not ok and detail["worst_algo"] == "gbm"
-    assert detail["max_ratio"] == 2.0
+def test_finish_returns_mfu_and_hands_the_capsule_a_ringless_profile():
+    """``finish(mfu=)`` puts the MFU on the returned record, and the
+    job's flight-recorder capsule gets that record without its ring."""
+    from h2o3_tpu.telemetry import flight_recorder
+    handle = flight_recorder.attach("job_stepprof_capsule")
+    try:
+        prof = stepprof.start("gbm", nrows=100)
+        for _ in range(3):
+            stepprof.chunk_begin()
+            stepprof.compute_done(None)
+            stepprof.chunk_end(trees=1)
+        d = stepprof.finish(prof, model_key="m_capsule", mfu=0.25)
+    finally:
+        flight_recorder.detach(handle, "DONE")
+    assert d["mfu"] == 0.25
+    assert len(d["ring"]) == 3
+    [block] = handle.capsule.to_dict()["step_profiles"]
+    assert "ring" not in block
+    assert block == {k: v for k, v in d.items() if k != "ring"}
 
 
-def test_perfbase_shape_buckets_isolate_baselines(tmp_path, monkeypatch):
-    monkeypatch.setenv("H2O3TPU_PERF_BASELINE_DIR", str(tmp_path))
-    from h2o3_tpu.telemetry import perfbase
-    assert perfbase.shape_bucket(4001) == "r4096"
-    assert perfbase.shape_bucket(4096) == "r4096"
-    assert perfbase.shape_bucket(4097) == "r8192"
-    slow = {"seconds": 10.0, "chunks": 1, "phases": {}}
-    fast = {"seconds": 0.1, "chunks": 1, "phases": {}}
-    perfbase.record_fit("gbm", 100, slow)
-    # a different shape bucket never compares against the 100-row best
-    assert perfbase.record_fit("gbm", 1_000_000, fast) == 1.0
-
-
-def test_perfbase_ignores_chunkless_fits(tmp_path, monkeypatch):
-    monkeypatch.setenv("H2O3TPU_PERF_BASELINE_DIR", str(tmp_path))
-    from h2o3_tpu.telemetry import perfbase
-    assert perfbase.record_fit("gbm", 10, {"seconds": 1.0,
-                                           "chunks": 0}) is None
-    assert os.listdir(str(tmp_path)) == []
-
-
-# ----------------------------------------------------------- benchdiff
-
-
-def test_benchdiff_flags_30pct_regression_and_passes_identical(tmp_path):
-    bd = _load_benchdiff()
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps([
-        {"metric": "fit_step", "value": 1.0, "unit": "seconds",
-         "phases": {"host": 0.2, "compute": 0.8}},
-        {"metric": "gbm_rate", "value": 1000.0, "unit": "rows/sec"}]))
-    new.write_text(json.dumps([
-        {"metric": "fit_step", "value": 1.3, "unit": "seconds",
-         "phases": {"host": 0.2, "compute": 1.1}},
-        {"metric": "gbm_rate", "value": 990.0, "unit": "rows/sec"}]))
-    assert bd.main([str(old), str(old)]) == 0       # identical passes
-    assert bd.main([str(old), str(new)]) == 1       # +30% seconds fails
-    res = bd.compare(bd.load_metrics(str(old)), bd.load_metrics(str(new)))
-    assert res["regressions"] == ["fit_step"]
-    fail = next(r for r in res["rows"] if r["regressed"])
-    assert fail["phase_deltas"]["compute"] == pytest.approx(0.3)
-
-
-def test_benchdiff_direction_heuristic(tmp_path):
-    """rows/sec dropping 30% is a regression; seconds dropping 30% is
-    an improvement — unit direction decides the sign."""
-    bd = _load_benchdiff()
-    old = tmp_path / "o.json"
-    new = tmp_path / "n.json"
-    old.write_text(json.dumps([
-        {"metric": "rate", "value": 1000.0, "unit": "rows/sec"},
-        {"metric": "lat", "value": 1.0, "unit": "seconds"}]))
-    new.write_text(json.dumps([
-        {"metric": "rate", "value": 700.0, "unit": "rows/sec"},
-        {"metric": "lat", "value": 0.7, "unit": "seconds"}]))
-    res = bd.compare(bd.load_metrics(str(old)), bd.load_metrics(str(new)))
-    by = {r["metric"]: r["regressed"] for r in res["rows"]}
-    assert by == {"rate": True, "lat": False}
-
-
-def test_benchdiff_parses_bench_artifact_tails(tmp_path):
-    """The committed BENCH_*.json format: config entries whose `tail`
-    embeds JSON metric lines; parsing stops at the summary marker and
-    an all-error artifact diffs as a vacuous pass."""
-    bd = _load_benchdiff()
-    art = tmp_path / "BENCH_x.json"
-    art.write_text(json.dumps(
-        {"n": 5, "cmd": "bench", "rc": 0, "tail":
-         'noise\n{"metric": "gbm cfg", "value": 5.0, "unit": "rows/sec"}'
-         '\n# ---- summary\n{"metric": "gbm cfg", "value": 9.9, '
-         '"unit": "rows/sec"}'}))
-    m = bd.load_metrics(str(art))
-    assert m == [{"metric": "gbm cfg", "value": 5.0,
-                  "unit": "rows/sec"}]    # first wins, summary ignored
-    assert bd.main([str(art), str(art)]) == 0
-    # the committed r05 artifact (all-error round) stays a clean pass
-    r05 = os.path.join(REPO, "BENCH_r05.json")
-    assert bd.main([r05, r05]) == 0
-    assert bd.main(["/nonexistent.json", str(art)]) == 2
+def test_a_profiled_fit_writes_no_file_under_the_ice_root(tmp_path,
+                                                          monkeypatch):
+    """The fit's ``fit.account`` span closes the step profile and keeps
+    nothing on disk: no baseline file appears under the ice root."""
+    import h2o3_tpu
+    from h2o3_tpu.core import config
+    from h2o3_tpu.models.glm import GLMEstimator
+    monkeypatch.setattr(config.ARGS, "ice_root", str(tmp_path))
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(256, 2)).astype(np.float32)
+    fr = h2o3_tpu.Frame.from_numpy(
+        {"a": x[:, 0], "b": x[:, 1],
+         "y": (x[:, 0] + 0.5 * x[:, 1]).astype(np.float32)})
+    model = GLMEstimator(family="gaussian").train(fr, y="y")
+    assert stepprof.profile_for(model.key)["algo"] == "glm"
+    assert [os.path.join(r, f) for r, _, fs in os.walk(tmp_path)
+            for f in fs] == []
 
 
 # --------------------------------------------- the real 2-process leg

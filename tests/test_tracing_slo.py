@@ -9,8 +9,7 @@ Tiers:
 - histogram quantiles: bucket interpolation, merged grids, and the
   ``predict_seconds{phase}`` shared-bucket-grid regression.
 - SLO engine: the multi-window burn-rate state machine on a private
-  registry with a fake clock (the same surface bench.py's ``_stub_slo``
-  leg drives), plus the gauge-rule and capsule surfaces.
+  registry with a fake clock, plus the gauge-rule and capsule surfaces.
 - REST: ``X-H2O-Trace-Id`` echo/generation, ``traceparent`` ingress,
   JobV3 ``trace_id``, single-process ``GET /3/Trace?trace_id=``
   stitching, ``GET /3/Trace`` bit-compat, and ``GET /3/Alerts``.
@@ -363,10 +362,23 @@ def test_slo_default_rules_evaluate_on_live_registry():
     assert names == {"predict_p99_latency", "rest_availability",
                      "heartbeat_health", "fit_mfu_floor",
                      "fleet_routing_availability", "fleet_replica_floor",
-                     "data_durability_floor", "fit_step_regression"}
+                     "data_durability_floor"}
     assert out["windows_s"] == [300.0, 3600.0]
     for r in out["rules"]:
         assert r["state"] in slo.STATES
+
+
+def test_slo_default_rules_all_healthy_on_a_fresh_registry(monkeypatch):
+    """With no fit, request or heartbeat recorded, every default rule
+    evaluates ``healthy``: no rule reads an absent series as a breach."""
+    monkeypatch.delenv("H2O3TPU_SLO_MFU_FLOOR", raising=False)
+    eng = slo.SLOEngine(registry=MetricsRegistry(), now=lambda: 1000.0)
+    out = eng.evaluate()
+    assert ({r["slo"] for r in out["rules"]}
+            == {r.name for r in slo.default_rules()})
+    assert {r["slo"]: r["state"] for r in out["rules"]} == {
+        r.name: "healthy" for r in slo.default_rules()}
+    assert out["alerts"] == []
 
 
 def test_capsule_stamps_active_slo_alerts(monkeypatch):
